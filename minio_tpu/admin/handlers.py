@@ -1340,7 +1340,29 @@ def _server_info(srv) -> dict:
         "drives": dinfo,
         "buckets": buckets,
         "backend_version": 1,
+        "codec": _codec_info(srv.layer),
     }
+
+
+def _codec_info(layer) -> dict:
+    """Where the bytes are coded, read back rather than assumed: the
+    codec backends the erasure sets RESOLVED to, the device JAX reports
+    when one of them is a device backend (platform, kind, count, kernel
+    form, compile tallies and cache — ops/device.py), what the md5
+    ``auto`` rung chose, and which native libraries loaded."""
+    from ..hashing import md5fast
+    from ..utils import nativelib
+    pools = getattr(layer, "pools", None) or [layer]
+    sets = [s for p in pools for s in getattr(p, "sets", [p])]
+    backends = sorted({c.backend for c in (
+        getattr(s, "_codec", None) for s in sets) if c is not None})
+    out = {"backends": backends, "device": None,
+           "md5": md5fast.backend_status(),
+           "native": nativelib.status()}
+    if any(b != "numpy" for b in backends):
+        from ..ops import device
+        out["device"] = device.describe()
+    return out
 
 
 def _config(h, srv, route, q1, payload, send_json) -> bool:

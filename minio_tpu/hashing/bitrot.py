@@ -112,42 +112,29 @@ def streaming_encode_batch(shards, shard_size: int,
     """Frame a full stripe of equal-length shard files at once.
 
     With use_device, the per-block HighwayHash runs ON the TPU
-    (ops/hh_kernels), fused after the erasure encode so parity AND
-    bitrot digests come out of one device pipeline (BASELINE config 5).
-    Falls back to the host C path on any device failure."""
+    (ops/hh_pallas), after the erasure encode, so parity AND bitrot
+    digests come off the device (BASELINE config 5).  A device failure
+    raises: the host C path never stands in for it silently."""
     if not is_streaming(algo):
         return [bytes(bytearray(s)) for s in shards]
-    if use_device and algo == HIGHWAYHASH256S and shards:
-        try:
-            import time as _time
+    if use_device and shards:
+        import time as _time
 
-            from ..obs import trace as _trace
-            if not _trace.active():
-                return _streaming_encode_batch_device(shards, shard_size)
-            # fused-hash span (trace type ``tpu``): the device-side
-            # HighwayHash leg of the fused encode+hash pipeline.
-            # Monotonic duration, wall clock only for the timestamp.
-            t0 = _time.monotonic_ns()
-            out = _streaming_encode_batch_device(shards, shard_size)
-            try:
-                # span bookkeeping must never reroute the data path:
-                # an observability error here would otherwise be
-                # swallowed by the DEVICE-failure fallback below and
-                # throw away a completed device result
-                dt = _time.monotonic_ns() - t0
-                nbytes = sum(getattr(s, "nbytes", len(s))
-                             for s in shards)
-                _trace.publish_span(_trace.make_span(
-                    "tpu", "tpu.fused-hash",
-                    start_ns=_trace.now_ns() - dt,
-                    duration_ns=dt, input_bytes=nbytes,
-                    detail={"op": "fused-hash", "shards": len(shards),
-                            "shardSize": shard_size}))
-            except Exception:  # noqa: BLE001 — tracing must never
-                pass           # fail the hash path
-            return out
-        except Exception:  # noqa: BLE001 — host path is always correct
-            pass
+        from ..obs import trace as _trace
+        t0 = _time.monotonic_ns()
+        out = _streaming_encode_batch_device(shards, shard_size)
+        if _trace.active():
+            # device-hash span (trace type ``tpu``); monotonic duration,
+            # wall clock only for the timestamp
+            dt = _time.monotonic_ns() - t0
+            _trace.publish_span(_trace.make_span(
+                "tpu", "tpu.fused-hash", start_ns=_trace.now_ns() - dt,
+                duration_ns=dt,
+                input_bytes=sum(getattr(s, "nbytes", len(s))
+                                for s in shards),
+                detail={"op": "fused-hash", "shards": len(shards),
+                        "shardSize": shard_size}))
+        return out
     # streaming_encode takes any contiguous buffer zero-copy (numpy
     # shard rows included) — don't round-trip through bytes()
     return [streaming_encode(s, shard_size, algo) for s in shards]
@@ -178,10 +165,10 @@ def fill_framed(framed2d, shard_size: int,
 
 
 def _device_hh256_batch(blocks):
-    """Best device formulation: single fused pallas kernel on TPU,
-    lax.scan packet loop elsewhere (both bit-identical)."""
-    import jax
-    if jax.default_backend() == "tpu":
+    """Single fused pallas kernel on a TPU, lax.scan packet loop
+    elsewhere (both bit-identical; ops/device.py decides)."""
+    from ..ops import device
+    if device.use_pallas():
         from ..ops import hh_pallas
         return hh_pallas.hh256_batch(blocks)
     from ..ops import hh_kernels

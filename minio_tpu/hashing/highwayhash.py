@@ -23,7 +23,11 @@ MAGIC_KEY = (b"\x4b\xe7\x34\xfa\x8e\x23\x8a\xcd\x26\x3e\x83\xe6\xbb\x96\x85"
              b"\x52\x04\x0f\x93\x5d\xa3\x9f\x44\x14\x97\xe0\x9d\x13\x22\xde"
              b"\x36\xa0")
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "native")
+_NATIVE_SRC = os.path.join(os.path.dirname(__file__), "native",
+                           "highwayhash.c")
+# built where the other native libraries are (git-ignored native/build/)
+_NATIVE_SO = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), "native", "build", "libmt_hash.so")
 _LIB = None
 _LIB_TRIED = False
 
@@ -33,9 +37,7 @@ def _get_lib():
     if _LIB_TRIED:
         return _LIB
     from ..utils import nativelib
-    src = os.path.join(_NATIVE_DIR, "highwayhash.c")
-    so = os.path.join(_NATIVE_DIR, "libmt_hash.so")
-    lib = nativelib.load(src, so)
+    lib = nativelib.load(_NATIVE_SRC, _NATIVE_SO)
     if lib is not None:
         try:
             lib.mt_hh256.argtypes = [ctypes.c_char_p, ctypes.c_char_p,

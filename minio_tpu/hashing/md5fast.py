@@ -105,13 +105,22 @@ def available() -> bool:
 #              outranks the knob — the operator kill switch)
 #   auto    -> MEASURED choice: the device rung only when its probed
 #              end-to-end rate (md5_device.device_rate_gibps, transfer
-#              included) beats the host core by a margin.  A TPU
-#              behind a slow tunnel must lose this race — the platform
-#              name alone says nothing about H2D bandwidth.
+#              included) beats the host core by a margin — the platform
+#              name alone says nothing about what H2D plus a serial
+#              chain costs against a host core.
 
 _BACKEND = "auto"
 _AUTO_CHOICE: str | None = None
+_AUTO_DETAIL: dict = {}      # what the probe measured, or why it failed
 _AUTO_MARGIN = 1.25
+
+
+def backend_status() -> dict:
+    """The configured rung, what ``auto`` chose (None until its probe
+    has landed) and what the probe measured."""
+    return {"configured": _BACKEND, "auto_choice": _AUTO_CHOICE,
+            "auto_probe": dict(_AUTO_DETAIL),
+            "native_available": available()}
 
 
 def set_backend(name: str) -> None:
@@ -148,7 +157,7 @@ def _host_rate_gibps() -> float:
 def _resolve_backend() -> str:
     """The effective rung for this digest: env override first, then
     the knob, with ``auto`` resolved (and cached) by measurement."""
-    global _AUTO_CHOICE
+    global _AUTO_CHOICE, _AUTO_DETAIL
     env = os.environ.get("MT_MD5")
     env = env.strip().lower() if env is not None else None
     if env == "hashlib":
@@ -162,6 +171,7 @@ def _resolve_backend() -> str:
         from . import md5_device
         if not md5_device.available():
             _AUTO_CHOICE = "native"
+            _AUTO_DETAIL = {"error": md5_device.unavailable_reason()}
         else:
             # probe OFF the request path: device_rate_gibps pays an
             # XLA compile plus ~20 MiB of benchmark hashing — charged
@@ -185,18 +195,21 @@ def _start_auto_probe() -> None:
         _probe_started = True
 
     def probe():
-        global _AUTO_CHOICE, _probe_started
+        global _AUTO_CHOICE, _AUTO_DETAIL, _probe_started
         try:
             from . import md5_device
             dev = md5_device.device_rate_gibps()
             host = _host_rate_gibps()
             choice = "device" if dev > host * _AUTO_MARGIN \
                 else "native"
-        except Exception:  # noqa: BLE001 — a broken probe means host
+            detail = {"device_gibps": round(dev, 4),
+                      "host_gibps": round(host, 4)}
+        except Exception as e:  # noqa: BLE001 — a broken probe means host; backend_status() says why
             choice = "native"
+            detail = {"error": f"{type(e).__name__}: {e}"}
         with _probe_lock:
             if _AUTO_CHOICE is None:
-                _AUTO_CHOICE = choice
+                _AUTO_CHOICE, _AUTO_DETAIL = choice, detail
             _probe_started = False
 
     threading.Thread(target=probe, daemon=True,

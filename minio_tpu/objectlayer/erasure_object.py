@@ -495,14 +495,12 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         blocks = max(1, STREAM_BATCH_BYTES // self.block_size)
         codec = self._codec
         if codec is not None and codec.backend == "mesh":
-            try:
-                from ..parallel import mesh as pmesh
-                devs = int(np.prod(list(
-                    pmesh.get_active_mesh().shape.values())))
-                cap = max(1, self._mesh_batch_cap // self.block_size)
-                blocks = max(blocks, min(blocks * max(1, devs), cap))
-            except Exception:  # noqa: BLE001 — mesh probe is advisory
-                pass
+            # the mesh the encode is about to run on: a mesh that cannot
+            # be built fails the PUT here rather than at the dispatch
+            from ..parallel import mesh as pmesh
+            devs = pmesh.get_active_mesh().devices.size
+            cap = max(1, self._mesh_batch_cap // self.block_size)
+            blocks = max(blocks, min(blocks * devs, cap))
         return blocks * self.block_size
 
     def put_object_stream(self, bucket: str, object_name: str, reader,

@@ -8,7 +8,12 @@ ShardFileOffset) with a pluggable backend:
   * ``tpu``   — batched bitplane MXU matmuls (rs_kernels.py), one chip
   * ``mesh``  — matmuls sharded over the active jax.sharding.Mesh with
                 ICI XOR fan-in (rs_mesh.py); 1-device mesh = single chip
-  * ``auto``  — tpu when an accelerator backend is initialized, else numpy
+  * ``auto``  — tpu when JAX's platform is a TPU, else numpy
+
+Which backend a name resolves to, and whether an explicit device backend
+may run at all, is ops/device.py's decision (``resolve_backend``): an
+explicit ``tpu``/``mesh`` without a TPU and without an explicit CPU
+opt-in raises instead of computing on the host under a device's name.
 
 Shard layout, padding, and matrix construction are bit-identical between
 backends (and with klauspost/reedsolomon's defaults).
@@ -16,7 +21,6 @@ backends (and with klauspost/reedsolomon's defaults).
 
 from __future__ import annotations
 
-import functools
 import time
 
 import numpy as np
@@ -41,13 +45,13 @@ class ErasureError(ValueError):
     pass
 
 
-@functools.lru_cache(maxsize=1)
-def _accelerator_present() -> bool:
-    try:
-        import jax
-        return jax.default_backend() != "cpu"
-    except Exception:  # pragma: no cover
-        return False
+def resolve_backend(backend: str) -> str:
+    """``auto``/``tpu``/``mesh``/``numpy`` -> the backend that will run
+    (ops/device.py; imported lazily so the host codec never loads JAX)."""
+    if backend == "numpy":
+        return backend
+    from . import device
+    return device.resolve_backend(backend)
 
 
 def _batcher(codec: "Erasure"):
@@ -82,23 +86,15 @@ class Erasure:
         self.data_blocks = data_blocks
         self.parity_blocks = parity_blocks
         self.block_size = int(block_size)
-        if backend == "auto":
-            backend = "tpu" if _accelerator_present() else "numpy"
-        if backend not in ("numpy", "tpu", "mesh"):
+        if backend not in ("auto", "numpy", "tpu", "mesh"):
             raise ErasureError(f"unknown backend {backend!r}")
-        self.backend = backend
+        self.backend = backend = resolve_backend(backend)
         # resolve the compute impl once; all modules expose the same
         # encode_parity/reconstruct surface
         if backend == "tpu":
-            try:
-                from . import rs_kernels as impl
-            except ImportError as e:
-                raise ErasureError(f"tpu backend unavailable: {e}") from e
+            from . import rs_kernels as impl
         elif backend == "mesh":
-            try:
-                from . import rs_mesh as impl
-            except ImportError as e:
-                raise ErasureError(f"mesh backend unavailable: {e}") from e
+            from . import rs_mesh as impl
         else:
             impl = gf8_ref
         self._impl = impl

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..hashing.highwayhash import MAGIC_KEY
+from . import device  # noqa: F401 — compile cache set before the first jit
 
 _U32 = jnp.uint32
 _MASK16 = np.uint32(0xFFFF)

@@ -38,7 +38,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..hashing.highwayhash import MAGIC_KEY
-from . import hh_kernels as hk
+from . import device, hh_kernels as hk
 
 _U32 = jnp.uint32
 
@@ -181,7 +181,7 @@ def _run_nat(x2d, n_packets, S):
         out_shape=jax.ShapeDtypeStruct((nb, 32, S, 128), _U32),
         scratch_shapes=[pltpu.VMEM((32, S, 128), _U32),
                         pltpu.VMEM((_PC_NAT * 32, S, 128), jnp.uint8)],
-        interpret=jax.default_backend() != "tpu",
+        interpret=device.interpret(),
     )(x2d)
 
 

@@ -44,7 +44,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import gf8, hh_pallas as hhp, hh_kernels as hk, rs_pallas
+from . import device, gf8, hh_pallas as hhp, hh_kernels as hk, rs_pallas
 
 _U32 = jnp.uint32
 # lane-tile ceiling: 2048 bytes = 64 packets per chunk, the same
@@ -166,11 +166,9 @@ def _kernel(m_ref, in_ref, par_ref, dig_ref, st, tbuf, *, k: int,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "k", "ro", "gs", "bs", "S", "pc", "n_packets", "hash_parity",
-    "interpret"))
+    "k", "ro", "gs", "bs", "S", "pc", "n_packets", "hash_parity"))
 def _fused_call(mat_bd, data, *, k: int, ro: int, gs: int, bs: int,
-                S: int, pc: int, n_packets: int, hash_parity: bool,
-                interpret: bool):
+                S: int, pc: int, n_packets: int, hash_parity: bool):
     """data: (B_pad, k, n_pad) uint8, B_pad % bs == 0, n_pad % tn == 0
     (caller pads).  Returns (parity (B_pad, ro, n_pad) u8,
     planes (B_pad//bs, 32, S, 128) u32 hash-state limbs)."""
@@ -197,7 +195,7 @@ def _fused_call(mat_bd, data, *, k: int, ro: int, gs: int, bs: int,
         ],
         scratch_shapes=[pltpu.VMEM((32, S, 128), _U32),
                         pltpu.VMEM((tn, S, 128), jnp.uint8)],
-        interpret=interpret,
+        interpret=device.interpret(),
     )(mat_bd, data)
 
 
@@ -239,8 +237,7 @@ def _digests_from_planes(planes, data, parity, *, k: int, ro: int,
 
 
 def encode_hash_device(M: np.ndarray, shards, *, n_real: int | None
-                       = None, hash_parity: bool = True,
-                       interpret: bool | None = None):
+                       = None, hash_parity: bool = True):
     """Single-kernel fused encode+hash; returns DEVICE arrays
     (parity (B, ro, n), digests (B, R, 32)) so callers chain further
     device work without a host round trip.
@@ -260,12 +257,9 @@ def encode_hash_device(M: np.ndarray, shards, *, n_real: int | None
     if p["n_pad"] != n:
         shards = jnp.pad(shards, ((0, 0), (0, 0), (0, p["n_pad"] - n)))
     mb = rs_pallas._device_matrix_bd(M.tobytes(), ro, k, p["gs"])
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     parity, planes = _fused_call(
         mb, shards, k=k, ro=ro, gs=p["gs"], bs=p["bs"], S=p["S"],
-        pc=p["pc"], n_packets=n_real // 32, hash_parity=hash_parity,
-        interpret=interpret)
+        pc=p["pc"], n_packets=n_real // 32, hash_parity=hash_parity)
     digests = _digests_from_planes(
         planes, shards, parity, k=k, ro=ro, bs=p["bs"], S=p["S"], B=B,
         n_real=n_real, hash_parity=hash_parity)
@@ -274,8 +268,7 @@ def encode_hash_device(M: np.ndarray, shards, *, n_real: int | None
 
 def encode_with_bitrot_fused(data_blocks: int, parity_blocks: int,
                              blocks: np.ndarray,
-                             matrix: np.ndarray | None = None,
-                             interpret: bool | None = None):
+                             matrix: np.ndarray | None = None):
     """rs_mesh.encode_with_bitrot's (parity, digests) contract through
     the single fused kernel — host numpy in, host numpy out, digests
     (B, k+m, 32) with data rows first."""
@@ -285,5 +278,5 @@ def encode_with_bitrot_fused(data_blocks: int, parity_blocks: int,
                                data_blocks + parity_blocks)
     rows = np.asarray(matrix)[data_blocks:]
     parity, digests = encode_hash_device(
-        rows, blocks, hash_parity=True, interpret=interpret)
+        rows, blocks, hash_parity=True)
     return np.asarray(parity), np.asarray(digests)

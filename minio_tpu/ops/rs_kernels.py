@@ -24,13 +24,12 @@ device dispatch, keeping the MXU fed (SURVEY.md section 7).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import gf8
+from . import device, gf8
 
 _LANES = 128    # TPU lane width; byte axis is padded to a lane multiple
 _MAX_BATCH = 64  # stripes per dispatch; batch axis is bucketed to powers of 2
@@ -79,18 +78,6 @@ def _put_matrix(M: np.ndarray) -> jax.Array:
     return _device_matrix(M.tobytes(), M.shape[0], M.shape[1])
 
 
-def _use_pallas() -> bool:
-    """Fused pallas kernel on real TPU (bit planes never touch HBM);
-    the XLA formulation elsewhere.  MT_RS_PALLAS=0 forces XLA on TPU,
-    =1 forces the pallas kernel (interpreter off-TPU) for testing."""
-    env = os.environ.get("MT_RS_PALLAS", "auto")
-    if env == "0":
-        return False
-    if env == "1":
-        return True
-    return jax.default_backend() == "tpu"
-
-
 def apply_matrix(M: np.ndarray, shards: np.ndarray | jax.Array) -> np.ndarray:
     """out[b] = M (GF) @ shards[b] for a batch of stripes.
 
@@ -100,7 +87,9 @@ def apply_matrix(M: np.ndarray, shards: np.ndarray | jax.Array) -> np.ndarray:
     squeeze = getattr(shards, "ndim", 3) == 2
     if squeeze:
         shards = shards[None]
-    pallas = _use_pallas()
+    # fused pallas kernel on a TPU (bit planes never touch HBM), the
+    # XLA formulation elsewhere — ops/device.py decides
+    pallas = device.use_pallas()
     if pallas:
         from . import rs_pallas
     else:

@@ -21,37 +21,24 @@ from __future__ import annotations
 import functools
 import os
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from minio_tpu.parallel import mesh as mesh_mod
-from . import gf8, rs_kernels
+from . import device, gf8, hh_pallas, rs_fused, rs_kernels, rs_pallas
 
-
-# version-compat shard_map resolution lives in parallel/mesh.py
-_shard_map_fn = mesh_mod._shard_map
-
-
-def _use_pallas() -> bool:
-    """On TPU the per-device compute runs the fused pallas bitplane
-    kernel (ops/rs_pallas.py, ~50 GiB/s/chip) with a ppermute ring
-    XOR-combining the PACKED parity bytes — per-chip pallas speed,
-    (S-1) x r x n bytes of ICI traffic (ring-allreduce optimal).  The
-    XLA psum formulation stays as the portable path (CPU virtual mesh,
-    and anywhere Mosaic is unavailable); MT_MESH_PALLAS=1/0 overrides
-    for tests."""
-    env = os.environ.get("MT_MESH_PALLAS", "")
-    if env in ("0", "1"):
-        return env == "1"
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+# Two per-device engines behind every entry point here, chosen by
+# ops/device.py (use_pallas): on a TPU the fused pallas bitplane kernel
+# (ops/rs_pallas.py) with a ppermute ring XOR-combining the PACKED
+# parity bytes — (S-1) x r x n bytes of ICI traffic, ring-allreduce
+# optimal; off it the XLA psum formulation (parallel/mesh.py) on the
+# virtual CPU mesh.
 
 
 @functools.lru_cache(maxsize=64)
-def _sharded_apply_pallas(mesh, r: int, kl: int, gs: int, tn: int,
-                          interpret: bool):
+def _sharded_apply_pallas(mesh, r: int, kl: int, gs: int, tn: int):
     """shard_map'd per-device pallas matmul + packed-byte ring XOR.
 
     GF(2) addition of packed parity bytes IS XOR, so partial parities
@@ -59,19 +46,13 @@ def _sharded_apply_pallas(mesh, r: int, kl: int, gs: int, tn: int,
     accumulator ever crosses ICI (a psum of the pre-packed accumulator
     would carry 32x the bytes and erase the kernel's HBM advantage).
     """
-    import jax
-    from jax.sharding import PartitionSpec as P
-    from . import rs_pallas
-
     S = mesh.shape["shard"]
     perm = [(j, (j + 1) % S) for j in range(S)]
 
     def local(mats, data):
         # mats: (1, gs*8r, gs*8kl) int8 — this device's column slice;
         # data: (B/T, kl, n) uint8
-        part = rs_pallas._gf2_apply_bm(mats[0], data,
-                                       interpret=interpret,
-                                       gs=gs, tn=tn)
+        part = rs_pallas._gf2_apply_bm(mats[0], data, gs=gs, tn=tn)
         if S == 1:
             return part
 
@@ -80,24 +61,15 @@ def _sharded_apply_pallas(mesh, r: int, kl: int, gs: int, tn: int,
 
         return jax.lax.fori_loop(0, S - 1, step, part)
 
-    specs = dict(in_specs=(P("shard", None, None),
-                           P("stripe", "shard", None)),
-                 out_specs=P("stripe", None, None))
-    smap = _shard_map_fn()
-    try:
-        fn = smap(local, mesh=mesh, check_vma=False, **specs)
-    except TypeError:                      # older JAX spells it check_rep
-        fn = smap(local, mesh=mesh, check_rep=False, **specs)
-    return jax.jit(fn)
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, check_vma=False,
+        in_specs=(P("shard", None, None), P("stripe", "shard", None)),
+        out_specs=P("stripe", None, None)))
 
 
 def _apply_pallas(m, rows: np.ndarray, shards: np.ndarray) -> np.ndarray:
     """Mesh apply with the pallas per-device engine; pads B to the
     stripe x gs grid, k to the shard axis, n to the lane tile."""
-    import jax
-    import jax.numpy as jnp
-    from . import rs_pallas
-
     T, S = m.shape["stripe"], m.shape["shard"]
     B, k, n = shards.shape
     r = rows.shape[0]
@@ -127,8 +99,7 @@ def _apply_pallas(m, rows: np.ndarray, shards: np.ndarray) -> np.ndarray:
             np.ascontiguousarray(rows[:, j * kl:(j + 1) * kl])
             .tobytes(), r, kl, gs)
         for j in range(S)])
-    interpret = jax.default_backend() != "tpu"
-    fn = _sharded_apply_pallas(m, r, kl, gs, tn, interpret)
+    fn = _sharded_apply_pallas(m, r, kl, gs, tn)
     out = np.asarray(fn(mats, jnp.asarray(shards)))
     return out[:B, :, :n]
 
@@ -145,7 +116,7 @@ def apply_matrix(rows: np.ndarray, shards) -> np.ndarray:
     if squeeze:
         shards = shards[None]
     m = mesh_mod.get_active_mesh()
-    if _use_pallas():
+    if device.use_pallas():
         rows8 = np.asarray(rows, dtype=np.uint8)
         out = _apply_pallas(m, rows8, shards)
         return out[0] if squeeze else out
@@ -193,8 +164,7 @@ def reconstruct_batch(shards: np.ndarray, present: list[int],
 
 @functools.lru_cache(maxsize=64)
 def _fused_pallas_single(mesh, r: int, kl: int, gs: int, bs: int,
-                         S_h: int, pc: int, n_real: int, hp: bool,
-                         interpret: bool):
+                         S_h: int, pc: int, n_real: int, hp: bool):
     """Fused encode+bitrot through the SINGLE-kernel formulation
     (ops/rs_fused.py): per device the data tile crosses HBM once —
     parity is computed and hashed from the VMEM-resident tiles.  When
@@ -202,20 +172,14 @@ def _fused_pallas_single(mesh, r: int, kl: int, gs: int, bs: int,
     ring XOR, so the kernel hashes only the data lanes (hp=False) and
     the parity digests run post-ring on the small parity rows; a
     1-wide shard axis hashes everything in-kernel (hp=True)."""
-    import jax
-    from jax.sharding import PartitionSpec as P
-    from . import hh_pallas, rs_fused
-
     S = mesh.shape["shard"]
     perm = [(j, (j + 1) % S) for j in range(S)]
 
     def local(mats, data):
-        import jax.numpy as jnp
         b = data.shape[0]
         part, planes = rs_fused._fused_call(
             mats[0], data, k=kl, ro=r, gs=gs, bs=bs, S=S_h, pc=pc,
-            n_packets=n_real // 32, hash_parity=hp,
-            interpret=interpret)
+            n_packets=n_real // 32, hash_parity=hp)
         if S > 1:
             def step(_, acc):
                 return jax.lax.ppermute(acc, "shard", perm) ^ part
@@ -238,39 +202,27 @@ def _fused_pallas_single(mesh, r: int, kl: int, gs: int, bs: int,
                                        tiled=True)
         return parity, jnp.concatenate([d_dig, p_dig], axis=1)
 
-    specs = dict(in_specs=(P("shard", None, None),
-                           P("stripe", "shard", None)),
-                 out_specs=(P("stripe", None, None),
-                            P("stripe", None, None)))
-    smap = _shard_map_fn()
-    try:
-        fn = smap(local, mesh=mesh, check_vma=False, **specs)
-    except TypeError:
-        fn = smap(local, mesh=mesh, check_rep=False, **specs)
-    return jax.jit(fn)
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, check_vma=False,
+        in_specs=(P("shard", None, None), P("stripe", "shard", None)),
+        out_specs=(P("stripe", None, None), P("stripe", None, None))))
 
 
 @functools.lru_cache(maxsize=64)
 def _fused_pallas(mesh, r: int, kl: int, gs: int, tn: int,
-                  n_real: int, interpret: bool):
+                  n_real: int):
     """Fused encode+bitrot, pallas per-chip form: local pallas matmul
     on this device's k-slice, packed-byte ring XOR for the parity, and
     the pallas HighwayHash kernel over the UNPADDED shard widths
     (digests must never cover lane-tile padding); data digests ride an
     all_gather, parity digests compute post-ring on the replicated
     parity."""
-    import jax
-    from jax.sharding import PartitionSpec as P
-    from . import hh_pallas, rs_pallas
-
     S = mesh.shape["shard"]
     perm = [(j, (j + 1) % S) for j in range(S)]
 
     def local(mats, data):
         b = data.shape[0]
-        part = rs_pallas._gf2_apply_bm(mats[0], data,
-                                       interpret=interpret,
-                                       gs=gs, tn=tn)
+        part = rs_pallas._gf2_apply_bm(mats[0], data, gs=gs, tn=tn)
         if S > 1:
             def step(_, acc):
                 return jax.lax.ppermute(acc, "shard", perm) ^ part
@@ -287,32 +239,19 @@ def _fused_pallas(mesh, r: int, kl: int, gs: int, tn: int,
         p_dig = hh_pallas.hh256_batch(
             parity[:, :, :n_real].reshape(b * rr, n_real)
         ).reshape(b, rr, 32)
-        import jax.numpy as jnp
         return parity, jnp.concatenate([d_dig, p_dig], axis=1)
 
-    specs = dict(in_specs=(P("shard", None, None),
-                           P("stripe", "shard", None)),
-                 out_specs=(P("stripe", None, None),
-                            P("stripe", None, None)))
-    smap = _shard_map_fn()
-    try:
-        fn = smap(local, mesh=mesh, check_vma=False, **specs)
-    except TypeError:
-        fn = smap(local, mesh=mesh, check_rep=False, **specs)
-    return jax.jit(fn)
-
-
-# single-kernel formulation state: None = untried, False = failed once
-# (a Mosaic rejection must not re-pay compile latency per dispatch —
-# the two-kernel pipeline below stays the proven fallback)
-_SINGLE_STATE: dict = {"ok": None}
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, check_vma=False,
+        in_specs=(P("shard", None, None), P("stripe", "shard", None)),
+        out_specs=(P("stripe", None, None), P("stripe", None, None))))
 
 
 def _use_single() -> bool:
-    env = os.environ.get("MT_FUSED_SINGLE", "")
-    if env in ("0", "1"):
-        return env == "1"
-    return _SINGLE_STATE["ok"] is not False
+    """Single fused kernel (ops/rs_fused.py) unless MT_FUSED_SINGLE=0
+    picks the two-kernel pipeline.  Whichever is chosen runs or raises:
+    neither is the other's fallback."""
+    return os.environ.get("MT_FUSED_SINGLE", "") != "0"
 
 
 def _encode_with_bitrot_single(m, data_blocks: int, parity_blocks: int,
@@ -322,10 +261,6 @@ def _encode_with_bitrot_single(m, data_blocks: int, parity_blocks: int,
     hash-state planes; padding mirrors _encode_with_bitrot_pallas
     (k up to the shard axis, B up to stripe x row-block, n up to the
     plan's lane tile)."""
-    import jax
-    import jax.numpy as jnp
-    from . import rs_fused, rs_pallas
-
     T, S = m.shape["stripe"], m.shape["shard"]
     B, k, n = blocks.shape
     r = parity_blocks
@@ -351,9 +286,8 @@ def _encode_with_bitrot_single(m, data_blocks: int, parity_blocks: int,
             np.ascontiguousarray(M[:, j * kl:(j + 1) * kl]).tobytes(),
             r, kl, p["gs"])
         for j in range(S)])
-    interpret = jax.default_backend() != "tpu"
     fn = _fused_pallas_single(m, r, kl, p["gs"], p["bs"], p["S"],
-                              p["pc"], n, hp, interpret)
+                              p["pc"], n, hp)
     parity, digests = fn(mats, jnp.asarray(blocks))
     parity = np.asarray(parity)[:B, :, :n]
     digests = np.asarray(digests)
@@ -365,10 +299,6 @@ def _encode_with_bitrot_single(m, data_blocks: int, parity_blocks: int,
 
 def _encode_with_bitrot_pallas(m, data_blocks: int, parity_blocks: int,
                                blocks: np.ndarray):
-    import jax
-    import jax.numpy as jnp
-    from . import rs_pallas
-
     T, S = m.shape["stripe"], m.shape["shard"]
     B, k, n = blocks.shape
     r = parity_blocks
@@ -398,8 +328,7 @@ def _encode_with_bitrot_pallas(m, data_blocks: int, parity_blocks: int,
             np.ascontiguousarray(M[:, j * kl:(j + 1) * kl]).tobytes(),
             r, kl, gs)
         for j in range(S)])
-    interpret = jax.default_backend() != "tpu"
-    fn = _fused_pallas(m, r, kl, gs, tn, n, interpret)
+    fn = _fused_pallas(m, r, kl, gs, tn, n)
     parity, digests = fn(mats, jnp.asarray(blocks))
     parity = np.asarray(parity)[:B, :, :n]
     digests = np.asarray(digests)
@@ -415,10 +344,10 @@ def encode_with_bitrot(data_blocks: int, parity_blocks: int,
     sharded pipeline: each device encodes its partial parity and hashes
     its own shard slice; digests ride an all_gather.
 
-    Two engines, same contract as apply_matrix: on TPU (or
-    MT_MESH_PALLAS=1) the per-device compute is the pallas matmul +
-    pallas HighwayHash with a packed-byte ppermute-ring XOR; elsewhere
-    the XLA psum formulation (mesh.distributed_encode_with_bitrot).
+    Two engines, same contract as apply_matrix: on a TPU the
+    per-device compute is the pallas matmul + pallas HighwayHash with a
+    packed-byte ppermute-ring XOR; elsewhere the XLA psum formulation
+    (mesh.distributed_encode_with_bitrot).
 
     Pads B up to the stripe axis and k up to the shard axis (padded
     shards are zero; their digests are computed but sliced off).
@@ -426,22 +355,10 @@ def encode_with_bitrot(data_blocks: int, parity_blocks: int,
     """
     m = mesh_mod.get_active_mesh()
     blocks = np.asarray(blocks, dtype=np.uint8)
-    if _use_pallas():
-        if _use_single():
-            try:
-                out = _encode_with_bitrot_single(
-                    m, data_blocks, parity_blocks, blocks)
-                _SINGLE_STATE["ok"] = True
-                return out
-            except Exception as e:  # noqa: BLE001 — two-kernel fallback
-                if _SINGLE_STATE["ok"] is None:
-                    import sys
-                    print(f"rs_mesh: single-kernel fused path failed "
-                          f"({type(e).__name__}: {e}); using the "
-                          f"two-kernel pipeline", file=sys.stderr)
-                _SINGLE_STATE["ok"] = False
-        return _encode_with_bitrot_pallas(
-            m, data_blocks, parity_blocks, blocks)
+    if device.use_pallas():
+        engine = _encode_with_bitrot_single if _use_single() \
+            else _encode_with_bitrot_pallas
+        return engine(m, data_blocks, parity_blocks, blocks)
     T, S = m.shape["stripe"], m.shape["shard"]
     B, k, n = blocks.shape
     padB, padK = (-B) % T, (-k) % S
@@ -454,7 +371,6 @@ def encode_with_bitrot(data_blocks: int, parity_blocks: int,
     if padK:
         Mp = np.concatenate(
             [Mp, np.zeros((Mp.shape[0], padK), np.uint8)], axis=1)
-    import jax.numpy as jnp
     M2 = jnp.asarray(gf8.gf2_expand(Mp), jnp.int8)
     fn = mesh_mod._fused_encode_hash(m, M2.shape[0], blocks.shape[1])
     parity, digests = fn(M2, jnp.asarray(blocks))

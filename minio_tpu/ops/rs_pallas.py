@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from . import gf8
+from . import device, gf8
 
 _LANES = 128
 # lanes per grid step: large enough that the (8r, 8k) @ (8k, TN) matmul
@@ -91,10 +91,9 @@ def _kernel(m_ref, in_ref, out_ref, *, k: int, ro: int, gs: int):
         out_ref[s] = out.astype(jnp.uint8)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "gs", "tn"))
+@functools.partial(jax.jit, static_argnames=("gs", "tn"))
 def _gf2_apply_bm(matrix_bd: jax.Array, data: jax.Array,
-                  interpret: bool = False, gs: int = _GS,
-                  tn: int = _TN) -> jax.Array:
+                  gs: int = _GS, tn: int = _TN) -> jax.Array:
     """matrix_bd: (gs*8r, gs*8k) int8 block-diagonal bit-major; data:
     (B, k, n) uint8 with B a multiple of gs and n a multiple of tn
     (caller pads both).  Returns (B, r, n) uint8."""
@@ -110,7 +109,7 @@ def _gf2_apply_bm(matrix_bd: jax.Array, data: jax.Array,
         ],
         out_specs=pl.BlockSpec((gs, ro, tn), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, ro, n), jnp.uint8),
-        interpret=interpret,
+        interpret=device.interpret(),
     )(matrix_bd, data)
 
 
@@ -129,8 +128,7 @@ def _device_matrix_bd(key: bytes, rows: int, cols: int,
     return jnp.asarray(bd)
 
 
-def apply_matrix(M: np.ndarray, shards, *,
-                 interpret: bool | None = None) -> jax.Array:
+def apply_matrix(M: np.ndarray, shards) -> jax.Array:
     """out[b] = M (GF) @ shards[b], fused pallas path.
 
     M: (r, k) uint8 GF coefficients; shards: (B, k, n) uint8 (device or
@@ -156,9 +154,7 @@ def apply_matrix(M: np.ndarray, shards, *,
     pad = (-n) % tn
     if pad:
         shards = jnp.pad(shards, ((0, 0), (0, 0), (0, pad)))
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    out = _gf2_apply_bm(mb, shards, interpret=interpret, gs=gs, tn=tn)
+    out = _gf2_apply_bm(mb, shards, gs=gs, tn=tn)
     if bpad and B > 1:
         out = out[:B]
     if pad:

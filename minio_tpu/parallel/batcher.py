@@ -137,12 +137,11 @@ def codec_for(data_blocks: int, parity_blocks: int, block_size: int,
               backend: str = "auto") -> Erasure:
     """The process-shared codec for one geometry (bounded registry: a
     pathological parade of one-off geometries evicts oldest)."""
-    if backend == "auto":
-        # normalize BEFORE keying: 'auto' resolves inside Erasure, and
-        # keying on the unresolved name would cache a second instance
-        # (and a second compiled-kernel cache line) per geometry
-        from ..ops.codec import _accelerator_present
-        backend = "tpu" if _accelerator_present() else "numpy"
+    # normalize BEFORE keying: 'auto' resolves inside Erasure, and
+    # keying on the unresolved name would cache a second instance
+    # (and a second compiled-kernel cache line) per geometry
+    from ..ops.codec import resolve_backend
+    backend = resolve_backend(backend)
     key = (int(data_blocks), int(parity_blocks), int(block_size),
            backend)
     with _CODEC_MU:

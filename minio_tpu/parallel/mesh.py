@@ -24,19 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from minio_tpu.ops import device  # noqa: F401 — compile cache set before the first jit
 from minio_tpu.ops import gf8
-
-
-def _shard_map():
-    """jax.shard_map moved to the top level in newer JAX; this image's
-    0.4.x still exports it from jax.experimental.shard_map — resolve
-    whichever exists (gated dependency, no pinned jax upgrade)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map as fn2
-    return fn2
-
 
 
 def make_mesh(devices=None, stripe: int | None = None,
@@ -117,7 +106,7 @@ def _sharded_apply(mesh: Mesh, n_rows: int, k: int):
     stripes over ``stripe``; partial products XOR-reduce via psum."""
     local = _local_gf2_kernel(
         n_rows, lambda acc: jax.lax.psum(acc, "shard"))
-    return jax.jit(_shard_map()(local, mesh=mesh, **_SPECS))
+    return jax.jit(jax.shard_map(local, mesh=mesh, **_SPECS))
 
 
 def distributed_apply(mesh: Mesh, M: np.ndarray,
@@ -192,11 +181,8 @@ def _ring_apply(mesh: Mesh, n_rows: int, k: int):
     # ring replication over 'shard' is real (every device ends with the
     # full sum) but not statically inferable through ppermute/fori_loop,
     # so replication checking is disabled for this kernel
-    try:
-        fn = _shard_map()(local, mesh=mesh, check_vma=False, **_SPECS)
-    except TypeError:                      # older JAX spells it check_rep
-        fn = _shard_map()(local, mesh=mesh, check_rep=False, **_SPECS)
-    return jax.jit(fn)
+    return jax.jit(jax.shard_map(local, mesh=mesh, check_vma=False,
+                                 **_SPECS))
 
 
 def _reconstruct_rows(data_blocks: int, parity_blocks: int,
@@ -239,7 +225,7 @@ def _grouped_apply(mesh: Mesh, n_rows: int, k: int):
     specs = dict(in_specs=(P("stripe", None, "shard"),
                            P("stripe", "shard", None)),
                  out_specs=P("stripe", None, None))
-    return jax.jit(_shard_map()(local, mesh=mesh, **specs))
+    return jax.jit(jax.shard_map(local, mesh=mesh, **specs))
 
 
 def distributed_reconstruct_mixed(
@@ -301,11 +287,8 @@ def _fused_encode_hash(mesh: Mesh, n_rows: int, k: int):
     specs = dict(in_specs=(P(None, "shard"), P("stripe", "shard", None)),
                  out_specs=(P("stripe", None, None),
                             P("stripe", None, None)))
-    try:
-        fn = _shard_map()(local, mesh=mesh, check_vma=False, **specs)
-    except TypeError:
-        fn = _shard_map()(local, mesh=mesh, check_rep=False, **specs)
-    return jax.jit(fn)
+    return jax.jit(jax.shard_map(local, mesh=mesh, check_vma=False,
+                                 **specs))
 
 
 def distributed_encode_with_bitrot(mesh: Mesh, data_blocks: int,

@@ -1,4 +1,4 @@
-"""S3 API error taxonomy + XML rendering (cmd/api-errors.go, ~300 codes in
+"""S3 API error catalogue + XML rendering (cmd/api-errors.go, ~300 codes in
 the reference; here the subset the implemented APIs can produce, extended as
 handlers land).
 """

@@ -141,7 +141,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="S3 frontend address")
     pn.add_argument("--set-drive-count", type=int, default=None)
     pn.add_argument("--backend", default="auto",
-                    choices=["auto", "tpu", "numpy"])
+                    choices=["auto", "tpu", "mesh", "numpy"])
     pn.add_argument("peers", nargs="+",
                     help="topology: id=host:rpcport=dir1,dir2 per node, "
                          "SAME order on every node")
@@ -152,11 +152,36 @@ def main(argv: list[str] | None = None) -> int:
     ps.add_argument("--secret-key", default=None)
     ps.add_argument("--set-drive-count", type=int, default=None)
     ps.add_argument("--backend", default="auto",
-                    choices=["auto", "tpu", "numpy"],
-                    help="erasure compute backend")
+                    choices=["auto", "tpu", "mesh", "numpy"],
+                    help="erasure compute backend: tpu = one chip, "
+                         "mesh = every chip JAX sees, numpy = host; an "
+                         "explicit tpu/mesh without a TPU exits unless "
+                         "JAX_PLATFORMS=cpu opts into the CPU")
     ps.add_argument("--block-size", type=int, default=None)
     ps.add_argument("--region", default="us-east-1")
     args = parser.parse_args(argv)
+
+    codec_line = ""
+    if args.command in ("node", "server"):
+        # resolve the codec backend ONCE, before anything is built, and
+        # say what it resolved to and on which device: the name the
+        # operator typed is not evidence of where the bytes are coded
+        from .ops.codec import resolve_backend
+        try:
+            backend = resolve_backend(args.backend)
+        except RuntimeError as e:           # ops.device.DeviceUnavailable
+            print(f"minio-tpu: {e}", file=sys.stderr, flush=True)
+            return 2
+        codec_line = f"backend={backend} (requested {args.backend})"
+        if backend != "numpy":
+            from .ops import device
+            d = device.describe()
+            codec_line += (
+                f" platform={d['platform']} device_kind={d['device_kind']!r}"
+                f" devices={d['device_count']} kernels={d['kernels']}"
+                f" compile_cache={d['compile_cache']['dir']}"
+                f" ({d['compile_cache']['entries']} entries)")
+        args.backend = backend
 
     if args.command == "node":
         from .cluster import NodeSpec, run_node
@@ -183,7 +208,7 @@ def main(argv: list[str] | None = None) -> int:
                              args.set_drive_count, backend=args.backend)
         shost = args.address.rpartition(":")[0] or "127.0.0.1"
         print(f"minio-tpu node {args.node_id}: rpc={node.rpc.endpoint} "
-              f"s3=http://{shost}:{srv.port}", flush=True)
+              f"s3=http://{shost}:{srv.port} {codec_line}", flush=True)
         try:
             srv.shutdown.wait()       # admin stop or Ctrl-C ends the node
         except KeyboardInterrupt:
@@ -210,8 +235,7 @@ def main(argv: list[str] | None = None) -> int:
     n = len(args.dirs)
     sdc = srv.layer.set_drive_count
     print(f"minio-tpu server: {n} drives, "
-          f"{n // sdc} set(s) x {sdc} drives, "
-          f"backend={args.backend}", flush=True)
+          f"{n // sdc} set(s) x {sdc} drives, {codec_line}", flush=True)
     print(f"S3 endpoint: http://{args.address}", flush=True)
     print(f"admin:       http://{args.address}/minio-tpu/admin/v1/info",
           flush=True)

@@ -236,13 +236,8 @@ def _huge_bytes_default() -> int:
     env = os.environ.get("MT_SOAK_HUGE_BYTES")
     if env:
         return int(env)
-    try:
-        import jax
-        if jax.default_backend() == "tpu":
-            return 1 << 30
-    except Exception:  # noqa: BLE001 — no jax means no mesh anyway
-        pass
-    return 32 << 20
+    from ..ops import device
+    return 1 << 30 if device.platform() == "tpu" else 32 << 20
 
 
 def forensic_drill_scenario(duration_s: float = 12.0) -> Scenario:

@@ -1,4 +1,4 @@
-"""Storage error taxonomy — mirrors cmd/storage-errors.go semantics.
+"""Storage error catalogue — mirrors cmd/storage-errors.go semantics.
 
 Typed exceptions instead of Go sentinel errors; the quorum/reduce logic in
 the object layer matches on these types the way the reference matches on

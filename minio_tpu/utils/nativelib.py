@@ -1,16 +1,24 @@
 """Shared build-on-demand loader for the native (C/C++) helper libraries.
 
-Three subsystems carry native kernels — snappy compression
-(native/snappy.cc), HighwayHash (hashing/native/highwayhash.c) and the
-GF(2^8) erasure matmul (native/gf8.cc) — the roles the reference fills
-with assembly-accelerated Go modules (SURVEY.md §2.4).  They all share
-one loading discipline, implemented once here:
+Five subsystems carry native kernels — snappy compression
+(native/snappy.cc), HighwayHash (hashing/native/highwayhash.c), the
+GF(2^8) erasure matmul (native/gf8.cc), multi-buffer md5
+(native/md5mb.cc) and the NDJSON scanner (native/jsonscan.cc) — the
+roles the reference fills with assembly-accelerated Go modules
+(SURVEY.md §2.4).  They all share one loading discipline, implemented
+once here:
 
-* rebuild when the .so is missing or older than the source;
+* the built file is keyed by its source's content (and the compiler and
+  flags): ``libfoo.so`` is built and loaded as ``libfoo.<hash>.so``, so
+  a library built from other source — copied along with the tree, or
+  left over from an earlier checkout — is never loaded, whatever its
+  mtime.  Nothing built is committed; native/build/ is git-ignored;
 * compile to a temp file and os.replace it (atomic under concurrent
   processes);
 * honor MT_NATIVE=0 (force the pure-Python fallbacks) and CC;
-* never raise: a missing compiler returns None and callers fall back.
+* never raise: a missing compiler returns None and callers fall back —
+  but never silently: ``status()`` says, per library, whether it loaded
+  and why not (admin ``info`` and ``chip_smoke.py`` read it back).
 
 Thread-safe: a per-path lock guarantees a library is built and loaded
 exactly once, and concurrent first callers WAIT for the build instead of
@@ -20,6 +28,7 @@ silently taking the slow path.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -27,10 +36,19 @@ import threading
 _meta_lock = threading.Lock()
 _locks: dict[str, threading.Lock] = {}
 _cache: dict[str, ctypes.CDLL | None] = {}
+_status: dict[str, dict] = {}
+
+
+def status() -> dict[str, dict]:
+    """Per library this process tried to load: the file, whether it
+    loaded, and the reason when it did not."""
+    with _meta_lock:
+        return {k: dict(v) for k, v in _status.items()}
 
 
 def load(src: str, so: str, timeout: int = 120) -> ctypes.CDLL | None:
-    """Build (if stale) and load `src` into `so`; None when unavailable.
+    """Build (unless already built from this very source) and load
+    `src` as `so` (content-keyed, see above); None when unavailable.
 
     Idempotent per `so` path; concurrent callers of the SAME library
     block until the first build finishes rather than observing a
@@ -50,22 +68,33 @@ def load(src: str, so: str, timeout: int = 120) -> ctypes.CDLL | None:
     with lock:
         if so in _cache:
             return _cache[so]
-        lib = None
-        if os.environ.get("MT_NATIVE", "1") != "0":
+        lib, built, err = None, so, ""
+        if os.environ.get("MT_NATIVE", "1") == "0":
+            err = "MT_NATIVE=0"
+        else:
             try:
-                if not os.path.exists(so) or (
-                        os.path.getmtime(so) < os.path.getmtime(src)):
-                    os.makedirs(os.path.dirname(so), exist_ok=True)
-                    tmp = so + f".tmp{os.getpid()}"
-                    cc = os.environ.get("CC", "g++" if src.endswith(
-                        (".cc", ".cpp")) else "cc")
+                cc = os.environ.get("CC", "g++" if src.endswith(
+                    (".cc", ".cpp")) else "cc")
+                cmd = [cc, "-O3", "-shared", "-fPIC", *extra]
+                with open(src, "rb") as f:
+                    key = hashlib.sha256(
+                        f.read() + "\0".join(cmd).encode()).hexdigest()[:16]
+                built = f"{so.removesuffix('.so')}.{key}.so"
+                if not os.path.exists(built):
+                    os.makedirs(os.path.dirname(built), exist_ok=True)
+                    tmp = built + f".tmp{os.getpid()}"
                     subprocess.run(  # mt-lint: ok(lock-discipline) one-time lazy build: waiters NEED the .so this compile produces; double-checked via _cache so it runs once per process
-                        [cc, "-O3", "-shared", "-fPIC", *extra,
-                         "-o", tmp, src],
+                        [*cmd, "-o", tmp, src],
                         check=True, capture_output=True, timeout=timeout)
-                    os.replace(tmp, so)
-                lib = ctypes.CDLL(so)
-            except Exception:  # noqa: BLE001 — fallback path is Python
-                lib = None
+                    os.replace(tmp, built)
+                lib = ctypes.CDLL(built)
+            except Exception as e:  # noqa: BLE001 — fallback path is Python; the reason is kept for status()
+                err = f"{type(e).__name__}: {e}"
+                stderr = getattr(e, "stderr", None)
+                if stderr:
+                    err += " | " + stderr.decode(errors="replace")[-400:]
         _cache[so] = lib
+        with _meta_lock:
+            _status[os.path.basename(so)] = {
+                "file": built, "loaded": lib is not None, "error": err}
         return lib

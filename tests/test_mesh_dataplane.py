@@ -224,11 +224,11 @@ def test_pallas_ring_engine_bit_identical(monkeypatch):
     """The TPU-default mesh engine: per-device fused pallas kernel +
     packed-byte XOR over a ppermute ring (GF(2) addition of packed
     parity IS XOR, so no int32 accumulator crosses ICI).  Forced on
-    here (MT_MESH_PALLAS=1, interpret mode on CPU) and asserted
+    here (MT_PALLAS=1, interpret mode on CPU) and asserted
     bit-identical with the numpy oracle across geometries including
     ragged k/B/n."""
     from minio_tpu.ops import gf8_ref
-    monkeypatch.setenv("MT_MESH_PALLAS", "1")
+    monkeypatch.setenv("MT_PALLAS", "1")
     prev = mesh_mod._ACTIVE
     mesh_mod.set_active_mesh(mesh_mod.make_mesh(stripe=2))
     try:

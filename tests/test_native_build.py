@@ -1,5 +1,6 @@
-"""Native toolchain smoke: every C/C++ helper in native/ must compile
-from a cold cache and load (utils/nativelib.py discipline), so a broken
+"""Native toolchain smoke: every C/C++ helper (native/ and
+hashing/native/highwayhash.c — the bit-identity oracle, no longer a
+committed binary) must compile from a cold cache and load (utils/nativelib.py discipline), so a broken
 toolchain is caught HERE with a named reason instead of silently
 degrading every consumer to its Python fallback — and a host with no
 compiler degrades to the fallbacks instead of failing tier-1.
@@ -8,7 +9,6 @@ compiler degrades to the fallbacks instead of failing tier-1.
 import ctypes
 import os
 import shutil
-import subprocess
 
 import pytest
 
@@ -22,12 +22,14 @@ SOURCES = {
     "snappy.cc": "mt_snappy_compress",
     "jsonscan.cc": "mt_ndjson_filter",
     "md5mb.cc": "mt_md5mb_update",
+    os.path.join(REPO, "minio_tpu", "hashing", "native",
+                 "highwayhash.c"): "mt_hh256_verify_framed",
 }
 
 
 def _have_compiler() -> bool:
-    cc = os.environ.get("CC", "g++")
-    return shutil.which(cc) is not None
+    return all(shutil.which(os.environ.get("CC", cc)) is not None
+               for cc in ("g++", "cc"))
 
 
 pytestmark = pytest.mark.skipif(
@@ -42,16 +44,38 @@ def test_source_compiles_cold_and_exports_symbol(tmp_path, monkeypatch,
     sanitizer-tier hook) — proves the checked-in source still compiles
     on this image, independent of any cached .so."""
     monkeypatch.setenv("MT_NATIVE_BUILD_DIR", str(tmp_path))
-    path = os.path.join(NATIVE, src)
-    so = os.path.join(str(tmp_path), "lib_smoke_" + src + ".so")
-    lib = nativelib.load(path, so)
+    path = os.path.join(NATIVE, src)       # absolute src passes through
+    name = "lib_smoke_" + os.path.basename(src) + ".so"
+    lib = nativelib.load(path, os.path.join(str(tmp_path), name))
+    st = nativelib.status()[name]
     if lib is None:
-        out = subprocess.run(
-            [os.environ.get("CC", "g++"), "-O3", "-shared", "-fPIC",
-             "-o", os.path.join(str(tmp_path), "direct.so"), path],
-            capture_output=True, text=True)
-        pytest.fail(f"{src} failed to build: {out.stderr[-2000:]}")
+        pytest.fail(f"{src} failed to build: {st['error'][-2000:]}")
     assert getattr(lib, symbol, None) is not None
+    # built under a name keyed by the source's content, and reported
+    assert st["loaded"] and os.path.exists(st["file"])
+    assert os.path.basename(st["file"]) != name
+
+
+def test_stale_library_is_never_loaded(tmp_path, monkeypatch):
+    """A .so sitting at the nominal path — copied along with a tree,
+    built from other source, newer than the source — must not be what
+    gets loaded: the loader only opens the file keyed by THIS source."""
+    monkeypatch.setenv("MT_NATIVE_BUILD_DIR", str(tmp_path))
+    src = tmp_path / "probe.c"
+    src.write_text("int mt_probe(void) { return 1; }\n")
+    so = str(tmp_path / "libprobe.so")
+    with open(so, "wb") as f:
+        f.write(b"not a shared object")       # stale/garbage, newer mtime
+    lib = nativelib.load(str(src), so)
+    assert lib is not None and lib.mt_probe() == 1
+    first = nativelib.status()["libprobe.so"]["file"]
+    # the source changes -> another key -> a fresh build, even though
+    # the old library is still there and newer than nothing
+    src.write_text("int mt_probe(void) { return 2; }\n")
+    nativelib._cache.pop(so)
+    lib2 = nativelib.load(str(src), so)
+    assert lib2.mt_probe() == 2
+    assert nativelib.status()["libprobe.so"]["file"] != first
 
 
 def test_md5_core_digest_after_cold_build(tmp_path, monkeypatch):

@@ -40,7 +40,7 @@ def test_bitmajor_expansion_equivalent():
 def test_encode_matches_reference(k, m):
     data = _rand((3, k, 300), seed=k)
     M = np.asarray(gf8.rs_matrix(k, k + m))
-    got = np.asarray(rs_pallas.apply_matrix(M[k:], data, interpret=True))
+    got = np.asarray(rs_pallas.apply_matrix(M[k:], data))
     want = np.stack([gf8.gf_matmul(M[k:], d) for d in data])
     np.testing.assert_array_equal(got, want)
 
@@ -49,7 +49,7 @@ def test_matches_xla_formulation():
     k, m = 12, 4
     data = _rand((2, k, 1000), seed=7)
     M = np.asarray(gf8.rs_matrix(k, k + m))
-    got = np.asarray(rs_pallas.apply_matrix(M[k:], data, interpret=True))
+    got = np.asarray(rs_pallas.apply_matrix(M[k:], data))
     want = rs_kernels.apply_matrix(np.asarray(M[k:]), data)
     np.testing.assert_array_equal(got, np.asarray(want))
 
@@ -58,14 +58,14 @@ def test_decode_roundtrip():
     k, m = 12, 4
     M = np.asarray(gf8.rs_matrix(k, k + m))
     data = _rand((2, k, 200), seed=3)
-    parity = np.asarray(rs_pallas.apply_matrix(M[k:], data, interpret=True))
+    parity = np.asarray(rs_pallas.apply_matrix(M[k:], data))
     # lose shards 0 and 1; reconstruct from 2..13
     present = list(range(2, k + 2))
     rows = rs_kernels.decode_rows(M, k, present, [0, 1])
     full = np.concatenate([data, parity], axis=1)
     survivors = full[:, present, :]
     rebuilt = np.asarray(
-        rs_pallas.apply_matrix(rows, survivors, interpret=True))
+        rs_pallas.apply_matrix(rows, survivors))
     np.testing.assert_array_equal(rebuilt, full[:, :2, :])
 
 
@@ -73,15 +73,15 @@ def test_rs_kernels_dispatcher_pallas_branch(monkeypatch):
     """The production dispatcher (rs_kernels.apply_matrix) must produce
     identical results when routed through the pallas kernel — this is
     the default TPU path but the CPU suite otherwise never runs it."""
-    monkeypatch.setenv("MT_RS_PALLAS", "1")
+    monkeypatch.setenv("MT_PALLAS", "1")
     k, m = 12, 4
     M = np.asarray(gf8.rs_matrix(k, k + m))
     for B, n in [(1, 300), (2, 128), (70, 1000)]:   # chunking + padding
         data = _rand((B, k, n), seed=B)
         got = rs_kernels.apply_matrix(np.asarray(M[k:]), data)
-        monkeypatch.setenv("MT_RS_PALLAS", "0")
+        monkeypatch.setenv("MT_PALLAS", "0")
         want = rs_kernels.apply_matrix(np.asarray(M[k:]), data)
-        monkeypatch.setenv("MT_RS_PALLAS", "1")
+        monkeypatch.setenv("MT_PALLAS", "1")
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     # 2-D squeeze contract
     data2 = _rand((k, 257), seed=9)
@@ -98,6 +98,6 @@ def test_lane_padding_roundtrip():
     for n in (1, 127, 128, 129, 4097):
         data = _rand((1, k, n), seed=n)
         got = np.asarray(
-            rs_pallas.apply_matrix(M[k:], data, interpret=True))
+            rs_pallas.apply_matrix(M[k:], data))
         want = gf8.gf_matmul(M[k:], data[0])
         np.testing.assert_array_equal(got[0], want)
