@@ -241,9 +241,12 @@ def where_the_time_went(endpoint: str) -> dict:
                "mt_s3_stage_seconds_count", "mt_tpu_kernel_seconds_sum",
                "mt_tpu_kernel_seconds_count")
     out = {"s3_stage": {}, "codec_dispatch": {}}
-    for (api, stage), v in m["mt_s3_stage_seconds_sum"].items():
-        n = m["mt_s3_stage_seconds_count"].get((api, stage), 0)
-        out["s3_stage"].setdefault(api, {})[stage] = \
+    for (api, stage, vec), v in m["mt_s3_stage_seconds_sum"].items():
+        n = m["mt_s3_stage_seconds_count"].get((api, stage, vec), 0)
+        # serial stages of one API add up to its wall; async detail
+        # overlaps it and is kept apart
+        name = stage if vec == "serial" else f"{stage} (async)"
+        out["s3_stage"].setdefault(api, {})[name] = \
             {"seconds": round(v, 3), "count": int(n)}
     for (backend, op), v in m["mt_tpu_kernel_seconds_sum"].items():
         n = m["mt_tpu_kernel_seconds_count"].get((backend, op), 0)

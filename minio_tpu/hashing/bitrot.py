@@ -118,23 +118,15 @@ def streaming_encode_batch(shards, shard_size: int,
     if not is_streaming(algo):
         return [bytes(bytearray(s)) for s in shards]
     if use_device and shards:
-        import time as _time
-
-        from ..obs import trace as _trace
-        t0 = _time.monotonic_ns()
-        out = _streaming_encode_batch_device(shards, shard_size)
-        if _trace.active():
-            # device-hash span (trace type ``tpu``); monotonic duration,
-            # wall clock only for the timestamp
-            dt = _time.monotonic_ns() - t0
-            _trace.publish_span(_trace.make_span(
-                "tpu", "tpu.fused-hash", start_ns=_trace.now_ns() - dt,
-                duration_ns=dt,
-                input_bytes=sum(getattr(s, "nbytes", len(s))
-                                for s in shards),
-                detail={"op": "fused-hash", "shards": len(shards),
-                        "shardSize": shard_size}))
-        return out
+        from ..ops import codec as _codec
+        # counted and timed like a codec dispatch (op ``hash``); its
+        # kernels are the one-chip forms whichever codec backend asked
+        with _codec.dispatch_span(
+                "hash", "tpu",
+                sum(getattr(s, "nbytes", len(s)) for s in shards),
+                lambda: {"op": "hash", "shards": len(shards),
+                         "shardSize": shard_size}):
+            return _streaming_encode_batch_device(shards, shard_size)
     # streaming_encode takes any contiguous buffer zero-copy (numpy
     # shard rows included) — don't round-trip through bytes()
     return [streaming_encode(s, shard_size, algo) for s in shards]
@@ -165,42 +157,53 @@ def fill_framed(framed2d, shard_size: int,
 
 
 def _device_hh256_batch(blocks):
-    """Single fused pallas kernel on a TPU, lax.scan packet loop
-    elsewhere (both bit-identical; ops/device.py decides)."""
+    """Digests of (B, n) host blocks, (B, 32) uint8 back on the host.
+    Single fused pallas kernel on a TPU, lax.scan packet loop elsewhere
+    (both bit-identical; ops/device.py decides).  Three legs:
+    ``hash.upload`` hands the bytes to JAX; ``hash.launch`` is the call
+    of ``hh256_batch`` to its return — slice, pad, kernel, the eager
+    reassembly chain, finalize — until the digests' handle is held;
+    ``hash.fetch`` waits for them and copies them down."""
+    from ..obs import trace as _trace
     from ..ops import device
     if device.use_pallas():
-        from ..ops import hh_pallas
-        return hh_pallas.hh256_batch(blocks)
-    from ..ops import hh_kernels
-    return hh_kernels.hh256_batch(blocks)
+        from ..ops import hh_pallas as hh
+    else:
+        from ..ops import hh_kernels as hh
+    blocks = device.upload("hash", blocks)
+    with _trace.span("tpu", "hash.launch", nbytes=blocks.nbytes):
+        digests = hh.hh256_batch(blocks)
+    return device.fetch("hash", digests)
 
 
 def _streaming_encode_batch_device(shards, shard_size: int) -> list[bytes]:
     import numpy as np
-    arrs = [np.asarray(bytearray(s), dtype=np.uint8) for s in shards]
-    L = len(arrs[0])
-    if L == 0:
-        return [b"" for _ in arrs]
-    if any(len(a) != L for a in arrs):
-        raise ValueError("shard lengths differ")
-    nblocks = ceil_frac(L, shard_size)
-    full, rem = divmod(L, shard_size)
-    stacked = np.stack(arrs)                       # (S, L)
-    digests: list[list[bytes]] = [[] for _ in arrs]
-    if full:
-        blocks = stacked[:, :full * shard_size].reshape(-1, shard_size)
-        hs = np.asarray(_device_hh256_batch(blocks))
-        hs = hs.reshape(len(arrs), full, 32)
-        for si in range(len(arrs)):
-            digests[si] = [hs[si, b].tobytes() for b in range(full)]
-    if rem:
-        tails = stacked[:, full * shard_size:]
-        hs = np.asarray(_device_hh256_batch(tails))
-        for si in range(len(arrs)):
-            digests[si].append(hs[si].tobytes())
-    assert all(len(d) == nblocks for d in digests)
-    return [_interleave(arrs[si].tobytes(), shard_size, digests[si])
-            for si in range(len(arrs))]
+
+    from ..obs import trace as _trace
+    with _trace.span("tpu", "hash.prep") as sp:
+        arrs = [np.asarray(bytearray(s), dtype=np.uint8) for s in shards]
+        L = len(arrs[0])
+        if L == 0:
+            return [b"" for _ in arrs]
+        if any(len(a) != L for a in arrs):
+            raise ValueError("shard lengths differ")
+        full, rem = divmod(L, shard_size)
+        stacked = np.stack(arrs)                       # (S, L)
+        sp.nbytes = stacked.nbytes
+        blocks = stacked[:, :full * shard_size].reshape(-1, shard_size) \
+            if full else None
+    hs_full = _device_hh256_batch(blocks).reshape(len(arrs), full, 32) \
+        if full else None
+    hs_tail = _device_hh256_batch(stacked[:, full * shard_size:]) \
+        if rem else None
+    with _trace.span("tpu", "hash.frame", nbytes=stacked.nbytes):
+        out = []
+        for si, arr in enumerate(arrs):
+            digests = [hs_full[si, b].tobytes() for b in range(full)]
+            if rem:
+                digests.append(hs_tail[si].tobytes())
+            out.append(_interleave(arr.tobytes(), shard_size, digests))
+        return out
 
 
 class StreamingBitrotWriter:

@@ -66,6 +66,21 @@ def _strict_compat() -> bool:
     return os.environ.get("MT_NO_COMPAT", "0").strip().lower() in (
         "", "0", "off", "false", "no")
 
+
+def _md5_timed(clock, fn, *args):
+    """One piece of the ETag md5, its wall charged to stage ``md5`` of
+    ``clock`` (the submitting request's StageClock: pool threads carry
+    none).  Always as async detail: on the pool the md5 overlaps encode
+    and commit, and on the request thread it sits inside a serial stage
+    or ``other``, so the serial vector reads what it read without it."""
+    t0 = time.monotonic_ns()
+    try:
+        return fn(*args)
+    finally:
+        if clock is not None:
+            clock.add_async("md5", time.monotonic_ns() - t0)
+
+
 DEFAULT_BLOCK_SIZE = 10 * 1024 * 1024   # blockSizeV1 (cmd/object-api-common.go:32)
 INLINE_THRESHOLD = 128 * 1024           # small-object inline into xl.meta
 ETAG_KEY = "etag"
@@ -530,6 +545,7 @@ class ErasureObjects(MultipartOps, ObjectLayer):
 
     def _put_object_bytes(self, bucket: str, object_name: str, data: bytes,
                           opts: PutObjectOptions) -> ObjectInfo:
+        from ..obs import stages as _stages
         self._check_bucket(bucket)
         n = len(self.disks)
         k, m = self._geometry(opts.parity)
@@ -545,7 +561,8 @@ class ErasureObjects(MultipartOps, ObjectLayer):
             # md5_of routes through the lane scheduler in 1 MiB slices:
             # concurrent PUTs' ETag passes coalesce into one multi-lane
             # native call instead of running two full serial chains
-            etag_future = self._pool.submit(md5fast.md5_of, data)
+            etag_future = self._pool.submit(
+                _md5_timed, _stages.current(), md5fast.md5_of, data)
         etag = None if etag_future is not None \
             else self._etag_for(data, opts)
         mod_time = opts.mod_time or now_ns()
@@ -565,7 +582,6 @@ class ErasureObjects(MultipartOps, ObjectLayer):
                 checksums=[ChecksumInfo(1, self.bitrot_algo)]),
             fresh=True)
 
-        from ..obs import stages as _stages
         with _stages.stage("encode"):
             framed = self._encode_and_frame(data, m, fi)
         inline = size <= self.inline_threshold
@@ -708,7 +724,9 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         (pkg/hash/reader.go:186, cmd/object-api-utils.go:843-855)."""
         if opts.content_md5 or (opts.preserve_etag is None
                                 and _strict_compat()):
-            etag = md5fast.md5(data).hexdigest()
+            from ..obs import stages as _stages
+            etag = _md5_timed(_stages.current(), md5fast.md5,
+                              data).hexdigest()
             if opts.content_md5 and etag != opts.content_md5.lower():
                 raise serrors.StorageError(
                     "Content-MD5 mismatch (BadDigest)")
@@ -947,7 +965,7 @@ class ErasureObjects(MultipartOps, ObjectLayer):
                 charge.release()
 
     @staticmethod
-    def _md5_link(prev, h, chunk, stats) -> None:
+    def _md5_link(prev, h, chunk, stats, clock) -> None:
         """One chained md5 update on the pool: waits for the previous
         link (updates are order-dependent), then hashes its chunk
         through the shared lane scheduler — concurrent streams'/parts'
@@ -963,11 +981,12 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         combiner hashes this chunk, or combining other streams'
         chunks), so per-PUT md5_s is a utilization view, not a pure
         hash cost — single-stream runs (the bench's pipelined leg) are
-        unaffected."""
+        unaffected.  The same wall is stage ``md5`` of the submitting
+        request's ``clock`` (:func:`_md5_timed`)."""
         if prev is not None:
             prev.result()
         t0 = time.perf_counter()
-        md5fast.SCHED.update(h, chunk)
+        _md5_timed(clock, md5fast.SCHED.update, h, chunk)
         stats["md5_s"] += time.perf_counter() - t0
 
     def _framed_fast_path(self, m: int) -> bool:
@@ -1027,6 +1046,8 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         md5_links: collections.deque = collections.deque()
         inflight: collections.deque = collections.deque()
         total = batches = 0
+        from ..obs import stages as _stages
+        clock = _stages.current()
         for chunk in chunks:
             total += len(chunk)
             batches += 1
@@ -1034,7 +1055,7 @@ class ErasureObjects(MultipartOps, ObjectLayer):
                 md5_links.append(self._pool.submit(
                     self._md5_link,
                     md5_links[-1] if md5_links else None,
-                    md5, chunk, stats))
+                    md5, chunk, stats, clock))
                 while len(md5_links) > depth:
                     md5_links.popleft().result()
             framed, release = self._encode_framed_pooled(
@@ -1046,7 +1067,6 @@ class ErasureObjects(MultipartOps, ObjectLayer):
                 # request thread parks behind the writer plane
                 t0 = time.perf_counter()
                 inflight.popleft().done.wait()
-                from ..obs import stages as _stages
                 _stages.add("write_enqueue",
                             int((time.perf_counter() - t0) * 1e9))
             alive = sw.alive()
@@ -1209,7 +1229,7 @@ class ErasureObjects(MultipartOps, ObjectLayer):
             src = readahead(chunks, depth=1) if readahead_body else chunks
             for chunk in src:
                 if md5 is not None:
-                    md5.update(chunk)
+                    _md5_timed(_stages.current(), md5.update, chunk)
                 total += len(chunk)
                 with _stages.stage("encode"):
                     framed = self._encode_and_frame(chunk, m, fi)

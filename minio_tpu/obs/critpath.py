@@ -28,9 +28,12 @@ names:
 Reconciliation contract: ``wall_ns`` is measured with the same
 monotonic clock as the StageClock stage that encloses the reduction,
 and the recorded child durations are offsets inside it — so
-``kth_ns <= wall_ns <= stage_ns`` holds exactly (pinned by
+``kth_ns <= wall_ns <= stage wall`` holds exactly (pinned by
 tests/test_trace_tree.py) the same way the serial stage vector plus
-``other`` reconciles with the request total.
+``other`` reconciles with the request total.  The stage WALL, not its
+entry in the serial vector: that entry is exclusive self time, and the
+stages charged inside the fan-out (``write_enqueue`` when an enqueue
+parks) are subtracted from it while the gating wall spans them.
 
 Idle contract: with no deep-trace consumer, one :func:`record` call is
 a sort of the (few) completion offsets, two metric updates, one

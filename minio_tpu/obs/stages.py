@@ -18,6 +18,10 @@ named stages as the request crosses them:
   ``memgov``         memory-governor admission accounting
   ``cache``          hot-read plane serve (hit validation included)
   ``encode``         erasure encode + bitrot framing (PUT)
+  ``md5``            the ETag md5, wherever it runs (async detail
+                     always: on the pool it overlaps ``encode`` and
+                     ``drive_commit``; on the request thread it is
+                     also inside a serial stage or ``other``)
   ``decode``         shard assembly / erasure decode (GET)
   ``batch_wait``     cross-request codec batcher queue wait
   ``drive_read``     shard-segment fan-out wall time (GET)
@@ -63,7 +67,7 @@ from typing import Optional
 # this set.
 STAGE_NAMES = (
     "admission", "auth", "policy", "body_read", "lock_wait", "memgov",
-    "cache", "encode", "decode", "batch_wait", "drive_read",
+    "cache", "encode", "md5", "decode", "batch_wait", "drive_read",
     "drive_commit", "write_enqueue", "write_drain", "body_write",
     "rpc", "other",
 )

@@ -12,8 +12,11 @@ pkg/trace.Type):
 
   ``storage``    per-drive-call spans (storage/xl_storage.py + remote.py)
   ``internode``  RPC client/server spans (parallel/rpc.py)
-  ``tpu``        erasure-kernel spans: encode/decode/matmul/fused-hash
-                 with shard geometry and bytes (ops/codec.py + friends)
+  ``tpu``        the device codec path, ``<op>.<leg>``: whole
+                 dispatches (``encode.dispatch``, ``hash.dispatch``,
+                 ``encode.batch`` ...) with shard geometry and bytes,
+                 and their legs (prep/upload/launch/fetch/frame), all
+                 through :class:`span` (ops/codec.py + friends)
   ``scanner``    data-crawler per-bucket spans (background/crawler.py)
   ``healing``    heal-sweep / MRF per-object spans (background/heal.py)
   ``replication``  per-object replication spans
@@ -27,6 +30,12 @@ spans emitted on a *peer* node still name the frontend request.
 Publishing is skipped entirely when nobody is subscribed, mirroring the
 reference's ``globalHTTPTrace.NumSubscribers() > 0`` guard — the hot
 path pays a single predicate (:func:`active`), no dict construction.
+
+:class:`span` is the one way a leg of the device codec path is timed:
+one enter/exit feeds the always-on span ring, the
+``mt_tpu_leg_seconds{op,leg}`` histogram and — when ops/device.py has
+installed an annotator — the profiler's own trace, so the program's
+spans sit on the device trace's clock.  This package never imports JAX.
 """
 
 from __future__ import annotations
@@ -39,6 +48,8 @@ import threading
 import time
 from typing import Any, Dict
 
+from ..admin.metrics import GLOBAL as _metrics
+from ..admin.metrics import KERNEL_BUCKETS
 from ..utils.pubsub import PubSub
 
 # global trace hub (reference: globalHTTPTrace)
@@ -363,6 +374,87 @@ def make_span(trace_type: str, func_name: str, *, start_ns: int,
         **({trace_type: detail} if detail else {}),
         **({"error": error} if error else {}),
     }
+
+
+# -- the span helper ----------------------------------------------------------
+
+# trace type -> the histogram family its helper spans observe, labelled
+# by the two halves of the span's ``<op>.<leg>`` name
+LEG_FAMILIES = {"tpu": "mt_tpu_leg_seconds"}
+
+# ``factory(name)`` -> a context manager entered and exited around every
+# helper span.  ops/device.py installs jax.profiler.TraceAnnotation when
+# it is imported (a ``--backend numpy`` server never imports it, and
+# this package never imports JAX); with no profiler session the
+# annotation is a no-op.
+_ANNOTATOR = None
+
+
+def set_annotator(factory) -> None:
+    global _ANNOTATOR
+    _ANNOTATOR = factory
+
+
+class span:
+    """``with span("tpu", "encode.prep", nbytes=n):`` — time one leg.
+
+    On exit, from the one interval: a compact tuple in the always-on
+    ring under the request id and span parent of the calling context
+    (``trace-tree`` shows the leg with no subscriber connected); one
+    observation of the type's leg histogram; the annotator's event
+    ``mt:<name>`` when one is installed.  The full span dict is built
+    only behind :func:`active`; ``detail`` is then called for its
+    type-keyed payload.  An exception passing through is recorded as
+    the span's ``error`` and propagates.  ``dur_ns`` and ``error`` stay
+    readable after the block for callers that also count the interval.
+    A leg's wall includes the time its thread waited for the GIL."""
+
+    __slots__ = ("trace_type", "name", "nbytes", "detail", "start_ns",
+                 "dur_ns", "error", "_t0", "_ann")
+
+    def __init__(self, trace_type: str, name: str, nbytes: int = 0,
+                 detail=None):
+        self.trace_type = trace_type
+        self.name = name
+        self.nbytes = nbytes
+        self.detail = detail
+        self.start_ns = self.dur_ns = 0
+        self.error = ""
+        self._ann = None
+
+    def __enter__(self):
+        if _ANNOTATOR is not None:
+            self._ann = _ANNOTATOR("mt:" + self.name)
+            self._ann.__enter__()
+        self.start_ns = time.time_ns()
+        self._t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        self.dur_ns = dur = time.monotonic_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+            self._ann = None
+        if et is not None:
+            self.error = f"{et.__name__}: {ev}"
+        family = LEG_FAMILIES.get(self.trace_type)
+        if family:
+            op, _, leg = self.name.partition(".")
+            _metrics.observe(family, {"op": op, "leg": leg}, dur / 1e9,
+                             buckets=KERNEL_BUCKETS)
+        rid = _REQUEST_ID.get()
+        sid = ""
+        if rid:
+            sid = new_span_id()
+            ring_append(rid, sid, _SPAN_PARENT.get(), self.trace_type,
+                        self.name, self.start_ns, dur, self.error)
+        if active():
+            publish_span(make_span(
+                self.trace_type, self.name, start_ns=self.start_ns,
+                duration_ns=dur, input_bytes=int(self.nbytes),
+                error=self.error, span_id=sid, _ring=False,
+                detail=self.detail() if self.detail else None))
+        return False
 
 
 def publish(info: Dict[str, Any]) -> None:

@@ -21,10 +21,12 @@ backends (and with klauspost/reedsolomon's defaults).
 
 from __future__ import annotations
 
+import contextlib
 import time
 
 import numpy as np
 
+from ..admin import metrics as _metrics
 from ..obs import trace as _obstrace
 from . import gf8, gf8_ref
 
@@ -43,6 +45,33 @@ def _nbytes(x) -> int:
 
 class ErasureError(ValueError):
     pass
+
+
+@contextlib.contextmanager
+def dispatch_span(op: str, backend: str, nbytes: int, detail=None,
+                  blocks: int = 0):
+    """One codec dispatch, whole: the ``tpu`` span ``<op>.dispatch``
+    (obs/trace.py: ring, leg histogram, profiler annotation, and the
+    span dict with ``detail()`` when a trace consumer is active), and
+    always counted into the mt_tpu_* families (encode GiB/s falls out
+    of bytes_total / kernel_seconds_sum).  Cost is a handful of counter
+    bumps against megabytes of GF(2^8) math — noise on this path."""
+    sp = _obstrace.span("tpu", op + ".dispatch", nbytes, detail)
+    try:
+        with sp:
+            yield
+    finally:
+        labels = {"op": op, "backend": backend}
+        m = _metrics.GLOBAL
+        m.inc("mt_tpu_ops_total", labels)
+        m.inc("mt_tpu_bytes_total", labels, float(nbytes))
+        m.observe("mt_tpu_kernel_seconds", labels, sp.dur_ns / 1e9,
+                  buckets=_metrics.KERNEL_BUCKETS)
+        if blocks:
+            m.observe("mt_tpu_batch_blocks", {"op": op}, float(blocks),
+                      buckets=_metrics.BATCH_BUCKETS)
+        if sp.error:
+            m.inc("mt_tpu_errors_total", labels)
 
 
 def resolve_backend(backend: str) -> str:
@@ -108,39 +137,15 @@ class Erasure:
 
     # -- kernel observability ----------------------------------------------
 
-    def _observe(self, op: str, nbytes: int, t0_ns: int,
-                 blocks: int = 0, error: str = "") -> None:
-        """One erasure-kernel dispatch: always counted into the
-        mt_tpu_* metric families (encode GiB/s falls out of
-        bytes_total / kernel_seconds_sum — the BENCH trajectory numbers
-        become scrapeable), and published as a ``tpu``-type span when a
-        trace consumer is active.  Cost is three counter bumps against
-        megabytes of GF(2^8) math — noise on this path."""
-        # lazy import: the compute-kernel layer must not pull the admin
-        # package in at import time (layering; a future admin->ops
-        # import must not cycle)
-        from ..admin import metrics as _metrics
-        dt = time.monotonic_ns() - t0_ns
-        labels = {"op": op, "backend": self.backend}
-        m = _metrics.GLOBAL
-        m.inc("mt_tpu_ops_total", labels)
-        m.inc("mt_tpu_bytes_total", labels, float(nbytes))
-        m.observe("mt_tpu_kernel_seconds", labels, dt / 1e9,
-                  buckets=_metrics.KERNEL_BUCKETS)
-        if blocks:
-            m.observe("mt_tpu_batch_blocks", {"op": op}, float(blocks),
-                      buckets=_metrics.BATCH_BUCKETS)
-        if error:
-            m.inc("mt_tpu_errors_total", labels)
-        if _obstrace.active():
-            _obstrace.publish_span(_obstrace.make_span(
-                "tpu", f"tpu.{op}", start_ns=time.time_ns() - dt,
-                duration_ns=dt,
-                input_bytes=int(nbytes), error=error,
-                detail={"op": op, "backend": self.backend,
-                        "k": self.data_blocks, "m": self.parity_blocks,
-                        "blockSize": self.block_size,
-                        "blocks": blocks}))
+    def _dispatch(self, op: str, nbytes: int, blocks: int = 0):
+        """:func:`dispatch_span` for one dispatch of this codec, with
+        its geometry as the span's detail."""
+        return dispatch_span(
+            op, self.backend, nbytes,
+            lambda: {"op": op, "backend": self.backend,
+                     "k": self.data_blocks, "m": self.parity_blocks,
+                     "blockSize": self.block_size, "blocks": blocks},
+            blocks)
 
     def apply_matrix(self, rows: np.ndarray, shards) -> np.ndarray:
         """rows (GF) @ shards through this codec's engine; accepts
@@ -149,20 +154,19 @@ class Erasure:
         combining queue (GET reconstruction and heal stripes from
         concurrent requests coalesce); the observed wall time then
         includes the combining window."""
-        t0 = time.monotonic_ns()
-        err = ""
-        try:
+        with self._dispatch("matmul", _nbytes(shards)):
             b = _batcher(self)
             if b is not None:
                 return b.apply(self, "reconstruct", rows, shards)
             return self._apply_matrix(rows, shards)
-        except Exception as e:
-            err = f"{type(e).__name__}: {e}"
-            raise
-        finally:
-            self._observe("matmul", _nbytes(shards), t0, error=err)
 
-    def _apply_matrix(self, rows: np.ndarray, shards) -> np.ndarray:
+    def _apply_matrix(self, rows: np.ndarray, shards,
+                      op: str = "decode") -> np.ndarray:
+        """The serial engine.  ``op`` (``encode`` / ``decode``) names
+        the legs of the one-chip device form (rs_kernels.apply_matrix);
+        the mesh form is one sharded program and has none."""
+        if self.backend == "tpu":
+            return self._impl.apply_matrix(rows, shards, op=op)
         impl_apply = getattr(self._impl, "apply_matrix", None)
         if impl_apply is not None:
             return impl_apply(rows, shards)
@@ -201,7 +205,8 @@ class Erasure:
         if buf.size == 0:
             return [np.zeros(0, dtype=np.uint8)
                     for _ in range(self.data_blocks + self.parity_blocks)]
-        data_shards = gf8.split(buf, self.data_blocks)
+        with _obstrace.span("tpu", "encode.prep", buf.nbytes):
+            data_shards = gf8.split(buf, self.data_blocks)
         par = self._encode_parity_blocks(data_shards[None])[0]
         return [data_shards[i] for i in range(self.data_blocks)] + \
                [par[i] for i in range(self.parity_blocks)]
@@ -210,9 +215,8 @@ class Erasure:
         lens = {len(s) for s in shards if s is not None and len(s) > 0}
         if len(lens) > 1:
             raise ErasureError("shard size mismatch")
-        t0 = time.monotonic_ns()
-        err = ""
-        try:
+        present = sum(_nbytes(s) for s in shards if s is not None)
+        with self._dispatch("decode", present):
             b = _batcher(self)
             if b is not None:
                 # shared survivor/solve logic (host) with the heavy
@@ -235,12 +239,6 @@ class Erasure:
             return self._impl.reconstruct(
                 shards, self.data_blocks, self.parity_blocks,
                 data_only=data_only, matrix=self.matrix)
-        except Exception as e:
-            err = f"{type(e).__name__}: {e}"
-            raise
-        finally:
-            present = sum(_nbytes(s) for s in shards if s is not None)
-            self._observe("decode", present, t0, error=err)
 
     def decode_data_blocks(self, shards) -> list[np.ndarray]:
         """DecodeDataBlocks (cmd/erasure-coding.go:89): rebuild data only.
@@ -289,50 +287,52 @@ class Erasure:
         dispatch for the tail block.  Returns k+m shard-file byte arrays whose
         concatenated per-block layout matches block-by-block encode_data.
         """
-        t0 = time.monotonic_ns()
-        err = ""
         total = _nbytes(data)
-        try:
+        with self._dispatch("encode", total,
+                            blocks=-(-total // self.block_size)):
             return self._encode_object(data)
-        except Exception as e:
-            err = f"{type(e).__name__}: {e}"
-            raise
-        finally:
-            self._observe("encode", total, t0,
-                          blocks=-(-total // self.block_size)
-                          if total else 0, error=err)
 
     def _encode_object(self, data) -> list[np.ndarray]:
-        buf = np.frombuffer(bytes(data), dtype=np.uint8) \
-            if not isinstance(data, np.ndarray) \
-            else np.asarray(data, np.uint8).ravel()
-        total = buf.size
-        k, m = self.data_blocks, self.parity_blocks
-        if total == 0:
-            return [np.zeros(0, dtype=np.uint8) for _ in range(k + m)]
-        bs = self.block_size
-        ssize = self.shard_size()
-        nfull = total // bs
-        outs: list[list[np.ndarray]] = [[] for _ in range(k + m)]
+        # ``encode.prep`` is this function's host copies either side of
+        # the parity dispatch: the body, the padded blocks, the shards
+        with _obstrace.span("tpu", "encode.prep", _nbytes(data)):
+            buf = np.frombuffer(bytes(data), dtype=np.uint8) \
+                if not isinstance(data, np.ndarray) \
+                else np.asarray(data, np.uint8).ravel()
+            total = buf.size
+            k, m = self.data_blocks, self.parity_blocks
+            if total == 0:
+                return [np.zeros(0, dtype=np.uint8) for _ in range(k + m)]
+            bs = self.block_size
+            ssize = self.shard_size()
+            nfull = total // bs
+            outs: list[list[np.ndarray]] = [[] for _ in range(k + m)]
+            if nfull:
+                blocks = buf[: nfull * bs].reshape(nfull, k, ssize) \
+                    if bs == k * ssize else None
+                if blocks is None:
+                    # blockSize not divisible by k: per-block zero padding
+                    blocks = np.zeros((nfull, k, ssize), dtype=np.uint8)
+                    flat = buf[: nfull * bs].reshape(nfull, bs)
+                    blocks.reshape(nfull, k * ssize)[:, :bs] = flat
         if nfull:
-            blocks = buf[: nfull * bs].reshape(nfull, k, ssize) \
-                if bs == k * ssize else None
-            if blocks is None:
-                # blockSize not divisible by k: per-block zero padding
-                blocks = np.zeros((nfull, k, ssize), dtype=np.uint8)
-                flat = buf[: nfull * bs].reshape(nfull, bs)
-                blocks.reshape(nfull, k * ssize)[:, :bs] = flat
             par = self._encode_parity_blocks(blocks)
-            for i in range(k):
-                outs[i].append(np.ascontiguousarray(blocks[:, i]).reshape(-1))
-            for j in range(m):
-                outs[k + j].append(np.ascontiguousarray(par[:, j]).reshape(-1))
+            with _obstrace.span("tpu", "encode.prep",
+                                blocks.nbytes + par.nbytes):
+                for i in range(k):
+                    outs[i].append(
+                        np.ascontiguousarray(blocks[:, i]).reshape(-1))
+                for j in range(m):
+                    outs[k + j].append(
+                        np.ascontiguousarray(par[:, j]).reshape(-1))
         tail = buf[nfull * bs:]
         if tail.size:
             for i, s in enumerate(self.encode_data(tail)):
                 outs[i].append(s)
-        return [np.concatenate(chunks) if len(chunks) != 1 else chunks[0]
-                for chunks in outs]
+        if len(outs[0]) == 1:          # full blocks only, or a tail only
+            return [chunks[0] for chunks in outs]
+        with _obstrace.span("tpu", "encode.prep", total * (k + m) // k):
+            return [np.concatenate(chunks) for chunks in outs]
 
     def speedtest(self, size: int = 8 << 20, iters: int = 3) -> dict:
         """Timed probe of this codec's hot paths (the admin
@@ -405,18 +405,10 @@ class Erasure:
         computed by the native kernel directly into its frame payloads.
         Requires the native GF8 library (callers fall back to
         encode_object + streaming framing)."""
-        t0 = time.monotonic_ns()
-        err = ""
         total = _nbytes(data)
-        try:
+        with self._dispatch("encode-framed", total,
+                            blocks=-(-total // self.block_size)):
             return self._encode_object_framed(data, digest, out)
-        except Exception as e:
-            err = f"{type(e).__name__}: {e}"
-            raise
-        finally:
-            self._observe("encode-framed", total, t0,
-                          blocks=-(-total // self.block_size)
-                          if total else 0, error=err)
 
     def _encode_object_framed(self, data, digest: int = 32,
                               out: np.ndarray | None = None) -> np.ndarray:

@@ -30,7 +30,15 @@ first, so four things are decided exactly once and can be read back
   * **what was compiled** — a ``jax.monitoring`` listener counts
     backend compile requests, the seconds spent tracing, lowering and
     compiling, and the persistent-cache hits and writes
-    (``compile_stats()``).
+    (``compile_stats()``), and keeps the same tally per jitted function
+    (``by_function``: which step recompiled).
+  * **what crossed the link** — ``upload`` and ``fetch`` are the two
+    calls that move bytes between host and device on the codec path;
+    each is one ``<op>.upload`` / ``<op>.fetch`` leg (obs/trace.py) and
+    counts its array's bytes, padding included, into
+    ``mt_tpu_link_bytes_total{op,dir}``.  Importing this module also
+    hands obs/trace.py the profiler's ``TraceAnnotation``, so every leg
+    lands in a profiler trace on the device's own clock.
 """
 
 from __future__ import annotations
@@ -41,6 +49,11 @@ import os
 import threading
 
 import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..admin.metrics import GLOBAL as _metrics
+from ..obs import trace as _trace
 
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_CACHE_DIR = os.path.join(
@@ -131,6 +144,17 @@ def interpret() -> bool:
     return not (_AOT_TPU or platform() == "tpu")
 
 
+def named_jit(name: str, **jit_kw):
+    """``jax.jit`` under a stable program name: the trace's ``XLA
+    Modules`` line and ``compile_stats()["by_function"]`` show
+    ``jit_<name>`` / ``<name>`` whatever the Python function is called,
+    so a reducer finds a kernel after its callers are restructured."""
+    def wrap(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn, **jit_kw)
+    return wrap
+
+
 # -- what was compiled -------------------------------------------------------
 
 _mu = threading.Lock()
@@ -147,7 +171,15 @@ _DURATIONS = {
 }
 
 
-def _on_duration(event: str, secs: float, **_kw) -> None:
+# per jitted function: [backend compile requests, seconds over the three
+# stages].  A server meets a few dozen names (kernels, their wrappers,
+# eager one-op programs); past the bound the rest share one row.
+_BY_FUNCTION_CAP = 128
+_by_function: dict[str, list] = {}
+
+
+def _on_duration(event: str, secs: float, fun_name: str = "",
+                 **_kw) -> None:
     key = _DURATIONS.get(event)
     if key:
         with _mu:
@@ -156,6 +188,19 @@ def _on_duration(event: str, secs: float, **_kw) -> None:
             # a persistent-cache retrieval too, so compiles - cache_hits
             # is what the backend really compiled
             _stats["compiles"] += key == "compile_seconds"
+            # tracing reports the function's name, lowering its
+            # module's (``jit_<name>``), compiling ``jit(<name>)``: one
+            # row for the three
+            if fun_name.startswith("jit(") and fun_name.endswith(")"):
+                fun_name = fun_name[4:-1]
+            else:
+                fun_name = fun_name.removeprefix("jit_")
+            if fun_name not in _by_function and \
+                    len(_by_function) >= _BY_FUNCTION_CAP:
+                fun_name = "(other)"
+            row = _by_function.setdefault(fun_name, [0, 0.0])
+            row[0] += key == "compile_seconds"
+            row[1] += secs
 
 
 def _on_event(event: str, **_kw) -> None:
@@ -172,7 +217,44 @@ jax.monitoring.register_event_listener(_on_event)
 
 def compile_stats() -> dict:
     with _mu:
-        return {k: round(v, 3) for k, v in _stats.items()}
+        out = {k: round(v, 3) for k, v in _stats.items()}
+        out["by_function"] = {
+            name: {"compiles": n, "seconds": round(secs, 3)}
+            for name, (n, secs) in _by_function.items()}
+        return out
+
+
+# -- what crossed the link ---------------------------------------------------
+
+_trace.set_annotator(jax.profiler.TraceAnnotation)
+
+
+def _count_link(op: str, direction: str, nbytes: int) -> None:
+    _metrics.inc("mt_tpu_link_bytes_total", {"op": op, "dir": direction},
+                 float(nbytes))
+
+
+def upload(op: str, x) -> jax.Array:
+    """Host bytes -> a uint8 device array, as the ``<op>.upload`` leg.
+    An array already on the device passes through: nothing crosses."""
+    if isinstance(x, jax.Array):
+        return jnp.asarray(x, jnp.uint8)
+    with _trace.span("tpu", op + ".upload",
+                     nbytes=getattr(x, "nbytes", 0)):
+        out = jnp.asarray(x, jnp.uint8)
+    _count_link(op, "h2d", out.nbytes)
+    return out
+
+
+def fetch(op: str, x: jax.Array, rows: int | None = None) -> np.ndarray:
+    """A device array (its first ``rows``) -> host memory, as the
+    ``<op>.fetch`` leg: the wait for the programs that produce it, then
+    the copy down."""
+    with _trace.span("tpu", op + ".fetch") as sp:
+        out = np.asarray(x if rows is None else x[:rows])
+        sp.nbytes = out.nbytes
+    _count_link(op, "d2h", out.nbytes)
+    return out
 
 
 def compile_cache() -> dict:

@@ -159,7 +159,7 @@ def _kernel_nat(in_ref, out_ref, st, tbuf, *, S, n_packets, init_consts):
             out_ref[0, idx] = st[idx]
 
 
-@functools.partial(jax.jit, static_argnames=("n_packets", "S"))
+@device.named_jit("mt_hh256", static_argnames=("n_packets", "S"))
 def _run_nat(x2d, n_packets, S):
     """x2d: (B_pad, P_pad*32) uint8 natural-layout shard bytes (row =
     one shard).  Returns (NB, 32, S, 128) u32 state planes.  2-D u8
@@ -182,6 +182,7 @@ def _run_nat(x2d, n_packets, S):
         scratch_shapes=[pltpu.VMEM((32, S, 128), _U32),
                         pltpu.VMEM((_PC_NAT * 32, S, 128), jnp.uint8)],
         interpret=device.interpret(),
+        name="mt_hh256",
     )(x2d)
 
 

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import trace as _trace
 from . import device, gf8
 
 _LANES = 128    # TPU lane width; byte axis is padded to a lane multiple
@@ -78,11 +79,18 @@ def _put_matrix(M: np.ndarray) -> jax.Array:
     return _device_matrix(M.tobytes(), M.shape[0], M.shape[1])
 
 
-def apply_matrix(M: np.ndarray, shards: np.ndarray | jax.Array) -> np.ndarray:
+def apply_matrix(M: np.ndarray, shards: np.ndarray | jax.Array,
+                 op: str = "decode") -> np.ndarray:
     """out[b] = M (GF) @ shards[b] for a batch of stripes.
 
     M: (r, k) uint8 GF coefficients;  shards: (B, k, n) uint8.
     Returns (B, r, n) uint8 (numpy, host).
+
+    ``op`` names the dispatch's legs (obs/trace.py): ``<op>.prep`` the
+    host padding, ``<op>.upload`` each chunk handed to JAX,
+    ``<op>.launch`` the Python dispatch of its program(s) until the
+    handle is held, ``<op>.fetch`` the wait for a result and its copy
+    down.  Every chunk is still dispatched before any result is pulled.
     """
     squeeze = getattr(shards, "ndim", 3) == 2
     if squeeze:
@@ -108,19 +116,22 @@ def apply_matrix(M: np.ndarray, shards: np.ndarray | jax.Array) -> np.ndarray:
     xp = jnp if on_device else np
     pad_n = (-n) % _LANES
     if pad_n:
-        shards = xp.pad(shards, ((0, 0), (0, 0), (0, pad_n)))
+        with _trace.span("tpu", op + ".prep", nbytes=shards.nbytes):
+            shards = xp.pad(shards, ((0, 0), (0, 0), (0, pad_n)))
     handles = []
     for off in range(0, B, _MAX_BATCH):
         chunk = shards[off: off + _MAX_BATCH]
         b = chunk.shape[0]
         bb = 1 << (b - 1).bit_length()  # next power of two
         if bb != b:
-            chunk = xp.pad(chunk, ((0, bb - b), (0, 0), (0, 0)))
-        if pallas:
-            handles.append((rs_pallas.apply_matrix(M, chunk), b))
-        else:
-            handles.append((_gf2_apply(mb, jnp.asarray(chunk)), b))
-    chunks = [np.asarray(out[:b]) for out, b in handles]
+            with _trace.span("tpu", op + ".prep", nbytes=chunk.nbytes):
+                chunk = xp.pad(chunk, ((0, bb - b), (0, 0), (0, 0)))
+        chunk = device.upload(op, chunk)
+        with _trace.span("tpu", op + ".launch", nbytes=chunk.nbytes):
+            out = rs_pallas.apply_matrix(M, chunk) if pallas \
+                else _gf2_apply(mb, chunk)
+        handles.append((out, b))
+    chunks = [device.fetch(op, out, rows=b) for out, b in handles]
     res = chunks[0] if len(chunks) == 1 else np.concatenate(chunks)
     if pad_n:
         res = res[..., :n]
@@ -136,7 +147,7 @@ def encode_parity(data_shards: np.ndarray, parity: int,
     k = data_shards.shape[1]
     if matrix is None:
         matrix = gf8.rs_matrix(k, k + parity)
-    out = apply_matrix(np.asarray(matrix)[k:], data_shards)
+    out = apply_matrix(np.asarray(matrix)[k:], data_shards, op="encode")
     return out[0] if squeeze else out
 
 

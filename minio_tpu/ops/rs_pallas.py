@@ -91,7 +91,7 @@ def _kernel(m_ref, in_ref, out_ref, *, k: int, ro: int, gs: int):
         out_ref[s] = out.astype(jnp.uint8)
 
 
-@functools.partial(jax.jit, static_argnames=("gs", "tn"))
+@device.named_jit("mt_rs_gf2", static_argnames=("gs", "tn"))
 def _gf2_apply_bm(matrix_bd: jax.Array, data: jax.Array,
                   gs: int = _GS, tn: int = _TN) -> jax.Array:
     """matrix_bd: (gs*8r, gs*8k) int8 block-diagonal bit-major; data:
@@ -110,6 +110,7 @@ def _gf2_apply_bm(matrix_bd: jax.Array, data: jax.Array,
         out_specs=pl.BlockSpec((gs, ro, tn), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, ro, n), jnp.uint8),
         interpret=device.interpret(),
+        name="mt_rs_gf2",
     )(matrix_bd, data)
 
 

@@ -43,13 +43,15 @@ pjit/shard_map plumbing (parallel/mesh.py + ops/rs_mesh.py), so many
 frontend nodes — local callers and RemoteCodec sidecar clients alike —
 share one device mesh through one combining queue.
 
-Every dispatch lands in the ``mt_codec_batch_*`` metric families and,
-when tracing is active, publishes a ``tpu``-type span carrying the
-batch detail (occupancy, blocks, geometry).
+Every dispatch lands in the ``mt_codec_batch_*`` metric families and
+is one ``tpu``-type span ``<op>.batch`` (obs/trace.py ``span``: always
+in the ring and the leg histogram; with a trace consumer active the
+span dict carries the batch detail — occupancy, blocks, geometry).
 """
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from collections import deque
@@ -153,6 +155,14 @@ def codec_for(data_blocks: int, parity_blocks: int, block_size: int,
                 _CODECS.pop(next(iter(_CODECS)))
             _CODECS[key] = c
         return c
+
+
+def _engine(codec: Erasure, op: str, fn):
+    """``fn``, or the codec's serial engine with its legs named for
+    ``op``: ``encode.*`` for an encode bucket, ``decode.*`` for
+    ``decode`` and ``reconstruct``."""
+    return fn or functools.partial(
+        codec._apply_matrix, op="encode" if op == "encode" else "decode")
 
 
 class _Waiter:
@@ -276,7 +286,7 @@ class CodecBatcher:
             bkt = self._buckets.get(key)
             if bkt is None:
                 bkt = _Bucket(rows, exec_codec, self._mu, op,
-                              fn or exec_codec._apply_matrix)
+                              _engine(exec_codec, op, fn))
                 self._buckets[key] = bkt
             if bkt.blocks + w.blocks > cfg.queue_depth:
                 # per-bucket backpressure: the queue never grows past
@@ -457,15 +467,28 @@ class CodecBatcher:
             return tuple(o[off:off + n] for o in out)
         return out[off:off + n]
 
+    @staticmethod
+    def _span(codec: Erasure, op: str, nwaiters: int, nblocks: int):
+        """The ``tpu`` span ``<op>.batch`` round one batched dispatch
+        (obs/trace.py); its detail says how full the batch was."""
+        return _trace.span(
+            "tpu", op + ".batch",
+            detail=lambda: {"op": op, "backend": codec.backend,
+                            "k": codec.data_blocks,
+                            "m": codec.parity_blocks,
+                            "blockSize": codec.block_size,
+                            "blocks": nblocks, "occupancy": nwaiters,
+                            "batched": nwaiters > 1})
+
     def _direct(self, codec: Erasure, op: str, rows: np.ndarray,
                 shards: np.ndarray, fn=None):
         """One caller, one dispatch — the strict serial fallback (and
         the shed/cancel path).  Counted with occupancy 1 so the scrape
         shows how much traffic is NOT coalescing."""
-        t0 = time.monotonic()
-        out = (fn or codec._apply_matrix)(rows, shards)
-        self._account(codec, op, nwaiters=1, nblocks=shards.shape[0],
-                      t0=t0, waits=(0.0,), err="")
+        with self._span(codec, op, 1, shards.shape[0]):
+            out = _engine(codec, op, fn)(rows, shards)
+        self._account(op, nwaiters=1, nblocks=shards.shape[0],
+                      waits=(0.0,))
         return out
 
     def _dispatch(self, bkt: _Bucket, batch: list[_Waiter],
@@ -474,21 +497,22 @@ class CodecBatcher:
         views sliced back per waiter (padding — lane tiles, pow2 batch,
         mesh axes — is the engine's own and stripped there)."""
         t0 = time.monotonic()
-        err = ""
         try:
-            if len(batch) == 1:
-                # the window found one caller: strict single-dispatch
-                # fallback, the serial reference semantics verbatim
-                batch[0].result = bkt.fn(bkt.rows, batch[0].shards)
-            else:
-                cat = np.concatenate([w.shards for w in batch], axis=0)
-                out = bkt.fn(bkt.rows, cat)
-                off = 0
-                for w in batch:
-                    w.result = self._slice(out, off, w.blocks)
-                    off += w.blocks
+            with self._span(bkt.codec, bkt.op, len(batch), nblocks):
+                if len(batch) == 1:
+                    # the window found one caller: strict single-
+                    # dispatch fallback, the serial reference semantics
+                    # verbatim
+                    batch[0].result = bkt.fn(bkt.rows, batch[0].shards)
+                else:
+                    cat = np.concatenate([w.shards for w in batch],
+                                         axis=0)
+                    out = bkt.fn(bkt.rows, cat)
+                    off = 0
+                    for w in batch:
+                        w.result = self._slice(out, off, w.blocks)
+                        off += w.blocks
         except BaseException as e:
-            err = f"{type(e).__name__}: {e}"
             for w in batch:
                 w.exc = e
             if not isinstance(e, Exception):
@@ -501,14 +525,11 @@ class CodecBatcher:
             for w in batch:
                 w.done = True
                 w.event.set()
-            self._account(bkt.codec, bkt.op, nwaiters=len(batch),
-                          nblocks=nblocks, t0=t0,
-                          waits=tuple(t0 - w.enq for w in batch),
-                          err=err)
+            self._account(bkt.op, nwaiters=len(batch), nblocks=nblocks,
+                          waits=tuple(t0 - w.enq for w in batch))
 
-    def _account(self, codec: Erasure, op: str, *, nwaiters: int,
-                 nblocks: int, t0: float, waits: tuple,
-                 err: str) -> None:
+    def _account(self, op: str, *, nwaiters: int, nblocks: int,
+                 waits: tuple) -> None:
         from ..admin.metrics import BATCH_BUCKETS, KERNEL_BUCKETS
         from ..admin.metrics import GLOBAL as _mtr
         with self._mu:
@@ -524,18 +545,6 @@ class CodecBatcher:
         for wt in waits:
             _mtr.observe("mt_codec_batch_wait_seconds", labels,
                          max(0.0, wt), buckets=KERNEL_BUCKETS)
-        if _trace.active():
-            dt = int((time.monotonic() - t0) * 1e9)
-            _trace.publish_span(_trace.make_span(
-                "tpu", f"tpu.batch-{op}",
-                start_ns=_trace.now_ns() - dt, duration_ns=dt,
-                error=err,
-                detail={"op": op, "backend": codec.backend,
-                        "k": codec.data_blocks,
-                        "m": codec.parity_blocks,
-                        "blockSize": codec.block_size,
-                        "blocks": nblocks, "occupancy": nwaiters,
-                        "batched": nwaiters > 1}))
 
 
 GLOBAL = CodecBatcher()

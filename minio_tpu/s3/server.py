@@ -1514,15 +1514,17 @@ def _make_handler(srv: S3Server):
                 # per-stage latency attribution (the X-ray histogram
                 # family): S3 APIs only, same scoping as the per-API
                 # counters — ~a dozen stages per API, bounded by the
-                # STAGE_NAMES catalog
-                for sname, sns in stage_ns.items():
-                    _mtr.observe("mt_s3_stage_seconds",
-                                 {"api": api_name, "stage": sname},
-                                 sns / 1e9)
-                for sname, sns in async_ns.items():
-                    _mtr.observe("mt_s3_stage_seconds",
-                                 {"api": api_name, "stage": sname},
-                                 sns / 1e9)
+                # STAGE_NAMES catalog.  ``vec`` keeps the two vectors
+                # apart: the serial stages of one API (``other``
+                # included) add up to its request wall; async detail
+                # overlaps it.  A selector without ``vec`` still sums
+                # both, which is what a stage read before the label.
+                for vec, vector in (("serial", stage_ns),
+                                    ("async", async_ns)):
+                    for sname, sns in vector.items():
+                        _mtr.observe("mt_s3_stage_seconds",
+                                     {"api": api_name, "stage": sname,
+                                      "vec": vec}, sns / 1e9)
                 # last-minute per-API window (mt_s3_api_last_minute_*
                 # gauges + admin `top`): S3 APIs only, same scoping as
                 # the per-API counter families above; monotonic delta,

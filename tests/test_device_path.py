@@ -114,3 +114,38 @@ def test_compile_cache_dir_honours_env(monkeypatch):
     assert configured("/some/where", cpu=True) is None
     assert configured("", cpu=True) is None
     assert configured("", cpu=False) == device.DEFAULT_CACHE_DIR
+
+
+def test_compile_stats_say_which_function_compiled():
+    """``by_function`` (admin ``info`` -> ``codec.device.compile``): the
+    compile listener tallies backend compile requests and seconds per
+    jitted function, under the stable name ``named_jit`` gives."""
+    @device.named_jit("mt_test_probe_23", static_argnames=("n",))
+    def whatever_it_is_called(x, n):
+        return x * n + 1
+
+    before = device.compile_stats()
+    assert "mt_test_probe_23" not in before["by_function"]
+    whatever_it_is_called(np.arange(7, dtype=np.int32), n=3)
+    whatever_it_is_called(np.arange(7, dtype=np.int32), n=3)   # cached
+    whatever_it_is_called(np.arange(9, dtype=np.int32), n=3)   # new shape
+    after = device.compile_stats()
+    row = after["by_function"]["mt_test_probe_23"]
+    assert row["compiles"] == 2 and row["seconds"] > 0, row
+    assert after["compiles"] - before["compiles"] >= 2
+    # the per-function rows add up to the process totals
+    assert sum(r["compiles"] for r in after["by_function"].values()) \
+        == after["compiles"]
+
+
+def test_kernel_programs_carry_stable_names():
+    """The Pallas kernels' jitted wrappers are ``jit_mt_rs_gf2`` /
+    ``jit_mt_hh256`` in a trace and in ``by_function`` (they were
+    ``_gf2_apply_bm`` / ``_run_nat``), whatever the callers become."""
+    from minio_tpu.ops import rs_pallas
+    assert rs_pallas._gf2_apply_bm.__name__ == "mt_rs_gf2"
+    assert hh_pallas._run_nat.__name__ == "mt_hh256"
+    lowered = rs_pallas._gf2_apply_bm.lower(
+        jax.ShapeDtypeStruct((32, 16), np.int8),
+        jax.ShapeDtypeStruct((1, 2, 128), np.uint8), gs=1, tn=128)
+    assert "jit_mt_rs_gf2" in lowered.as_text()

@@ -501,3 +501,118 @@ def test_cross_node_tree_assembles_idle_from_rings(tmp_path):
         srv.stop()
         for node in nodes:
             node.stop()
+
+
+# -- legs and link bytes of the device codec path (ISSUE 23) -----------------
+
+def _leg_counts() -> dict:
+    """{(op, leg): observations} of mt_tpu_leg_seconds."""
+    from minio_tpu.admin.metrics import GLOBAL
+    out = {}
+    for (name, labels, buckets), h in GLOBAL.hist_snapshot().items():
+        if name == "mt_tpu_leg_seconds":
+            d = dict(labels)
+            out[(d["op"], d["leg"])] = h[len(buckets)]
+    return out
+
+
+def _tpu_counters() -> dict:
+    """{(family, op, dir or backend): value} of the mt_tpu_* counters."""
+    from minio_tpu.admin.metrics import GLOBAL
+    out = {}
+    for (name, labels), v in GLOBAL.snapshot().items():
+        if name.startswith("mt_tpu_"):
+            d = dict(labels)
+            out[(name, d.get("op"), d.get("dir") or d.get("backend"))] = v
+    return out
+
+
+def test_device_put_counts_legs_and_link_bytes(tmp_path):
+    """A device-codec PUT of a known size and geometry moves
+    mt_tpu_leg_seconds and mt_tpu_link_bytes_total by exactly what the
+    code dispatches: 2+2, 64 KiB blocks, 200,000 B = 3 full blocks and
+    a 3,392 B tail, so the RS and the hash legs each run twice (full
+    blocks, tail).  Link bytes are array nbytes, padding included."""
+    disks = []
+    for i in range(4):
+        d = tmp_path / f"d{i}"
+        d.mkdir()
+        disks.append(XLStorage(str(d)))
+    layer = ErasureObjects(disks, parity=2, block_size=64 * 1024,
+                           backend="tpu")
+    layer.make_bucket("linkb")
+    legs0, ctr0 = _leg_counts(), _tpu_counters()
+    layer.put_object("linkb", "obj", b"k" * 200_000)
+    legs = {k: v - legs0.get(k, 0) for k, v in _leg_counts().items()}
+    ctr = {k: v - ctr0.get(k, 0) for k, v in _tpu_counters().items()}
+
+    for op in ("encode", "hash"):
+        for leg in ("upload", "launch", "fetch"):
+            assert legs[(op, leg)] == 2, (op, leg, legs)
+        assert legs[(op, "dispatch")] == 1, legs
+        assert legs[(op, "prep")] >= 1, legs
+    assert legs[("hash", "frame")] == 1, legs
+    assert legs[("encode", "batch")] == 2, legs
+
+    k, m, shard, tail_shard = 2, 2, 32768, 1696    # ceil(3392 / 2)
+    lanes = -(-tail_shard // 128) * 128            # lane pad: 1792
+    link = {(op, d): ctr[("mt_tpu_link_bytes_total", op, d)]
+            for op in ("encode", "hash") for d in ("h2d", "d2h")}
+    # RS: 3 blocks padded to a batch of 4 go up, 3 come down; the tail
+    # stripe goes up and comes down at its lane-padded width
+    assert link[("encode", "h2d")] == 4 * k * shard + k * lanes
+    assert link[("encode", "d2h")] == 3 * m * shard + m * lanes
+    # hash: every shard's bytes go up again, data and parity, unpadded;
+    # 32 B per block and shard come down
+    assert link[("hash", "h2d")] == (k + m) * (3 * shard + tail_shard)
+    assert link[("hash", "d2h")] == (k + m) * 4 * 32
+    # the device bitrot leg now counts like a codec dispatch
+    assert ctr[("mt_tpu_ops_total", "hash", "tpu")] == 1
+    assert ctr[("mt_tpu_bytes_total", "hash", "tpu")] == \
+        (k + m) * (3 * shard + tail_shard)
+    assert ctr[("mt_tpu_ops_total", "encode", "tpu")] == 1
+    assert ctr[("mt_tpu_bytes_total", "encode", "tpu")] == 200_000
+    # and what it wrote reads back
+    assert bytes(layer.get_object("linkb", "obj")[1]) == b"k" * 200_000
+
+
+def test_stage_sums_do_not_depend_on_the_vec_label(served):
+    """``vec`` splits mt_s3_stage_seconds into its serial and async
+    vectors; a selector without it (the benchmark's ``stage`` reader)
+    still reads the per-{api,stage} totals the flight recorder holds."""
+    from minio_tpu.admin.metrics import GLOBAL
+
+    def sums():
+        out = {}
+        for (name, labels, _b), h in GLOBAL.hist_snapshot().items():
+            if name == "mt_s3_stage_seconds":
+                d = dict(labels)
+                assert d["vec"] in ("serial", "async"), d
+                key = (d["api"], d["stage"])
+                out[key] = out.get(key, 0.0) + h[-1]
+        return out
+    before = sums()
+    c = S3Client(served.endpoint, "ok", "os")
+    c.make_bucket("vecbkt")
+    c.put_object("vecbkt", "obj", b"v" * (1 << 20))
+    c.get_object("vecbkt", "obj")
+    want: dict = {}
+    got: dict = {}
+    for _ in range(100):    # the last observation trails the response
+        recs = served.flightrec.query(limit=50)
+        want = {}
+        for r in recs:
+            for vec in (r["stages"], r["asyncStages"]):
+                for stage, ns in vec.items():
+                    key = (r["api"], stage)
+                    want[key] = want.get(key, 0.0) + ns / 1e9
+        after = sums()
+        got = {k: after.get(k, 0.0) - before.get(k, 0.0) for k in want}
+        if len(recs) == 3 and got == pytest.approx(want, abs=1e-9):
+            break
+        time.sleep(0.02)
+    assert len(recs) == 3, recs
+    assert got == pytest.approx(want, abs=1e-9)
+    # the ETag md5 of a 1 MiB PUT ran, as async detail only
+    put = next(r for r in recs if r["api"] == "PutObject")
+    assert "md5" in put["asyncStages"] and "md5" not in put["stages"]

@@ -179,8 +179,8 @@ def test_stage_histogram_and_trace_detail(served):
     conn.request("GET", "/minio-tpu/metrics")
     text = conn.getresponse().read().decode()
     conn.close()
-    assert 'mt_s3_stage_seconds_count{api="PutObject",stage="encode"}' \
-        in text
+    assert 'mt_s3_stage_seconds_count{api="PutObject",stage="encode",' \
+        'vec="serial"}' in text
     assert 'mt_flight_ring_depth{ring="requests"}' in text
 
 
@@ -289,3 +289,131 @@ def test_xray_aggregates_peers_and_cluster_healthinfo(duo):
         "GET", "/minio-tpu/admin/v1/healthinfo", "scope=cluster").body)
     assert len(hd["nodes"]) == 2
     assert any(n.get("offline") for n in hd["nodes"]), hd["nodes"]
+
+
+# -- device legs beside the stage clock (ISSUE 23) ----------------------------
+
+@pytest.fixture
+def device_layer(tmp_path):
+    """An object layer on a DEVICE codec (XLA:CPU under conftest's
+    explicit CPU pin), so a PUT crosses every leg of the device path."""
+    disks = []
+    for i in range(4):
+        d = tmp_path / f"d{i}"
+        d.mkdir()
+        disks.append(XLStorage(str(d)))
+    layer = ErasureObjects(disks, parity=2, block_size=256 * 1024,
+                           backend="tpu")
+    layer.make_bucket("legbkt")
+    return layer
+
+
+def _clocked_put(layer, key: str, body: bytes):
+    """One PUT under an armed clock and request id; returns (serial,
+    async, unattributed, total_ns, this request's tpu ring records)."""
+    rid = f"xray-legs-{key}"
+    trace.set_request_id(rid)
+    trace.set_span_parent(rid)
+    clock = stages.StageClock()
+    stages.set_clock(clock)
+    t0 = time.monotonic_ns()
+    try:
+        layer.put_object("legbkt", key, body)
+    finally:
+        dur = time.monotonic_ns() - t0
+        serial, async_ns, un = clock.finish(dur)
+        stages.clear()
+        trace.set_request_id("")
+        trace.set_span_parent("")
+    legs = [r for r in trace.SPANS.snapshot()
+            if r[trace._R_RID] == rid and r[trace._R_TYPE] == "tpu"]
+    return serial, async_ns, un, dur, legs
+
+
+@pytest.mark.parametrize("size", [200_000, (1 << 20) + 4321],
+                         ids=["md5-on-request-thread", "md5-on-pool"])
+def test_legs_leave_the_serial_vector_alone(device_layer, size):
+    """The legs are spans, never stages: the serial vector of a
+    device-codec PUT holds catalog stages only and, with ``other``,
+    still reconciles with the request wall EXACTLY; the time the legs
+    cover stays in ``encode``'s self time (the legs sum to no more than
+    it); and the ETag md5 shows as async detail only, whichever thread
+    ran it."""
+    serial, async_ns, un, dur, legs = _clocked_put(
+        device_layer, f"o{size}", b"x" * size)
+    assert un >= 0, "double count in the serial vector"
+    assert sum(serial.values()) == dur
+    assert set(serial) <= set(stages.STAGE_NAMES), serial
+    assert set(async_ns) <= set(stages.STAGE_NAMES), async_ns
+    assert "md5" in async_ns and "md5" not in serial, (serial, async_ns)
+    assert async_ns["md5"] > 0
+    by_name: dict = {}
+    for r in legs:
+        by_name[r[trace._R_NAME]] = by_name.get(r[trace._R_NAME], 0) \
+            + r[trace._R_DUR]
+    assert {"encode.dispatch", "hash.dispatch"} <= set(by_name), by_name
+    # both whole dispatches ran inside stage encode, on this thread
+    assert by_name["encode.dispatch"] + by_name["hash.dispatch"] \
+        <= serial["encode"] + serial.get("batch_wait", 0)
+
+
+def test_every_leg_enters_and_exits_the_annotator_once(device_layer,
+                                                       monkeypatch):
+    """With an annotator installed (ops/device.py hands obs/trace.py
+    ``jax.profiler.TraceAnnotation``; here a recording fake) every leg
+    enters and exits it exactly once, around its own interval, strictly
+    nested, under the name ``mt:<op>.<leg>``."""
+    log: list = []
+
+    class Fake:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name, threading.get_ident()))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name, threading.get_ident()))
+
+    monkeypatch.setattr(trace, "_ANNOTATOR", Fake)
+    _s, _a, _u, _d, legs = _clocked_put(device_layer, "ann",
+                                        b"a" * 200_000)
+    me = threading.get_ident()
+    mine = [(what, name) for what, name, tid in log if tid == me]
+    stack: list = []
+    closed: list = []
+    for what, name in mine:
+        if what == "enter":
+            stack.append(name)
+        else:
+            assert stack and stack[-1] == name, (name, stack)
+            closed.append(stack.pop())
+    assert not stack, f"never exited: {stack}"
+    # one annotation per ring record, same names, same completion order
+    ring = ["mt:" + r[trace._R_NAME] for r in legs]
+    assert closed == ring, (closed, ring)
+    assert {"mt:encode.launch", "mt:encode.fetch", "mt:hash.launch",
+            "mt:hash.fetch"} <= set(closed)
+
+
+def test_obs_never_imports_jax():
+    """A ``--backend numpy`` server never loads JAX: importing all of
+    ``minio_tpu.obs`` and timing a span with no annotator installed
+    leaves ``jax`` out of ``sys.modules``."""
+    import subprocess
+    import sys
+    code = (
+        "import importlib, pkgutil, sys\n"
+        "import minio_tpu.obs as obs\n"
+        "for m in pkgutil.iter_modules(obs.__path__):\n"
+        "    importlib.import_module('minio_tpu.obs.' + m.name)\n"
+        "from minio_tpu.obs import trace\n"
+        "assert trace._ANNOTATOR is None\n"
+        "trace.set_request_id('r1')\n"
+        "with trace.span('tpu', 'encode.prep', nbytes=1):\n"
+        "    pass\n"
+        "assert trace.SPANS.snapshot()[-1][trace._R_NAME] == 'encode.prep'\n"
+        "assert 'jax' not in sys.modules, 'obs/ pulled JAX in'\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
