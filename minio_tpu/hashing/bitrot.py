@@ -161,9 +161,10 @@ def _device_hh256_batch(blocks):
     Single fused pallas kernel on a TPU, lax.scan packet loop elsewhere
     (both bit-identical; ops/device.py decides).  Three legs:
     ``hash.upload`` hands the bytes to JAX; ``hash.launch`` is the call
-    of ``hh256_batch`` to its return — slice, pad, kernel, the eager
-    reassembly chain, finalize — until the digests' handle is held;
-    ``hash.fetch`` waits for them and copies them down."""
+    of ``hh256_batch`` to its return — one dispatch of one compiled
+    program (slice, pad, kernel, limb reassembly, remainder, finalize)
+    — until the digests' handle is held; ``hash.fetch`` waits for them
+    and copies them down."""
     from ..obs import trace as _trace
     from ..ops import device
     if device.use_pallas():

@@ -18,9 +18,13 @@ This kernel runs the ENTIRE packet loop inside a single Mosaic kernel:
   variable wiring, reusing hh_kernels' shape-generic u64-pair helpers;
 * tail packets in the final chunk are masked with selects (the packet
   count is rarely a multiple of the chunk size);
-* the remainder packet + finalization (10 permutes, modular
-  reduction) run as plain jnp on the (B, 4) state — ~90 tiny ops once
-  per batch, not per packet.
+* slice, pad, the kernel, the limb reassembly, the remainder packet
+  and finalization (10 permutes, modular reduction; plain jnp on the
+  (B, 4) state) are traced together: ONE compiled program per (B, n)
+  shape (``jit_mt_hh256_batch``), one dispatch per call.  It has to
+  stay one: op by op, the jnp around the kernel is ~840 Python
+  dispatches per call — seconds under a server's GIL, around 4 ms of
+  kernel.
 
 Bit-identical to minio_tpu.hashing.highwayhash (reference:
 cmd/bitrot.go:30-57, minio/highwayhash AVX2 assembly) — conformance-
@@ -205,15 +209,23 @@ def hh256_batch(blocks, key: bytes = MAGIC_KEY):
     """Drop-in for hh_kernels.hh256_batch, pallas packet loop.
 
     blocks: (B, n) uint8.  Returns (B, 32) uint8 digests, bit-identical
-    to the reference HighwayHash256 with the bitrot magic key."""
+    to the reference HighwayHash256 with the bitrot magic key.  One
+    dispatch; callable under an outer trace (rs_mesh's shard_map)."""
     if key != MAGIC_KEY:
         raise ValueError("pallas path supports the bitrot magic key only")
     blocks = jnp.asarray(blocks, jnp.uint8)
     B, n = blocks.shape
-    P, rem = n // 32, n % 32
-    if P == 0 or B == 0:
+    if n < 32 or B == 0:
         return hk.hh256_batch(blocks, key)
+    return _hh256_batch(blocks)
 
+
+@device.named_jit("mt_hh256_batch")
+def _hh256_batch(blocks):
+    """(B, n) uint8 -> (B, 32) uint8 digests, B >= 1 and n >= 32.
+    Everything below is a function of the static shape."""
+    B, n = blocks.shape
+    P, rem = n // 32, n % 32
     # adapt the shard tile to the batch: a 16-shard tail call must not
     # pad (and hash) 1008 garbage rows.  Mosaic requires the 2nd-minor
     # block dim to be 8-divisible or equal to the whole array dim, so:

@@ -72,6 +72,11 @@ def test_zero_length_blocks():
     assert np.array_equal(got[1], want)
 
 
+def _host_digests(blocks):
+    return np.stack([np.frombuffer(hh.hh256(row.tobytes()), np.uint8)
+                     for row in blocks])
+
+
 @pytest.mark.parametrize("B,n", [
     # tier-1 keeps the single-packet floor; the multi-chunk ragged
     # shapes ride the slow tier (~7-9s each) because the multi-chunk
@@ -89,9 +94,7 @@ def test_pallas_kernel_matches_reference(B, n):
     rng = np.random.default_rng(17)
     blocks = rng.integers(0, 256, (B, n), dtype=np.uint8)
     got = np.asarray(hh_pallas.hh256_batch(blocks))
-    want = np.stack([np.frombuffer(hh.hh256(blocks[i].tobytes()), np.uint8)
-                     for i in range(B)])
-    assert np.array_equal(got, want)
+    assert np.array_equal(got, _host_digests(blocks))
 
 
 def test_pallas_kernel_multi_chunk_grid_carry():
@@ -110,3 +113,57 @@ def test_pallas_kernel_multi_chunk_grid_carry():
     for i in idx:
         want = np.frombuffer(hh.hh256(blocks[i].tobytes()), np.uint8)
         assert np.array_equal(got[i], want), i
+
+
+def _compiles_by_function():
+    from minio_tpu.ops import device
+    return {name: row["compiles"] for name, row in
+            device.compile_stats()["by_function"].items()}
+
+
+def _new_compiles(before):
+    after = _compiles_by_function()
+    return {name: n - before.get(name, 0) for name, n in after.items()
+            if n != before.get(name, 0)}
+
+
+@pytest.mark.parametrize("B,n", [
+    # shapes no other test of this file hashes: the first call must be
+    # the one that compiles
+    (8, 171),       # rem 11: the three-byte remainder packet
+    (8, 160),       # rem 0: no remainder update in the program
+    (5, 95),        # B not a multiple of 8; rem 31 (the `rem & 16` form)
+    (3, 2101),      # P = 65 > _PC_NAT: two packet chunks, one valid
+                    # packet in the second
+], ids=["rem", "aligned", "ragged-batch", "multi-chunk"])
+def test_pallas_batch_is_one_program_per_shape(B, n):
+    """``hh_pallas.hh256_batch`` is ONE compiled program per (B, n) —
+    ``mt_hh256_batch``: slice, pad, kernel, limb reassembly, remainder
+    and finalization dispatched once, and nothing under a jnp
+    primitive's name (op by op they are ~840 dispatches per call)."""
+    from minio_tpu.ops import hh_pallas
+    rng = np.random.default_rng(B * 100003 + n)
+    blocks = rng.integers(0, 256, (B, n), dtype=np.uint8)
+    before = _compiles_by_function()
+    got = np.asarray(hh_pallas.hh256_batch(blocks))
+    assert _new_compiles(before) == {"mt_hh256_batch": 1}
+    before = _compiles_by_function()
+    again = np.asarray(hh_pallas.hh256_batch(blocks))
+    assert _new_compiles(before) == {}
+    want = _host_digests(blocks)
+    assert np.array_equal(got, want)
+    assert np.array_equal(again, want)
+
+
+def test_pallas_batch_under_an_outer_trace():
+    """rs_mesh calls ``hh256_batch`` inside ``jit(shard_map(...))``: under
+    an outer trace it inlines and gives the direct call's digests."""
+    import jax
+    from minio_tpu.ops import hh_pallas
+    rng = np.random.default_rng(29)
+    # a shape the test above has compiled: only the outer program is new
+    blocks = rng.integers(0, 256, (8, 171), dtype=np.uint8)
+    direct = np.asarray(hh_pallas.hh256_batch(blocks))
+    traced = np.asarray(jax.jit(lambda x: hh_pallas.hh256_batch(x))(blocks))
+    assert np.array_equal(traced, direct)
+    assert np.array_equal(direct, _host_digests(blocks))
