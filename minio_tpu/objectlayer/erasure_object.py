@@ -84,6 +84,10 @@ def _md5_timed(clock, fn, *args):
 DEFAULT_BLOCK_SIZE = 10 * 1024 * 1024   # blockSizeV1 (cmd/object-api-common.go:32)
 INLINE_THRESHOLD = 128 * 1024           # small-object inline into xl.meta
 ETAG_KEY = "etag"
+# how long a node may answer from memory what only make/delete_bucket or
+# a first bucket configuration changes: the bucket-existence cache below
+# and the cached "no document" of BucketMetadataSys
+BUCKET_TTL_S = 3.0
 # streaming pipeline batch: stripes are encoded/decoded this many bytes at
 # a time so memory is O(batch * n/k) regardless of object size, while each
 # device dispatch still carries enough stripes to fill the MXU
@@ -221,7 +225,7 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         # that changes only through make/delete_bucket.  TTL-bounded for
         # out-of-band wipes; a majority VolumeNotFound at commit time
         # also evicts and surfaces BucketNotFound (see _commit_put).
-        self._bucket_ttl = 3.0
+        self._bucket_ttl = BUCKET_TTL_S
         self._buckets_seen: dict[str, float] = {}
         # pipelined PUT data plane (storage/writers.py): one persistent
         # writer thread per drive with a bounded in-order queue, shared

@@ -271,9 +271,11 @@ def measure_netperf(client: RPCClient,
 
 
 class PeerNotifier:
-    """Client side: best-effort async fan-out of control-plane change
-    notifications to every other node (NotificationSys peer calls,
-    cmd/notification.go)."""
+    """Client side: fan-out of control-plane change notifications to
+    every other node (NotificationSys peer calls, cmd/notification.go).
+    Object and IAM changes are best-effort and asynchronous; a bucket-
+    metadata change is delivered before ``bucket_meta_changed``
+    returns."""
 
     def __init__(self, clients: list[RPCClient]):
         self.clients = clients
@@ -326,7 +328,35 @@ class PeerNotifier:
                 pass           # drain and exit on the next sentinel
 
     def bucket_meta_changed(self, bucket: str) -> None:
-        self._fanout("reload_bucket_meta", bucket=bucket)
+        """Deliver ``reload_bucket_meta`` to every reachable peer BEFORE
+        returning: a policy / object-lock / versioning document one node
+        acknowledged is in force on its peers when the client has its
+        reply (upstream's LoadBucketMetadata peer call waits the same
+        way).  One direct call per peer, in parallel, each under the RPC
+        client's own deadline, retries and breaker — not the lossy
+        queue.  A peer that cannot be reached is skipped: it holds "no
+        document" for at most the existence TTL and re-reads the drives
+        on restart."""
+        from ..obs import trace as _trace
+        rid = _trace.get_request_id()
+        parent = _trace.get_span_parent()
+
+        def one(c: RPCClient):
+            _trace.set_request_id(rid)
+            _trace.set_span_parent(parent)
+            try:
+                c.call("peer", "reload_bucket_meta", _idempotent=True,
+                       bucket=bucket)
+            except Exception:  # noqa: BLE001 — peer down: see above
+                pass
+
+        threads = [threading.Thread(target=one, args=(c,), daemon=True,
+                                    name="mt-peer-reload")
+                   for c in self.clients]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
 
     def object_changed(self, bucket: str, object_name: str = "") -> None:
         """Async per-write fan-out feeding every peer's update tracker
