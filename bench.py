@@ -65,7 +65,7 @@ def _device_peak_tops(dev) -> float | None:
 def main() -> None:
     import jax
     import jax.numpy as jnp
-    from minio_tpu.ops import gf8, rs_kernels, rs_pallas
+    from minio_tpu.ops import gf8, rs_pallas
 
     k, m = 12, 4
     block_size = 1 << 20
@@ -87,10 +87,10 @@ def main() -> None:
     enc_mat = bd_matrix(M[k:])
     # decode: BASELINE config 3 — 2 shards zeroed, reconstruct on device
     present = list(range(2, k + 2))              # lost shards 0,1; use 2..13
-    dec_mat = bd_matrix(rs_kernels.decode_rows(M, k, present, [0, 1]))
+    dec_mat = bd_matrix(gf8.decode_rows(M, k, present, [0, 1]))
     # heal: BASELINE config 4 — 16-drive set, 3 shards offline
     present3 = list(range(3, k + 3))
-    heal_mat = bd_matrix(rs_kernels.decode_rows(M, k, present3, [0, 1, 2]))
+    heal_mat = bd_matrix(gf8.decode_rows(M, k, present3, [0, 1, 2]))
 
     @partial(jax.jit, static_argnums=(2,))
     def chained(mat, d0, iters):

@@ -1000,6 +1000,71 @@ class SpanDisciplineRule(Rule):
         return None
 
 
+# -- layering ----------------------------------------------------------------
+
+
+class LayeringRule(Rule):
+    id = "layering"
+    description = ("the codec seam, as an import rule: objectlayer/ "
+                   "reaches ops/ only through ``codec`` (and ``gf8`` "
+                   "for shard arithmetic), the host hashing library "
+                   "takes only ``gf8`` from ops/, and ops/ imports "
+                   "nothing from objectlayer/ or s3/ — function-level "
+                   "imports count")
+
+    # (importing file or directory, package under minio_tpu, the
+    # submodules of it that may be imported) — exemptions are by file,
+    # here, and nowhere else
+    _ALLOWED = (
+        ("minio_tpu/objectlayer/", "ops", frozenset({"codec", "gf8"})),
+        ("minio_tpu/hashing/bitrot.py", "ops", frozenset({"gf8"})),
+        ("minio_tpu/hashing/highwayhash.py", "ops", frozenset({"gf8"})),
+        ("minio_tpu/ops/", "objectlayer", frozenset()),
+        ("minio_tpu/ops/", "s3", frozenset()),
+    )
+
+    @staticmethod
+    def _targets(mod: Module, node) -> list[str]:
+        """Absolute dotted names one import statement reaches."""
+        if isinstance(node, ast.Import):
+            return [a.name for a in node.names]
+        base: list[str] = []
+        if node.level:
+            pkg = mod.rel.split("/")[:-1]
+            base = pkg[:len(pkg) - (node.level - 1)]
+        if node.module:
+            base = base + node.module.split(".")
+        return [".".join(base + [a.name]) for a in node.names]
+
+    def check_module(self, mod: Module):
+        scoped = [(pkg, allowed) for where, pkg, allowed in self._ALLOWED
+                  if mod.rel == where
+                  or (where.endswith("/") and mod.rel.startswith(where))]
+        if not scoped:
+            return
+        for node in ast.walk(mod.tree):
+            if not isinstance(node, (ast.Import, ast.ImportFrom)):
+                continue
+            for target in self._targets(mod, node):
+                parts = target.split(".")
+                if parts[0] != "minio_tpu" or len(parts) < 2:
+                    continue
+                for pkg, allowed in scoped:
+                    if parts[1] != pkg:
+                        continue
+                    # ``from .. import ops`` / ``from ..ops import *``
+                    # reach the whole package: never in the allowed set
+                    sub = parts[2] if len(parts) > 2 else "*"
+                    if sub not in allowed:
+                        may = ", ".join(f"{pkg}.{a}"
+                                        for a in sorted(allowed)) \
+                            or "nothing"
+                        yield Finding(
+                            mod.rel, node.lineno, self.id,
+                            f"imports {pkg}.{sub} — from {pkg}/ this "
+                            f"file may import {may}")
+
+
 ALL_RULES = [
     BareExceptRule,
     MutableDefaultRule,
@@ -1015,4 +1080,5 @@ ALL_RULES = [
     NamedSkipRule,
     PoolRoutingRule,
     SpanDisciplineRule,
+    LayeringRule,
 ]

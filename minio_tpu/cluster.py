@@ -54,10 +54,6 @@ class Node:
         self.rpc = RPCServer(secret, host=host, port=port, tls=tls)
         register_storage_service(self.rpc, self.drives)
         register_lock_service(self.rpc, self.locker)
-        # codec sidecar (BASELINE north star): peers without a chip can
-        # ship shard blocks here for device encode/reconstruct
-        from .parallel.codec_service import register_codec_service
-        register_codec_service(self.rpc)
         self.rpc.start()
         spec.endpoint = self.rpc.endpoint
         self._all_specs = all_specs

@@ -98,35 +98,14 @@ def streaming_encode(data: bytes, shard_size: int,
     return hh256_frame(data, shard_size)
 
 
-def _interleave(data: bytes, shard_size: int, hashes) -> bytes:
-    out = bytearray()
-    for i, h in enumerate(hashes):
-        out += bytes(h)
-        out += data[i * shard_size:(i + 1) * shard_size]
-    return bytes(out)
-
-
 def streaming_encode_batch(shards, shard_size: int,
-                           algo: str = DEFAULT_BITROT_ALGORITHM,
-                           use_device: bool = False) -> list[bytes]:
-    """Frame a full stripe of equal-length shard files at once.
-
-    With use_device, the per-block HighwayHash runs ON the TPU
-    (ops/hh_pallas), after the erasure encode, so parity AND bitrot
-    digests come off the device (BASELINE config 5).  A device failure
-    raises: the host C path never stands in for it silently."""
+                           algo: str = DEFAULT_BITROT_ALGORITHM
+                           ) -> list[bytes]:
+    """Frame a full stripe of equal-length shard files at once, on the
+    host.  (The device form, digests from the chip, is the codec
+    facade's: ``Erasure.encode_framed``.)"""
     if not is_streaming(algo):
         return [bytes(bytearray(s)) for s in shards]
-    if use_device and shards:
-        from ..ops import codec as _codec
-        # counted and timed like a codec dispatch (op ``hash``); its
-        # kernels are the one-chip forms whichever codec backend asked
-        with _codec.dispatch_span(
-                "hash", "tpu",
-                sum(getattr(s, "nbytes", len(s)) for s in shards),
-                lambda: {"op": "hash", "shards": len(shards),
-                         "shardSize": shard_size}):
-            return _streaming_encode_batch_device(shards, shard_size)
     # streaming_encode takes any contiguous buffer zero-copy (numpy
     # shard rows included) — don't round-trip through bytes()
     return [streaming_encode(s, shard_size, algo) for s in shards]
@@ -154,57 +133,6 @@ def fill_framed(framed2d, shard_size: int,
         if not hh256_fill(row, shard_size):
             return False
     return True
-
-
-def _device_hh256_batch(blocks):
-    """Digests of (B, n) host blocks, (B, 32) uint8 back on the host.
-    Single fused pallas kernel on a TPU, lax.scan packet loop elsewhere
-    (both bit-identical; ops/device.py decides).  Three legs:
-    ``hash.upload`` hands the bytes to JAX; ``hash.launch`` is the call
-    of ``hh256_batch`` to its return — one dispatch of one compiled
-    program (slice, pad, kernel, limb reassembly, remainder, finalize)
-    — until the digests' handle is held; ``hash.fetch`` waits for them
-    and copies them down."""
-    from ..obs import trace as _trace
-    from ..ops import device
-    if device.use_pallas():
-        from ..ops import hh_pallas as hh
-    else:
-        from ..ops import hh_kernels as hh
-    blocks = device.upload("hash", blocks)
-    with _trace.span("tpu", "hash.launch", nbytes=blocks.nbytes):
-        digests = hh.hh256_batch(blocks)
-    return device.fetch("hash", digests)
-
-
-def _streaming_encode_batch_device(shards, shard_size: int) -> list[bytes]:
-    import numpy as np
-
-    from ..obs import trace as _trace
-    with _trace.span("tpu", "hash.prep") as sp:
-        arrs = [np.asarray(bytearray(s), dtype=np.uint8) for s in shards]
-        L = len(arrs[0])
-        if L == 0:
-            return [b"" for _ in arrs]
-        if any(len(a) != L for a in arrs):
-            raise ValueError("shard lengths differ")
-        full, rem = divmod(L, shard_size)
-        stacked = np.stack(arrs)                       # (S, L)
-        sp.nbytes = stacked.nbytes
-        blocks = stacked[:, :full * shard_size].reshape(-1, shard_size) \
-            if full else None
-    hs_full = _device_hh256_batch(blocks).reshape(len(arrs), full, 32) \
-        if full else None
-    hs_tail = _device_hh256_batch(stacked[:, full * shard_size:]) \
-        if rem else None
-    with _trace.span("tpu", "hash.frame", nbytes=stacked.nbytes):
-        out = []
-        for si, arr in enumerate(arrs):
-            digests = [hs_full[si, b].tobytes() for b in range(full)]
-            if rem:
-                digests.append(hs_tail[si].tobytes())
-            out.append(_interleave(arr.tobytes(), shard_size, digests))
-        return out
 
 
 class StreamingBitrotWriter:

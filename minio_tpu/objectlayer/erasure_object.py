@@ -504,20 +504,18 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         widened for TPU batching): a multiple of block_size so framing
         stays batch-invariant.
 
-        On a MESH codec the batch additionally scales with the device
-        count (capped by ``pipeline.mesh_batch_bytes``): one huge
-        object's stripes must fill the whole stripe axis per dispatch,
-        or a 5 TiB PUT saturates one chip while the rest idle — the
-        single-transfer form of ISSUE 12 tentpole c.  Framing is
-        batch-invariant, so the on-disk result is bit-identical at any
-        batch size (test_put_pipeline's contract)."""
+        When one codec dispatch spans several devices (a mesh) the
+        batch additionally scales with their count (capped by
+        ``pipeline.mesh_batch_bytes``): one huge object's stripes must
+        fill the whole stripe axis per dispatch, or a 5 TiB PUT
+        saturates one chip while the rest idle — the single-transfer
+        form of ISSUE 12 tentpole c.  Framing is batch-invariant, so
+        the on-disk result is bit-identical at any batch size
+        (test_put_pipeline's contract)."""
         blocks = max(1, STREAM_BATCH_BYTES // self.block_size)
         codec = self._codec
-        if codec is not None and codec.backend == "mesh":
-            # the mesh the encode is about to run on: a mesh that cannot
-            # be built fails the PUT here rather than at the dispatch
-            from ..parallel import mesh as pmesh
-            devs = pmesh.get_active_mesh().devices.size
+        devs = codec.dispatch_devices() if codec is not None else 1
+        if devs > 1:
             cap = max(1, self._mesh_batch_cap // self.block_size)
             blocks = max(blocks, min(blocks * devs, cap))
         return blocks * self.block_size
@@ -761,40 +759,19 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         fi.metadata = {ETAG_KEY: etag, **opts.user_defined}
         fi.parts = [ObjectPartInfo(1, size, size, etag, mod_time)]
 
-    def _encode_and_frame(self, data: bytes, m: int, fi: FileInfo):
-        """Erasure-encode + bitrot-frame one batch of blocks.
-
-        Fast host path: parity and shard bytes land DIRECTLY in the
-        framed on-disk layout (one copy total), digests filled in place
-        by a GIL-free native pass.  Device codecs keep the fused
-        TPU encode+hash pipeline; other fallbacks take the copying
-        encode_object + streaming_encode_batch route."""
-        ss = fi.erasure.shard_size()
+    def _encode_and_frame(self, data: bytes, m: int, fi: FileInfo,
+                          out=None):
+        """Erasure-encode + bitrot-frame one batch of blocks into the
+        per-drive framed rows: ``Erasure.encode_framed``, where the
+        route is chosen (``out``: a recycled buffer of its
+        ``framed_shape``).  Without parity the body is the one shard
+        and only the framing remains."""
         if m > 0:
-            codec = self._codec_for(m)
-            if (codec.backend == "mesh"
-                    and self.bitrot_algo == bitrot.HIGHWAYHASH256S):
-                # multi-chip fused pipeline: parity via ICI psum XOR
-                # fan-in, per-shard digests all_gathered — one sharded
-                # dispatch per block batch (SURVEY §2.3 contract)
-                from ..ops import rs_mesh
-                return list(rs_mesh.encode_object_framed_fused(
-                    codec.data_blocks, m, codec.block_size, data))
-            if (codec.backend == "numpy"
-                    and self.bitrot_algo == bitrot.HIGHWAYHASH256S):
-                from ..ops import gf8_native
-                if gf8_native.available():
-                    framed2d = codec.encode_object_framed(data)
-                    if bitrot.fill_framed(framed2d, ss, self.bitrot_algo):
-                        return list(framed2d)
-            shards = codec.encode_object(data)      # ONE device dispatch
-        else:
-            shards = [np.frombuffer(data, dtype=np.uint8)]
-        # bitrot digests fuse onto the device when the codec runs there:
-        # parity + per-block HighwayHash from one pipeline (ops/hh_kernels)
+            return self._codec_for(m).encode_framed(
+                data, self.bitrot_algo, out=out)
         return bitrot.streaming_encode_batch(
-            shards, ss, self.bitrot_algo,
-            use_device=(m > 0 and codec.is_device))
+            [np.frombuffer(data, dtype=np.uint8)],
+            fi.erasure.shard_size(), self.bitrot_algo)
 
     def _commit_fanout(self, write_one, shuffled, wq, framed) -> list:
         """One commit-class fan-out (one storage call per drive) with
@@ -993,24 +970,12 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         _md5_timed(clock, md5fast.SCHED.update, h, chunk)
         stats["md5_s"] += time.perf_counter() - t0
 
-    def _framed_fast_path(self, m: int) -> bool:
-        """True when _encode_and_frame takes the host one-copy framed
-        route (the only path worth recycling output buffers for)."""
-        if m <= 0 or self.bitrot_algo != bitrot.HIGHWAYHASH256S:
-            return False
-        if self._codec_for(m).backend != "numpy":
-            return False
-        from ..hashing.highwayhash import _get_lib
-        from ..ops import gf8_native
-        # both natives must be present: without hh256_fill the framed
-        # encode would be thrown away and re-done by the fallback
-        return gf8_native.available() and _get_lib() is not None
-
     def _encode_framed_pooled(self, chunk, m: int, fi: FileInfo, stats):
         """Encode + frame one batch, recycling the framed 2-D buffer
-        through utils/bufpool when the host fast path runs.  Returns
-        (framed_rows, release_cb) — release fires once every drive
-        wrote the batch (memory stays O(depth x batch))."""
+        through utils/bufpool when the codec's route fills one in place
+        (``framed_shape``).  Returns (framed_rows, release_cb) —
+        release fires once every drive wrote the batch (memory stays
+        O(depth x batch))."""
         from ..obs import stages as _stages
         t0 = time.perf_counter()
         try:
@@ -1019,19 +984,13 @@ class ErasureObjects(MultipartOps, ObjectLayer):
             # time, keeping the serial reconciliation exact on device
             # backends too
             with _stages.stage("encode"):
-                if len(chunk) and self._framed_fast_path(m):
-                    codec = self._codec_for(m)
-                    buf = bufpool.GLOBAL.acquire(
-                        codec.framed_shape(len(chunk)))
-                    framed2d = codec.encode_object_framed(chunk,
-                                                          out=buf)
-                    if bitrot.fill_framed(framed2d,
-                                          fi.erasure.shard_size(),
-                                          self.bitrot_algo):
-                        return list(framed2d), \
-                            (lambda b=buf: bufpool.GLOBAL.release(b))
-                    bufpool.GLOBAL.release(buf)   # native hash missing
-                return self._encode_and_frame(chunk, m, fi), None
+                shape = self._codec_for(m).framed_shape(
+                    len(chunk), self.bitrot_algo) if m > 0 else None
+                if shape is None:
+                    return self._encode_and_frame(chunk, m, fi), None
+                buf = bufpool.GLOBAL.acquire(shape)
+                framed = self._encode_and_frame(chunk, m, fi, out=buf)
+                return framed, (lambda: bufpool.GLOBAL.release(buf))
         finally:
             stats["encode_s"] += time.perf_counter() - t0
 
@@ -1668,40 +1627,11 @@ class ErasureObjects(MultipartOps, ObjectLayer):
                 raise ReadQuorumError("no parity to reconstruct from")
             # the OBJECT's persisted geometry picks the matrix — a
             # storage-class parity differs from the layer default
-            codec = self._codec_for(m)
             present = [i for i in range(k + m) if shards[i] is not None][:k]
-            sfsize = fi.erasure.shard_file_size(part_size)
-            mat = codec.matrix
-            from ..ops import rs_kernels
-            rows = rs_kernels.decode_rows(mat, k, present, missing_data)
-            rebuilt_full = None
-            if nfull:
-                # identical survivor pattern across all full stripes ->
-                # one batched reconstruction dispatch
-                surv = np.stack([shards[i][: nfull * ssize]
-                                 .reshape(nfull, ssize) for i in present],
-                                axis=1)  # (nfull, k, ssize)
-                if codec.is_device:
-                    rebuilt_full = codec.apply_matrix(rows, surv)
-                else:
-                    rebuilt_full = np.stack(
-                        [gf8.gf_matmul(rows, surv[b]) for b in range(nfull)])
-            rebuilt_tail = None
-            if tail:
-                t_ssize = gf8.ceil_frac(tail, k)
-                surv_t = np.stack(
-                    [shards[i][nfull * ssize: nfull * ssize + t_ssize]
-                     for i in present])  # (k, t_ssize)
-                if codec.is_device:
-                    rebuilt_tail = codec.apply_matrix(rows, surv_t)
-                else:
-                    rebuilt_tail = gf8.gf_matmul(rows, surv_t)
-            for j, i in enumerate(missing_data):
-                full = np.empty(sfsize, dtype=np.uint8)
-                if rebuilt_full is not None:
-                    full[: nfull * ssize] = rebuilt_full[:, j].reshape(-1)
-                if rebuilt_tail is not None:
-                    full[nfull * ssize:] = rebuilt_tail[j]
+            rebuilt = self._codec_for(m).reconstruct_files(
+                [shards[i] for i in present], present, missing_data,
+                part_size, block_size=bs)
+            for i, full in zip(missing_data, rebuilt):
                 shards[i] = full
         # concatenate data blocks, trimming per-block padding: one
         # strided copy per shard over ALL blocks (the mirror of

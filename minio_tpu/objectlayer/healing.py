@@ -18,7 +18,6 @@ from typing import Optional
 import numpy as np
 
 from ..hashing import bitrot
-from ..ops import gf8
 from ..storage import errors as serrors
 from ..storage.datatypes import ErasureInfo, FileInfo
 from ..storage.xl_storage import SYS_DIR
@@ -205,9 +204,12 @@ def heal_object(er: ErasureObjects, bucket: str, object_name: str,
                 return res
             present = sorted(shards)[:k]
             wanted = healable
-            rebuilt = _reconstruct_shards(er, fi, present,
-                                          [shards[i] for i in present],
-                                          wanted, part.size)
+            # matrix for the OBJECT's geometry: storage-class parity
+            # may differ from the layer default
+            rebuilt = er._codec_for(
+                fi.erasure.parity_blocks).reconstruct_files(
+                    [shards[i] for i in present], present, wanted,
+                    part.size, block_size=fi.erasure.block_size)
             for j, i in enumerate(wanted):
                 if i in stage_errs:
                     continue            # drive already failed staging
@@ -306,41 +308,3 @@ def _disk_fileinfo(fi: FileInfo, shard_idx: int) -> FileInfo:
     # assigns its own extent) or stages regular part files
     dfi.seg = None
     return dfi
-
-
-def _reconstruct_shards(er: ErasureObjects, fi: FileInfo, present: list[int],
-                        surviving: list[np.ndarray], wanted: list[int],
-                        part_size: int) -> list[np.ndarray]:
-    """Rebuild full shard files for ``wanted`` indices (data or parity),
-    batching all full stripes into one device dispatch."""
-    from ..ops import rs_kernels
-    k = fi.erasure.data_blocks
-    bs = fi.erasure.block_size
-    ssize = fi.erasure.shard_size()
-    nfull = part_size // bs
-    tail = part_size - nfull * bs
-    sfsize = fi.erasure.shard_file_size(part_size)
-    # matrix for the OBJECT's geometry: storage-class parity may differ
-    # from the layer default
-    codec = er._codec_for(fi.erasure.parity_blocks)
-    rows = rs_kernels.decode_rows(codec.matrix, k, present, wanted)
-    outs = [np.empty(sfsize, dtype=np.uint8) for _ in wanted]
-    if nfull:
-        surv = np.stack([s[: nfull * ssize].reshape(nfull, ssize)
-                         for s in surviving], axis=1)
-        if codec.is_device:
-            reb = codec.apply_matrix(rows, surv)
-        else:
-            reb = np.stack([gf8.gf_matmul(rows, surv[b])
-                            for b in range(nfull)])
-        for j in range(len(wanted)):
-            outs[j][: nfull * ssize] = reb[:, j].reshape(-1)
-    if tail:
-        t_ssize = gf8.ceil_frac(tail, k)
-        surv_t = np.stack([s[nfull * ssize: nfull * ssize + t_ssize]
-                           for s in surviving])
-        reb_t = codec.apply_matrix(rows, surv_t) if codec.is_device \
-            else gf8.gf_matmul(rows, surv_t)
-        for j in range(len(wanted)):
-            outs[j][nfull * ssize:] = reb_t[j]
-    return outs

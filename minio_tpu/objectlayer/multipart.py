@@ -158,11 +158,7 @@ class MultipartOps:
                 md5.update(chunk)
                 size += len(chunk)
                 # the upload's persisted geometry wins: a storage-class
-                # parity chosen at initiate applies to every part.
-                # Same framed fast path as single-part PUT: shard bytes
-                # land once in their final frame layout, digests filled
-                # by one native pass (vs the old copying
-                # encode_object + streaming_encode route, ~4x slower)
+                # parity chosen at initiate applies to every part
                 framed = self._encode_and_frame(
                     chunk, fi.erasure.parity_blocks, fi)
 

@@ -17,6 +17,14 @@ opt-in raises instead of computing on the host under a device's name.
 
 Shard layout, padding, and matrix construction are bit-identical between
 backends (and with klauspost/reedsolomon's defaults).
+
+The object layer reaches the kernels through two methods and nothing
+else: ``Erasure.encode_framed`` (a batch of body bytes -> the k+m
+bitrot-framed shard rows; which route encodes and frames is decided
+there) and ``Erasure.reconstruct_files`` (k surviving shard files ->
+the wanted ones, degraded GET and heal).  The device bitrot leg
+(``_streaming_encode_batch_device``) lives here, next to the kernels
+it drives.
 """
 
 from __future__ import annotations
@@ -27,6 +35,7 @@ import time
 import numpy as np
 
 from ..admin import metrics as _metrics
+from ..hashing import bitrot
 from ..obs import trace as _obstrace
 from . import gf8, gf8_ref
 
@@ -101,6 +110,66 @@ def _batcher(codec: "Erasure"):
     except Exception:  # pragma: no cover — parallel plane unavailable
         return None
     return _b.GLOBAL if _b.CONFIG.on() else None
+
+
+def _interleave(data: bytes, shard_size: int, hashes) -> bytes:
+    out = bytearray()
+    for i, h in enumerate(hashes):
+        out += bytes(h)
+        out += data[i * shard_size:(i + 1) * shard_size]
+    return bytes(out)
+
+
+def _device_hh256_batch(blocks):
+    """Digests of (B, n) host blocks, (B, 32) uint8 back on the host.
+    Single fused pallas kernel on a TPU, lax.scan packet loop elsewhere
+    (both bit-identical; ops/device.py decides).  Three legs:
+    ``hash.upload`` hands the bytes to JAX; ``hash.launch`` is the call
+    of ``hh256_batch`` to its return — one dispatch of one compiled
+    program (slice, pad, kernel, limb reassembly, remainder, finalize)
+    — until the digests' handle is held; ``hash.fetch`` waits for them
+    and copies them down."""
+    from . import device
+    if device.use_pallas():
+        from . import hh_pallas as hh
+    else:
+        from . import hh_kernels as hh
+    blocks = device.upload("hash", blocks)
+    with _obstrace.span("tpu", "hash.launch", nbytes=blocks.nbytes):
+        digests = hh.hh256_batch(blocks)
+    return device.fetch("hash", digests)
+
+
+def _streaming_encode_batch_device(shards, shard_size: int) -> list[bytes]:
+    """Frame a full stripe of equal-length shard files with the
+    per-block HighwayHash run ON the device, after the erasure encode,
+    so parity AND bitrot digests come off it (BASELINE config 5).  A
+    device failure raises: the host C path never stands in for it
+    silently."""
+    with _obstrace.span("tpu", "hash.prep") as sp:
+        arrs = [np.asarray(bytearray(s), dtype=np.uint8) for s in shards]
+        L = len(arrs[0])
+        if L == 0:
+            return [b"" for _ in arrs]
+        if any(len(a) != L for a in arrs):
+            raise ValueError("shard lengths differ")
+        full, rem = divmod(L, shard_size)
+        stacked = np.stack(arrs)                       # (S, L)
+        sp.nbytes = stacked.nbytes
+        blocks = stacked[:, :full * shard_size].reshape(-1, shard_size) \
+            if full else None
+    hs_full = _device_hh256_batch(blocks).reshape(len(arrs), full, 32) \
+        if full else None
+    hs_tail = _device_hh256_batch(stacked[:, full * shard_size:]) \
+        if rem else None
+    with _obstrace.span("tpu", "hash.frame", nbytes=stacked.nbytes):
+        out = []
+        for si, arr in enumerate(arrs):
+            digests = [hs_full[si, b].tobytes() for b in range(full)]
+            if rem:
+                digests.append(hs_tail[si].tobytes())
+            out.append(_interleave(arr.tobytes(), shard_size, digests))
+        return out
 
 
 class Erasure:
@@ -379,18 +448,116 @@ class Erasure:
             "backend": self.backend,
         }
 
-    def framed_shape(self, total: int, digest: int = 32) -> tuple[int, int]:
-        """Shape of encode_object_framed's output for a ``total``-byte
-        object — lets the put pipeline acquire a recycled buffer
-        (utils/bufpool.py) before encoding."""
-        k, m = self.data_blocks, self.parity_blocks
-        bs = self.block_size
-        ssize = self.shard_size()
-        nfull, tail_len = divmod(total, bs)
-        tail_ss = gf8.ceil_frac(tail_len, k)
-        F = digest + ssize
-        flen = nfull * F + ((digest + tail_ss) if tail_len else 0)
-        return (k + m, flen)
+    # -- the object layer's two entries -------------------------------------
+
+    def dispatch_devices(self) -> int:
+        """Devices one dispatch of this codec spans — what a stream
+        batch must fill.  On ``mesh`` it is the size of the mesh the
+        encode is about to run on: a mesh that cannot be built fails
+        the caller here rather than at the dispatch."""
+        if self.backend != "mesh":
+            return 1
+        from ..parallel import mesh as pmesh
+        return pmesh.get_active_mesh().devices.size
+
+    def _fills_in_place(self, algo: str) -> bool:
+        """True when :meth:`encode_framed` takes the host one-copy
+        route.  Both natives must be present: without ``hh256_fill``
+        the framed encode would be thrown away and done again by the
+        copying route."""
+        if self.backend != "numpy" or algo != bitrot.HIGHWAYHASH256S:
+            return False
+        from ..hashing.highwayhash import _get_lib
+        from . import gf8_native
+        return gf8_native.available() and _get_lib() is not None
+
+    def framed_shape(self, total: int,
+                     algo: str = bitrot.DEFAULT_BITROT_ALGORITHM
+                     ) -> tuple[int, int] | None:
+        """Shape of the buffer :meth:`encode_framed` fills in place for
+        a ``total``-byte batch — lets the put pipeline acquire a
+        recycled one (utils/bufpool.py) before encoding — or None when
+        the route it will take makes its own rows (a device backend, a
+        missing native library, an empty batch)."""
+        if not total or not self._fills_in_place(algo):
+            return None
+        *_, flen = gf8.framed_layout(self.block_size, self.data_blocks,
+                                     total)
+        return (self.data_blocks + self.parity_blocks, flen)
+
+    def encode_framed(self, data,
+                      algo: str = bitrot.DEFAULT_BITROT_ALGORITHM,
+                      out: np.ndarray | None = None) -> list:
+        """One batch of body bytes -> the k+m bitrot-framed shard rows,
+        each the final on-disk layout (``gf8.framed_layout``).  The one
+        entry of every PUT path, and the one place the route is chosen:
+
+          * ``mesh`` + HighwayHash256S: the fused multi-chip pipeline —
+            parity via ICI XOR fan-in, per-shard digests all_gathered,
+            one sharded dispatch per block batch (rs_mesh);
+          * ``numpy`` with both native libraries: shard bytes and parity
+            land once in the framed layout, digests filled in place by
+            one GIL-free pass — into ``out`` when its shape is
+            :meth:`framed_shape`'s, stale bytes and all;
+          * otherwise ``encode_object`` (ONE parity dispatch) + streaming
+            framing, with the digests from the device too when the
+            codec runs there (op ``hash``: counted and timed like a
+            codec dispatch; its kernels are the one-chip forms
+            whichever device backend asked)."""
+        if self.backend == "mesh" and algo == bitrot.HIGHWAYHASH256S:
+            from . import rs_mesh
+            return list(rs_mesh.encode_object_framed_fused(
+                self.data_blocks, self.parity_blocks, self.block_size,
+                data))
+        ss = self.shard_size()
+        if self._fills_in_place(algo):
+            framed2d = self.encode_object_framed(data, out=out)
+            if bitrot.fill_framed(framed2d, ss, algo):
+                return list(framed2d)
+        shards = self.encode_object(data)
+        if self.is_device and bitrot.is_streaming(algo):
+            with dispatch_span(
+                    "hash", "tpu", sum(_nbytes(s) for s in shards),
+                    lambda: {"op": "hash", "shards": len(shards),
+                             "shardSize": ss}):
+                return _streaming_encode_batch_device(shards, ss)
+        return bitrot.streaming_encode_batch(shards, ss, algo)
+
+    def reconstruct_files(self, surviving, present, wanted,
+                          part_size: int,
+                          block_size: int | None = None
+                          ) -> list[np.ndarray]:
+        """Whole shard files for the ``wanted`` indices (data or parity)
+        from the k ``surviving`` ones (unframed, in ``present``'s
+        order) over ``part_size`` bytes of object — degraded GET and
+        heal.  The decode rows are solved once; the survivor pattern is
+        the same across all full stripes, so they go in ONE batched
+        dispatch and the short tail stripe in a second.  ``block_size``
+        is the object's persisted one where it differs from this
+        codec's.  A device dispatch is counted (op ``matmul``) and may
+        ride the batcher; the host engine is called bare."""
+        k = self.data_blocks
+        bs = self.block_size if block_size is None else block_size
+        ssize = gf8.shard_size(bs, k)
+        nfull, tail = divmod(part_size, bs)
+        rows = gf8.decode_rows(self.matrix, k, list(present), list(wanted))
+        apply = self.apply_matrix if self.is_device else self._apply_matrix
+        outs = [np.empty(gf8.shard_file_size(bs, k, part_size),
+                         dtype=np.uint8) for _ in wanted]
+        if nfull:
+            surv = np.stack([s[: nfull * ssize].reshape(nfull, ssize)
+                             for s in surviving], axis=1)  # (nfull, k, ssize)
+            reb = apply(rows, surv)
+            for j, o in enumerate(outs):
+                o[: nfull * ssize] = reb[:, j].reshape(-1)
+        if tail:
+            t_ssize = gf8.ceil_frac(tail, k)
+            surv_t = np.stack([s[nfull * ssize: nfull * ssize + t_ssize]
+                               for s in surviving])        # (k, t_ssize)
+            reb_t = apply(rows, surv_t)
+            for j, o in enumerate(outs):
+                o[nfull * ssize:] = reb_t[j]
+        return outs
 
     def encode_object_framed(self, data, digest: int = 32,
                              out: np.ndarray | None = None) -> np.ndarray:
@@ -403,8 +570,8 @@ class Erasure:
         in place (hashing.highwayhash.hh256_fill).  One copy total:
         data bytes land once in their final frame position; parity is
         computed by the native kernel directly into its frame payloads.
-        Requires the native GF8 library (callers fall back to
-        encode_object + streaming framing)."""
+        Requires the native GF8 library (:meth:`encode_framed` takes
+        the encode_object + streaming framing route without it)."""
         total = _nbytes(data)
         with self._dispatch("encode-framed", total,
                             blocks=-(-total // self.block_size)):
@@ -423,10 +590,9 @@ class Erasure:
         k, m = self.data_blocks, self.parity_blocks
         bs = self.block_size
         ssize = self.shard_size()
-        nfull, tail_len = divmod(total, bs)
-        tail_ss = gf8.ceil_frac(tail_len, k)
+        nfull, tail_len, tail_ss, flen = gf8.framed_layout(
+            bs, k, total, digest)
         F = digest + ssize
-        flen = nfull * F + ((digest + tail_ss) if tail_len else 0)
         # np.empty + targeted clears: every payload byte is overwritten
         # below (data copy / native parity matmul), so a full calloc
         # would memset ~6 MB per 4 MiB object only to overwrite it.

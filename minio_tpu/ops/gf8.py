@@ -257,6 +257,42 @@ def shard_file_offset(block_size: int, data_blocks: int, start_offset: int,
     return till_offset
 
 
+def framed_layout(block_size: int, data_blocks: int, total: int,
+                  digest: int = 32) -> tuple[int, int, int, int]:
+    """The bitrot-framed shard file of a ``total``-byte batch: per full
+    erasure block one ``[digest][shard block]`` frame, then one short
+    frame for a tail block (cmd/bitrot-streaming.go framing around
+    cmd/erasure-encode.go blocks).  Returns (full blocks, tail bytes,
+    tail shard size, framed file length) — the one place the layout's
+    arithmetic lives; every route that writes it asks here."""
+    nfull, tail_len = divmod(total, block_size)
+    tail_ss = ceil_frac(tail_len, data_blocks)
+    flen = nfull * (digest + shard_size(block_size, data_blocks)) \
+        + ((digest + tail_ss) if tail_len else 0)
+    return nfull, tail_len, tail_ss, flen
+
+
+def decode_rows(matrix: np.ndarray, data_blocks: int,
+                present: list[int], wanted: list[int]) -> np.ndarray:
+    """Host-side tiny GF solve: rows mapping k survivors -> wanted shards.
+
+    present: indices (sorted) of the k shards used for reconstruction.
+    wanted:  shard indices to produce (data or parity).
+    Returns (len(wanted), k) GF coefficient rows to feed apply_matrix.
+    """
+    assert len(present) == data_blocks
+    sub = np.asarray(matrix)[present]              # (k, k)
+    dec = gf_mat_inv(sub)                          # survivors -> data
+    rows = []
+    for w in wanted:
+        if w < data_blocks:
+            rows.append(dec[w])
+        else:
+            # parity row composed with the decode: parity_w = M[w] @ data
+            rows.append(gf_matmul(np.asarray(matrix)[w][None, :], dec)[0])
+    return np.stack(rows).astype(np.uint8)
+
+
 def split(data: bytes | bytearray | memoryview | np.ndarray,
           data_shards: int) -> np.ndarray:
     """reedsolomon Split semantics: k equal shards, zero-padded tail.

@@ -151,27 +151,6 @@ def encode_parity(data_shards: np.ndarray, parity: int,
     return out[0] if squeeze else out
 
 
-def decode_rows(matrix: np.ndarray, data_blocks: int,
-                present: list[int], wanted: list[int]) -> np.ndarray:
-    """Host-side tiny GF solve: rows mapping k survivors -> wanted shards.
-
-    present: indices (sorted) of the k shards used for reconstruction.
-    wanted:  shard indices to produce (data or parity).
-    Returns (len(wanted), k) GF coefficient rows to feed apply_matrix.
-    """
-    assert len(present) == data_blocks
-    sub = np.asarray(matrix)[present]              # (k, k)
-    dec = gf8.gf_mat_inv(sub)                      # survivors -> data
-    rows = []
-    for w in wanted:
-        if w < data_blocks:
-            rows.append(dec[w])
-        else:
-            # parity row composed with the decode: parity_w = M[w] @ data
-            rows.append(gf8.gf_matmul(np.asarray(matrix)[w][None, :], dec)[0])
-    return np.stack(rows).astype(np.uint8)
-
-
 def reconstruct(shards: list[np.ndarray | None], data_blocks: int,
                 parity_blocks: int, data_only: bool = False,
                 matrix: np.ndarray | None = None,
@@ -201,7 +180,7 @@ def reconstruct(shards: list[np.ndarray | None], data_blocks: int,
     if not missing:
         return out
     use = present[:data_blocks]
-    rows = decode_rows(matrix, data_blocks, use, missing)
+    rows = gf8.decode_rows(matrix, data_blocks, use, missing)
     stack = np.stack([np.asarray(shards[i], dtype=np.uint8) for i in use])
     rebuilt = apply(rows, stack[None])[0]
     for j, i in enumerate(missing):
@@ -220,5 +199,6 @@ def reconstruct_batch(shards: np.ndarray, present: list[int],
     """
     if matrix is None:
         matrix = gf8.rs_matrix(data_blocks, data_blocks + parity_blocks)
-    rows = decode_rows(matrix, data_blocks, list(present), list(wanted))
+    rows = gf8.decode_rows(matrix, data_blocks, list(present),
+                           list(wanted))
     return apply_matrix(rows, shards)

@@ -157,8 +157,8 @@ def reconstruct_batch(shards: np.ndarray, present: list[int],
     """Batched same-pattern reconstruction over the mesh."""
     if matrix is None:
         matrix = gf8.rs_matrix(data_blocks, data_blocks + parity_blocks)
-    rows = rs_kernels.decode_rows(matrix, data_blocks, list(present),
-                                  list(wanted))
+    rows = gf8.decode_rows(matrix, data_blocks, list(present),
+                           list(wanted))
     return apply_matrix(rows, shards)
 
 
@@ -413,8 +413,8 @@ def encode_object_framed_fused(data_blocks: int, parity_blocks: int,
                                block_size: int, data,
                                digest: int = 32) -> np.ndarray:
     """Whole object -> bitrot-framed shard files with parity AND digests
-    from the fused mesh pipeline (the multi-chip form of
-    Erasure.encode_object_framed + fill_framed).
+    from the fused mesh pipeline (the multi-chip route of
+    Erasure.encode_framed, its one caller).
 
     Returns (k+m, framed_len) uint8: per erasure block a
     [32B HighwayHash-256 digest][shard payload] frame, bit-identical to
@@ -428,10 +428,9 @@ def encode_object_framed_fused(data_blocks: int, parity_blocks: int,
     total = buf.size
     bs = block_size
     ssize = gf8.shard_size(bs, k)
-    nfull, tail_len = divmod(total, bs)
-    tail_ss = gf8.ceil_frac(tail_len, k)
+    nfull, tail_len, tail_ss, flen = gf8.framed_layout(bs, k, total,
+                                                       digest)
     F = digest + ssize
-    flen = nfull * F + ((digest + tail_ss) if tail_len else 0)
     out = np.zeros((k + m_par, flen), dtype=np.uint8)
     if nfull:
         blocks = np.zeros((nfull, k, ssize), dtype=np.uint8)

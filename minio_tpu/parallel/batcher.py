@@ -9,9 +9,8 @@ batching layer from inference serving applied to the storage data
 plane — the same combining shape as the MD5 ``LaneScheduler``
 (hashing/md5fast.py), one level up:
 
-  * concurrent callers (the PUT writer plane, GET reconstruction,
-    heal, and the sidecar's ``/raw/codec-*`` handlers) submit
-    ``(rows, (B, k, n) stripes)`` work items;
+  * concurrent callers (the PUT writer plane, GET reconstruction and
+    heal) submit ``(rows, (B, k, n) stripes)`` work items;
   * items are **bucketed** by geometry + operation — the full key is
     ``(op, backend, k, m, block_size, n, rows-bytes)`` so everything
     in one bucket is the same matmul over the same coefficient rows
@@ -39,9 +38,9 @@ shutdown — tests pin the ``mt-codec-*`` naming rule for their own
 worker threads instead.
 
 On a mesh-backend codec the one fused dispatch rides the existing
-pjit/shard_map plumbing (parallel/mesh.py + ops/rs_mesh.py), so many
-frontend nodes — local callers and RemoteCodec sidecar clients alike —
-share one device mesh through one combining queue.
+pjit/shard_map plumbing (parallel/mesh.py + ops/rs_mesh.py), so every
+caller in the process shares one device mesh through one combining
+queue.
 
 Every dispatch lands in the ``mt_codec_batch_*`` metric families and
 is one ``tpu``-type span ``<op>.batch`` (obs/trace.py ``span``: always
@@ -124,11 +123,9 @@ CONFIG = CodecConfig()
 # -- shared per-geometry codec registry -------------------------------------
 #
 # One Erasure instance per (k, m, blockSize, backend) for the whole
-# process: the sidecar handlers, the batcher's bucket executors, and
-# any direct caller resolve here, so a geometry maps to ONE codec (and
-# one compiled-kernel cache line) instead of one per call site.  The
-# old per-module lru_cache in codec_service gave the sidecar its own
-# unbounded-lifetime copies.
+# process: the batcher's bucket executors and any direct caller resolve
+# here, so a geometry maps to ONE codec (and one compiled-kernel cache
+# line) instead of one per call site.
 
 _CODEC_MU = mtlock("codec.registry")
 _CODECS: dict[tuple, Erasure] = {}

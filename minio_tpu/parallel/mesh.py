@@ -188,10 +188,8 @@ def _ring_apply(mesh: Mesh, n_rows: int, k: int):
 def _reconstruct_rows(data_blocks: int, parity_blocks: int,
                       present: list[int], wanted: list[int]) -> np.ndarray:
     """Host-side GF solve shared by the psum and ring reconstructs."""
-    from minio_tpu.ops import rs_kernels
     M = gf8.rs_matrix(data_blocks, data_blocks + parity_blocks)
-    return rs_kernels.decode_rows(M, data_blocks, list(present),
-                                  list(wanted))
+    return gf8.decode_rows(M, data_blocks, list(present), list(wanted))
 
 
 def ring_reconstruct(mesh: Mesh, data_blocks: int, parity_blocks: int,

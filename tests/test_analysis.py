@@ -568,6 +568,80 @@ def test_span_discipline_canary(tmp_path):
     assert not clean3, clean3
 
 
+def test_layering_canary(tmp_path):
+    # the object layer reaching around the codec facade, lazily too
+    bad = _lint(tmp_path, {"objectlayer/put.py": """
+        from ..ops.codec import Erasure
+        from ..ops import gf8
+
+        def encode(data):
+            from ..ops import rs_mesh
+            import minio_tpu.ops.gf8_native as native
+            return Erasure, gf8, rs_mesh, native
+        """})
+    hits = sorted((f.line, f.message.split(" — ")[0])
+                  for f in bad if f.rule == "layering")
+    assert hits == [(6, "imports ops.rs_mesh"),
+                    (7, "imports ops.gf8_native")], bad
+    # the whole package is never in the allowed set
+    bad2 = _lint(tmp_path, {"objectlayer/x.py": """
+        from .. import ops
+
+        def f():
+            return ops
+        """})
+    assert any(f.rule == "layering" and "ops.*" in f.message
+               for f in bad2), bad2
+    # the host hashing library takes arithmetic only; the kernels never
+    # call up into the object layer or the S3 front
+    bad3 = _lint(tmp_path, {
+        "hashing/bitrot.py": """
+            from ..ops.gf8 import ceil_frac
+
+            def device_leg():
+                from ..ops import device
+                return device, ceil_frac
+            """,
+        "ops/codec.py": """
+            def up():
+                from ..objectlayer import erasure_object
+                from minio_tpu.s3.server import S3Server
+                return erasure_object, S3Server
+            """})
+    assert sorted((f.path, f.line) for f in bad3
+                  if f.rule == "layering") == [
+        ("minio_tpu/hashing/bitrot.py", 5),
+        ("minio_tpu/ops/codec.py", 3),
+        ("minio_tpu/ops/codec.py", 4)], bad3
+    # the seam respected — and files outside the rule's table (a device
+    # module that lives in hashing/, the parallel plane) are not its
+    # business
+    clean = _lint(tmp_path, {
+        "objectlayer/put.py": """
+            from ..hashing import bitrot
+            from ..ops import gf8
+            from ..ops.codec import Erasure
+
+            def f():
+                return bitrot, gf8, Erasure
+            """,
+        "hashing/md5_device.py": """
+            from ..ops import device
+
+            def f():
+                return device
+            """,
+        "ops/codec.py": """
+            from ..hashing import bitrot
+            from . import gf8
+
+            def f():
+                from ..parallel import batcher
+                return bitrot, gf8, batcher
+            """})
+    assert not clean, clean
+
+
 def test_label_cardinality_canary(tmp_path):
     # shape A: a counter-registry call labelling an mt_ family by a
     # request-derived key outside the bounded metering registry

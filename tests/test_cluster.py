@@ -310,6 +310,17 @@ def test_cluster_put_get_across_nodes(cluster):
         ["shared-object"]
 
 
+def test_cluster_node_serves_storage_and_locks_only(cluster):
+    """A node's internode listener carries the storage and lock
+    services and no other raw route: there is no codec service for
+    chip-less peers (nothing could construct its client; removed in
+    PR 29)."""
+    for node in cluster:
+        assert set(node.rpc._raw) == {"storage-write", "storage-read"}
+        assert not [n for n in node.rpc._raw_stream
+                    if n.startswith("codec")]
+
+
 def test_cluster_survives_node_loss(cluster):
     n0, n1, n2 = cluster
     n0.layer.make_bucket("bkt")

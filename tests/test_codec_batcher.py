@@ -322,13 +322,15 @@ def test_mesh_fused_framed_path_rides_batcher_bit_identical():
 
 # -- shared geometry registry ----------------------------------------------
 
-def test_sidecar_and_local_share_one_codec_per_geometry():
-    from minio_tpu.parallel.codec_service import _codec
-    a = _codec(4, 2, 64 * 1024, "numpy")
-    b = _codec(4, 2, 64 * 1024, "numpy")
-    c = batcher.codec_for(4, 2, 64 * 1024, "numpy")
-    assert a is b is c
-    assert _codec(4, 2, 32 * 1024, "numpy") is not a
+def test_callers_share_one_codec_per_geometry():
+    a = batcher.codec_for(4, 2, 64 * 1024, "numpy")
+    b = batcher.codec_for(4, 2, 64 * 1024, "numpy")
+    assert a is b
+    assert batcher.codec_for(4, 2, 32 * 1024, "numpy") is not a
+    # 'auto' is resolved before keying: one instance per geometry under
+    # the name it resolves to, not a second one under 'auto'
+    auto = batcher.codec_for(4, 2, 64 * 1024, "auto")
+    assert auto is batcher.codec_for(4, 2, 64 * 1024, auto.backend)
 
 
 # -- live reload ------------------------------------------------------------

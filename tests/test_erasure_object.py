@@ -23,6 +23,8 @@ from minio_tpu.storage import errors as serrors
 from minio_tpu.storage.faulty import BadDisk
 from minio_tpu.storage.xl_storage import XLStorage
 
+from . import shard_files
+
 BS = 64 * 1024  # small block size so multi-stripe paths get exercised
 
 
@@ -126,19 +128,12 @@ def test_read_with_corrupt_shard(tmp_path):
     er.make_bucket("bkt")
     data = _data(BS + 50, seed=2)
     er.put_object("bkt", "obj", data)
-    # corrupt one shard file on disk 0 (any part file found)
-    corrupted = 0
+    # corrupt the shard on two drives, wherever each keeps it (this
+    # size is packed into the drive's segment file: no part.N file)
     for disk in er.disks[:2]:
-        root = disk.root
-        for dirpath, _, files in os.walk(os.path.join(root, "bkt")):
-            for f in files:
-                if f.startswith("part."):
-                    p = os.path.join(dirpath, f)
-                    raw = bytearray(open(p, "rb").read())
-                    raw[len(raw) // 2] ^= 0xFF
-                    open(p, "wb").write(bytes(raw))
-                    corrupted += 1
-    assert corrupted == 2
+        before = shard_files.read_shard(disk, "bkt", "obj")
+        shard_files.flip_byte(disk, "bkt", "obj", len(before) // 2)
+        assert shard_files.read_shard(disk, "bkt", "obj") != before
     _, got = er.get_object("bkt", "obj")  # bitrot detected -> reconstruct
     assert got == data
 
@@ -242,13 +237,9 @@ def test_heal_corrupt_shard_deep(tmp_path):
     data = _data(BS + 5, seed=4)
     er.put_object("bkt", "obj", data)
     victim = er.disks[2]
-    for dirpath, _, files in os.walk(os.path.join(victim.root, "bkt")):
-        for f in files:
-            if f.startswith("part."):
-                p = os.path.join(dirpath, f)
-                raw = bytearray(open(p, "rb").read())
-                raw[-1] ^= 1
-                open(p, "wb").write(bytes(raw))
+    shard_files.flip_byte(victim, "bkt", "obj", -1, mask=1)
+    with pytest.raises(serrors.FileCorrupt):
+        victim.verify_file("bkt", "obj", victim.read_version("bkt", "obj"))
     res = healing.heal_object(er, "bkt", "obj", deep=True)
     assert res.after_ok == 4
     victim_fi = victim.read_version("bkt", "obj")

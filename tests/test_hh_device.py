@@ -55,13 +55,13 @@ def test_streaming_encode_batch_device_matches_host():
     """The fused stripe-framing path must produce byte-identical shard
     files to the host C path (shard sizes are NOT 32-aligned)."""
     from minio_tpu.hashing import bitrot
+    from minio_tpu.ops import codec
     rng = np.random.default_rng(99)
     shard_size = 1387                  # deliberately ragged
     shards = [rng.integers(0, 256, 4500, dtype=np.uint8).tobytes()
               for _ in range(6)]
     host = [bitrot.streaming_encode(s, shard_size) for s in shards]
-    dev = bitrot.streaming_encode_batch(shards, shard_size,
-                                        use_device=True)
+    dev = codec._streaming_encode_batch_device(shards, shard_size)
     assert dev == host
 
 

@@ -20,6 +20,8 @@ from minio_tpu.objectlayer import erasure_object as eo
 from minio_tpu.objectlayer.erasure_object import ErasureObjects
 from minio_tpu.storage.xl_storage import XLStorage
 
+from . import shard_files
+
 BS = 4096          # tiny block size so a small object spans many blocks
 
 
@@ -78,12 +80,11 @@ def test_streamed_matches_buffered_layout(layer):
     layer.put_object_stream("strbkt", "s", io.BytesIO(body))
     layer._put_object_bytes("strbkt", "b", body,
                             eo.PutObjectOptions())
-    import glob
+    # the streamed object has a part.1 file on each drive; the buffered
+    # one, at this size, is an extent of the drive's segment file
     for d in layer.disks:
-        sfiles = glob.glob(os.path.join(d.root, "strbkt", "s", "*", "part.1"))
-        bfiles = glob.glob(os.path.join(d.root, "strbkt", "b", "*", "part.1"))
-        assert len(sfiles) == 1 and len(bfiles) == 1
-        assert open(sfiles[0], "rb").read() == open(bfiles[0], "rb").read()
+        framed = shard_files.read_shard(d, "strbkt", "s")
+        assert framed and framed == shard_files.read_shard(d, "strbkt", "b")
 
 
 def test_range_get_touches_only_covering_blocks(layer):

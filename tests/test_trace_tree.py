@@ -106,7 +106,8 @@ def test_planted_slowdisk_dominates_write_gating(tmp_path):
         d.mkdir()
         disks.append(XLStorage(str(d)))
     slow_ep = disks[3].endpoint()
-    disks[3] = SlowDisk(disks[3], delay_s=0.03)
+    delay_s = 0.03
+    disks[3] = SlowDisk(disks[3], delay_s=delay_s)
     layer = ErasureObjects(disks, parity=2, block_size=64 * 1024,
                            backend="numpy")
     # on a 1-core CI host the layer serializes fan-outs (the pool buys
@@ -117,8 +118,12 @@ def test_planted_slowdisk_dominates_write_gating(tmp_path):
     # the planted delay (not the shuffle) decides who ends last — the
     # same regime as any real multi-core / remote-drive deployment.
     layer._serial_fanout = False
-    before = _gating_counts("write")
     layer.make_bucket("slowb")
+    # the process's first put pays the one-off warm-up (native
+    # libraries loaded or built, pools started): seconds on a loaded
+    # worker, and nothing to do with the straggler — keep it untimed
+    layer.put_object("slowb", "warmup", b"s" * 64_000)
+    before = _gating_counts("write")
     n = 10
     durs = []
     for i in range(n):
@@ -133,11 +138,13 @@ def test_planted_slowdisk_dominates_write_gating(tmp_path):
     assert delta.get(slow_ep, 0) >= n, delta
     others = [v for d, v in delta.items() if d != slow_ep]
     assert delta[slow_ep] > max(others, default=0), delta
-    # p99 holds: the commit waited for write quorum (4 of 6), not for
-    # the planted straggler's tail — generous CI bound, but an
-    # accidental straggler-serialized path (6 x 30ms+) would blow it
+    # the puts stay fast: each waits out the planted delay about once
+    # (its commit fan-out), never once per drive.  The bound is
+    # relative to that delay and on the MEDIAN put, not an absolute
+    # second on the slowest: puts parked by a loaded ``-n 6`` worker
+    # must not trip it
     durs.sort()
-    assert durs[-1] < 1.0, durs
+    assert durs[n // 2] < 6 * delay_s, durs
 
 
 # -- idle contract ------------------------------------------------------------
