@@ -601,7 +601,7 @@ class ObsDocsDriftRule(Rule):
                    "name (the ``RULE_NAMES`` catalog), and every "
                    "``mt_{s3_stage,forensic,flight,quorum,drive_op,"
                    "trace_tree,alert,history,bucket,tenant,metering,"
-                   "commit_group}"
+                   "commit}"
                    "_*`` metric family "
                    "literal must appear in docs/observability.md — an "
                    "operator reading the stage/rule/family catalog "
@@ -609,7 +609,7 @@ class ObsDocsDriftRule(Rule):
 
     _FAMILY_RE = re.compile(
         r"^mt_(?:s3_stage|forensic|flight|quorum|drive_op|trace_tree"
-        r"|alert|history|bucket|tenant|metering|commit_group)_\w+$")
+        r"|alert|history|bucket|tenant|metering|commit)_\w+$")
 
     def check_tree(self, mods: list[Module], repo: str):
         import os

@@ -777,7 +777,7 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         """One commit-class fan-out (one storage call per drive) with
         its quorum critical-path row.  With the pipeline on, the ops
         ride the per-drive writer plane, where CONCURRENT streams'
-        commit ops coalesce into group commits — one fsync wall settles
+        commit ops coalesce into group commits — one flush settles
         many streams' writes (storage/commit.py) — and the queue bound
         widens to the group batch size so one object's whole fan-out
         enqueues without parking on itself.  The staged framed bytes

@@ -4,19 +4,23 @@ The fourth application of the combining discipline (md5 LaneScheduler →
 CodecBatcher → SingleFlight hot reads → commit plane): concurrent
 streams' create/append/fsync/rename ops queued on the same _DriveWriter
 (storage/writers.py) coalesce into batched group commits — one flush
-round of fsyncs (files + deduplicated parent dirs) settles many streams'
-writes, with durability acknowledged per stream only AFTER its covering
-fsync landed and quorum re-checked per stream as completions drain.
+(files + deduplicated parent dirs) settles many streams' writes, with
+durability acknowledged per stream only AFTER its covering fsync landed
+and quorum re-checked per stream as completions drain.
 
-Two pieces live here:
+Three pieces live here:
 
   * :class:`GroupCollector` — the thread-local deferred-durability
     ledger a drive writer arms around one batch of ops.  Drive op
     bodies (xl_storage.py) register dup'd file descriptors and parent
     dir paths instead of fsyncing eagerly, and defer their
     visibility-flipping os.replace into an ``after_flush``
-    continuation; :meth:`GroupCollector.flush` then runs rounds of
-    fsync → continuations until quiescent.  The crash-atomicity
+    continuation; :meth:`GroupCollector.flush` then runs rounds until
+    quiescent.  A round is two waves and its continuations: the
+    round's file fsyncs, issued together; then its dedup'd directory
+    fsyncs, issued together; then, on the drive's writer thread and in
+    registration order, the continuations, which register the next
+    round.  The crash-atomicity
     contract is preserved exactly: a version's xl.meta replace only
     runs after every fsync registered before it (its part/segment
     bytes and its meta tmp file) has landed — the same
@@ -24,6 +28,15 @@ Two pieces live here:
     batched.  Registering DUP'D fds (not paths) is load-bearing: the
     op body closes its own fd and may rename the file before the
     flush, and an fd fsync is immune to both.
+
+  * the wave helper (``native/syncwave.c``, loaded on first use) — one
+    call that issues a wave's fsyncs together and never holds the
+    interpreter lock.  Under a loaded interpreter every ``os.*`` call
+    of a thread ends with a wait for the GIL, and a batch's ~40 fsyncs
+    (+ closes and opens) issued one ``os.*`` call at a time made the
+    drive's writer thread wait for the interpreter, not for the drive.
+    Without a compiler (or ``MT_NATIVE=0``) the same calls run one by
+    one from Python.
 
   * :class:`SegmentStore` — per-drive journaled append-only segment
     files under ``<root>/.mt.sys/seg/`` that pack many small objects'
@@ -43,6 +56,8 @@ Knobs ride the live-reloadable ``commit`` kvconfig subsystem
 
 from __future__ import annotations
 
+import ctypes
+import functools
 import os
 import threading
 import time
@@ -56,6 +71,24 @@ from . import errors
 # mirrors xl_storage._FSYNC (import would be circular: xl_storage
 # imports this module for the collector hooks)
 _FSYNC = os.environ.get("MT_FSYNC", "1") != "0"
+
+# mt_commit_{queue,body,flush}_seconds: a 9p or spinning drive's op runs
+# tens of ms and a saturated drive queue holds an op for a second
+STAGE_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+                 0.5, 1.0, 2.5)
+STAGE_FAMILIES = {"queue": "mt_commit_queue_seconds",
+                  "body": "mt_commit_body_seconds",
+                  "flush": "mt_commit_flush_seconds"}
+
+
+def observe_stage(stage: str, seconds: float) -> None:
+    """One sample of the three walls a drive op's ``drive_commit`` time
+    is made of on a one-thread-per-drive server: ``queue`` (enqueue to
+    its batch's start), ``body`` (the op body; an overlapped PUT's md5
+    gate park is inside it) and ``flush`` (one batch's flush, all
+    rounds)."""
+    _metrics.observe(STAGE_FAMILIES[stage], {}, seconds,
+                     buckets=STAGE_BUCKETS)
 
 
 class CommitConfig:
@@ -126,10 +159,73 @@ def disarm() -> None:
     _TLS.collector = None
 
 
+_WAVE_SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), "native", "syncwave.c")
+_WAVE_SO = os.path.join(os.path.dirname(_WAVE_SRC), "build",
+                        "libmtsyncwave.so")
+
+
+@functools.cache
+def _wave_lib():
+    """native/syncwave.c, built and loaded on first use; None when it
+    cannot be (utils/nativelib.status() says why)."""
+    from ..utils import nativelib
+    lib = nativelib.load(_WAVE_SRC, _WAVE_SO)
+    if lib is not None:
+        ints = ctypes.POINTER(ctypes.c_int)
+        lib.mt_sync_files.argtypes = [ints, ctypes.c_int, ints]
+        lib.mt_sync_files.restype = None
+        lib.mt_sync_dirs.argtypes = [ctypes.POINTER(ctypes.c_char_p),
+                                     ctypes.c_int]
+        lib.mt_sync_dirs.restype = None
+    return lib
+
+
+def _fsync_close(fd: int) -> int:
+    """fsync + close one fd; 0 or the fsync's errno."""
+    try:
+        os.fsync(fd)
+        return 0
+    except OSError as e:
+        return e.errno or 5
+    finally:
+        os.close(fd)
+
+
+def sync_files(fds: list[int]) -> list[int]:
+    """One wave: fsync + close every fd, all issued together; per fd 0
+    or the errno of its fsync.  Returns when every one has returned."""
+    lib = _wave_lib()
+    if lib is None:
+        return [_fsync_close(fd) for fd in fds]
+    n = len(fds)
+    errs = (ctypes.c_int * n)()
+    lib.mt_sync_files((ctypes.c_int * n)(*fds), n, errs)
+    return list(errs)
+
+
+def sync_dirs(paths: list[str]) -> None:
+    """One wave: open + fsync + close every directory, all issued
+    together; errors tolerated as ``_fsync_dir`` tolerates them."""
+    lib = _wave_lib()
+    if lib is None:
+        for path in paths:
+            try:
+                _fsync_close(os.open(
+                    path, os.O_RDONLY | getattr(os, "O_DIRECTORY", 0)))
+            except OSError:
+                pass
+        return
+    n = len(paths)
+    lib.mt_sync_dirs((ctypes.c_char_p * n)(*map(os.fsencode, paths)), n)
+
+
 class GroupCollector:
     """Deferred-durability ledger for ONE drive-writer batch.
 
-    Runs entirely on the drive's single writer thread — no lock needed.
+    Runs entirely on the drive's single writer thread — no lock needed
+    (a flush wave's fsyncs fan out below Python, inside
+    :func:`sync_files` / :func:`sync_dirs`, and are joined there).
     Every registration is tagged with the op currently executing
     (``current_op``) so a flush-time fsync failure latches onto exactly
     the streams whose writes it covered, and per-stream quorum is
@@ -149,6 +245,7 @@ class GroupCollector:
         self._pending: dict[str, bytes] = {}
         self.deferred = 0           # eager fsyncs this batch replaced
         self.synced = 0             # fsync syscalls actually issued
+        self.waves = 0              # flush waves that issued any
         self.seg_bytes = 0          # bytes packed into segments
         self.streams: set = set()
 
@@ -205,43 +302,37 @@ class GroupCollector:
                     pass
 
     def flush(self) -> None:
-        """Rounds until quiescent: fsync registered fds, fsync dedup'd
-        dirs, then run continuations (which may register more of both —
-        a deferred xl.meta replace re-registers its parent dir)."""
+        """Rounds until quiescent.  A round is two waves and then its
+        continuations: every registered file fsync, issued together;
+        then every dedup'd directory fsync, issued together; then, on
+        this thread and in registration order, the continuations (which
+        may register more of both — a deferred xl.meta replace
+        re-registers its parent dir for the next round).  A
+        continuation therefore runs only after every fsync registered
+        before it has RETURNED, exactly as when they ran one by one;
+        order inside a wave is arbitrary, as it always was (fds by
+        storage, dirs in dict order)."""
         while self._fds or self._dirs or self._after:
             fds, self._fds = self._fds, []
             dirs, self._dirs = self._dirs, {}
-            # group per drive so the flush-time fsync wall is charged
-            # to each drive's commit micro-profiler, not lost
-            fds.sort(key=lambda rec: id(rec[1]))
-            run_storage, run_t0 = None, 0
-            for fd, storage, ops, _key in fds:
-                if storage is not run_storage:
-                    if run_storage is not None:
-                        run_storage._prof("fsync", run_t0)
-                    run_storage, run_t0 = storage, time.monotonic_ns()
-                try:
-                    os.fsync(fd)
-                except OSError as e:
-                    self._latch(ops, errors.FaultyDisk(str(e)))
-                finally:
-                    os.close(fd)
-                self.synced += 1
-            if run_storage is not None:
-                run_storage._prof("fsync", run_t0)
-            for path, ops in dirs.items():
-                self.synced += 1
-                try:
-                    dfd = os.open(path, os.O_RDONLY
-                                  | getattr(os, "O_DIRECTORY", 0))
-                except OSError:
-                    continue        # same tolerance as _fsync_dir
-                try:
-                    os.fsync(dfd)
-                except OSError:
-                    pass
-                finally:
-                    os.close(dfd)
+            if fds:
+                t0 = time.monotonic_ns()
+                self.synced += len(fds)
+                self.waves += 1
+                for rec, err in zip(fds, sync_files(
+                        [rec[0] for rec in fds])):
+                    if err:
+                        self._latch(rec[2], errors.FaultyDisk(
+                            str(OSError(err, os.strerror(err)))))
+                # the wave's wall is charged once to each drive's
+                # commit micro-profiler, not lost
+                for storage in {id(rec[1]): rec[1] for rec in fds
+                                if rec[1] is not None}.values():
+                    storage._prof("fsync", t0)
+            if dirs:
+                self.synced += len(dirs)
+                self.waves += 1
+                sync_dirs(list(dirs))
             after, self._after = self._after, []
             for fn, op in after:
                 self.current_op = op
@@ -264,6 +355,9 @@ class GroupCollector:
         saved = self.deferred - self.synced
         if saved > 0:
             _metrics.inc("mt_commit_group_fsyncs_saved_total", {}, saved)
+        if self.synced:
+            _metrics.inc("mt_commit_fsyncs_total", {}, self.synced)
+            _metrics.inc("mt_commit_flush_waves_total", {}, self.waves)
         if self.seg_bytes:
             _metrics.inc("mt_commit_group_segment_bytes_total", {},
                          self.seg_bytes)

@@ -23,10 +23,18 @@ writer thread per drive with a bounded in-order queue:
     hazards because each stream only ever appends to its own files;
   * with the ``commit`` kvconfig subsystem on, each drive's drain is
     GROUPED (storage/commit.py): up to commit.max_batch queued ops run
-    their bodies with a GroupCollector armed, ONE flush of deduplicated
-    file + parent-dir fsyncs settles the whole batch, and every
-    stream's durability is acknowledged (quorum re-checked) only after
-    its covering fsync landed.
+    their bodies, one after another, with a GroupCollector armed; ONE
+    flush settles the whole batch, and every stream's durability is
+    acknowledged (quorum re-checked) only after its covering fsync
+    landed.  The flush runs in rounds of two waves — the round's file
+    fsyncs issued together, then its deduplicated parent-dir fsyncs
+    issued together, each wave ONE call that does not hold the
+    interpreter lock (commit.sync_files / sync_dirs over
+    native/syncwave.c) — followed by the round's continuations (the
+    xl.meta replaces) on the drive's writer thread.  Op bodies,
+    continuations and settlement all stay on the one thread per drive,
+    so the FIFO contract above is untouched.  A drive op's time is
+    queue + body + flush (``mt_commit_{queue,body,flush}_seconds``).
 
 Shutdown: ``close()`` wakes blocked enqueuers (they see PlaneClosed and
 abort their PUT, which cleans its tmp files), fails every queued op so
@@ -87,7 +95,7 @@ class _Batch:
 
 class _Op:
     __slots__ = ("stream", "idx", "fn", "batch", "rid", "clock",
-                 "parent")
+                 "parent", "t_enq")
 
     def __init__(self, stream, idx, fn, batch, rid, clock=None,
                  parent=""):
@@ -98,6 +106,7 @@ class _Op:
         self.rid = rid
         self.clock = clock
         self.parent = parent
+        self.t_enq = 0.0         # perf_counter when it joined its queue
 
     def run_body(self, disk) -> tuple:
         """Execute the op body WITHOUT settling; returns ``(err, dt)``.
@@ -163,6 +172,7 @@ class _DriveWriter:
                     self._cv.wait()
             if self._closed:
                 raise PlaneClosed("writer plane closed")
+            op.t_enq = time.perf_counter()
             self._q.append(op)
             self._cv.notify_all()
 
@@ -199,18 +209,25 @@ class _DriveWriter:
     def _group_commit(self, ops: list[_Op]) -> None:
         """One group commit: run every op body with the collector armed
         (bodies defer their fsyncs / visibility flips into it), flush
-        once — one fsync wall settles the whole batch — THEN settle
-        each op so per-stream quorum is re-checked only after its
-        covering fsync landed."""
+        once — rounds of a file wave, a directory wave and the round's
+        continuations settle the whole batch — THEN settle each op so
+        per-stream quorum is re-checked only after its covering fsync
+        landed."""
         col = _commit.GroupCollector()
         _commit.arm(col)
         settles: list[tuple] = []
+        t_batch = time.perf_counter()
         try:
             for op in ops:
+                _commit.observe_stage("queue", t_batch - op.t_enq)
                 col.current_op = op
-                settles.append(op.run_body(self.disk))
+                settle = op.run_body(self.disk)
+                settles.append(settle)
+                _commit.observe_stage("body", settle[1])
             col.current_op = None
+            t_flush = time.perf_counter()
             col.flush()
+            _commit.observe_stage("flush", time.perf_counter() - t_flush)
         except Exception as e:  # noqa: BLE001 — flush must not kill us
             for op in ops:
                 try:

@@ -1,9 +1,10 @@
 """Shared build-on-demand loader for the native (C/C++) helper libraries.
 
-Five subsystems carry native kernels — snappy compression
+Six subsystems carry native kernels — snappy compression
 (native/snappy.cc), HighwayHash (hashing/native/highwayhash.c), the
 GF(2^8) erasure matmul (native/gf8.cc), multi-buffer md5
-(native/md5mb.cc) and the NDJSON scanner (native/jsonscan.cc) — the
+(native/md5mb.cc), the NDJSON scanner (native/jsonscan.cc) and the
+group commit's fsync waves (native/syncwave.c) — the
 roles the reference fills with assembly-accelerated Go modules
 (SURVEY.md §2.4).  They all share one loading discipline, implemented
 once here:

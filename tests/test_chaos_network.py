@@ -1001,7 +1001,9 @@ def test_aborted_request_keeps_stage_vector(s3_server):
     # buffers: the proxy stops reading after the reset budget, so the
     # server's body_write must block and then fail on the RST
     data = os.urandom(32 << 20)
-    c.put_object("chab", "big", data)
+    # staged past the front: the fixture's 1 s body deadline is the
+    # subject of leg 2, and cuts a 32 MiB upload on a loaded host
+    srv.layer.put_object("chab", "big", data)
 
     def newest_abort(api):
         for r in srv.flightrec.query(errors_only=True, limit=50):

@@ -17,7 +17,14 @@ Contracts pinned here:
   * isolation — BadDigest aborts ONE stream of a group without
     poisoning batch-mates; a dead drive mid-group still commits at
     quorum;
-  * observability — mt_commit_group_* families tick when groups form.
+  * observability — mt_commit_group_* families tick when groups form;
+  * flush waves — a round's fsyncs are issued together (file wave, then
+    directory wave; one native call each, or the os.* loop that stands
+    in for it) and nothing the protocol orders is relaxed: every fsync
+    registered before a version's os.replace has RETURNED before it,
+    the post-rename directory fsync comes after it, a wave's fsync
+    error latches onto its own stream's drive only, and the same fsyncs
+    land the same bytes as with grouping off.
 """
 
 import glob
@@ -25,6 +32,7 @@ import hashlib
 import os
 import shutil
 import threading
+import time
 
 import pytest
 
@@ -346,6 +354,373 @@ def test_group_metrics_tick_when_groups_form(tmp_path):
         delta("mt_commit_group_batches_total")
     assert delta("mt_commit_group_segment_bytes_total") > 0
     assert delta("mt_commit_group_fsyncs_saved_total") > 0
+
+
+# -- flush waves -------------------------------------------------------------
+
+@pytest.fixture(params=["native", "python"])
+def wave_impl(request, monkeypatch):
+    """Both forms of a flush wave: the one call into native/syncwave.c,
+    and the os.* loop that stands in for it without a compiler (the
+    only form whose fsyncs a wrapped ``os.fsync`` can see)."""
+    if request.param == "python":
+        monkeypatch.setattr(commit, "_wave_lib", lambda: None)
+    elif commit._wave_lib() is None:
+        pytest.skip("native/syncwave.c did not build here")
+    return request.param
+
+
+class SyncLog:
+    """``os.fsync`` and ``os.replace`` wrapped to log (and, on demand,
+    to fail an fsync): fsync rows are (path, start, end), replace rows
+    (src, dst, time); ``on_replace(src, dst)`` runs just before a
+    replace."""
+
+    def __init__(self, monkeypatch, fail=None, on_replace=None):
+        self.fsyncs: list = []
+        self.replaces: list = []
+        self._mu = threading.Lock()
+        real_fsync, real_replace = os.fsync, os.replace
+
+        def fsync(fd):
+            path = os.readlink(f"/proc/self/fd/{fd}")
+            t0 = time.monotonic()
+            try:
+                if fail is not None and fail(path):
+                    raise OSError(5, "injected fsync failure", path)
+                real_fsync(fd)
+            finally:
+                with self._mu:
+                    self.fsyncs.append((path, t0, time.monotonic()))
+
+        def replace(src, dst, **kw):
+            if on_replace is not None:
+                on_replace(str(src), str(dst))
+            real_replace(src, dst, **kw)
+            with self._mu:
+                self.replaces.append((str(src), str(dst),
+                                      time.monotonic()))
+        monkeypatch.setattr(os, "fsync", fsync)
+        monkeypatch.setattr(os, "replace", replace)
+
+
+def gated_puts(lay, puts, timeout=20.0):
+    """Run ``puts`` [(name, body, opts)] so that every drive's writer
+    thread takes all of them as ONE group-commit batch, in this order:
+    a gate op parks each drive's writer, the PUTs start one at a time
+    (each once its predecessor sits on every drive queue, so uuid
+    minting and queue order are deterministic), then the gate opens.
+    Returns {name: exception} for the PUTs that failed."""
+    plane = lay._write_plane
+    gate = threading.Event()
+    parked = [threading.Event() for _ in lay.disks]
+
+    def hold(idx, disk):
+        parked[idx].set()
+        gate.wait(timeout)
+    sw = plane.stream(lay.disks)
+    for i in range(len(lay.disks)):
+        sw.submit(i, hold)
+    assert all(ev.wait(timeout) for ev in parked)
+    errs: dict = {}
+
+    def put(name, body, opts):
+        try:
+            lay.put_object("pbkt", name, body, opts)
+        except Exception as e:        # noqa: BLE001 — asserted by caller
+            errs[name] = e
+    threads = []
+    try:
+        for j, (name, body, opts) in enumerate(puts):
+            t = threading.Thread(target=put, args=(name, body, opts))
+            t.start()
+            threads.append(t)
+            end = time.monotonic() + timeout
+            while any(st["queue_depth"] < j + 1
+                      for st in plane.stats().values()):
+                assert time.monotonic() < end and t.is_alive(), \
+                    (name, errs, plane.stats())
+                time.sleep(0.002)
+    finally:
+        gate.set()
+    for t in threads:
+        t.join(timeout)
+        assert not t.is_alive()
+    assert sw.drain(timeout)
+    return errs
+
+
+def hist(name):
+    """(sum, count) of an unlabelled mt_commit_*_seconds histogram."""
+    for (fam, _labels, _b), h in metrics.hist_snapshot().items():
+        if fam == name:
+            return h[-1], h[-2]
+    return 0.0, 0
+
+
+def counter(name):
+    return metrics.snapshot().get((name, ()), 0)
+
+
+def test_sync_waves_fsync_and_close_every_item(tmp_path, wave_impl):
+    """The wave helper itself: every fd is fsynced and CLOSED when the
+    call returns, an fd that cannot be fsynced reports its errno at its
+    own index, a directory that is not there is tolerated."""
+    fds = [os.open(tmp_path / f"f{i}", os.O_CREAT | os.O_WRONLY)
+           for i in range(20)]
+    r, w = os.pipe()                  # fsync(pipe) -> EINVAL
+    try:
+        inodes = [os.fstat(fd).st_ino for fd in fds]
+        errs = commit.sync_files(fds[:7] + [os.dup(w)] + fds[7:])
+        assert errs[7] != 0 and not any(errs[:7] + errs[8:]), errs
+        for fd, ino in zip(fds, inodes):
+            try:
+                assert os.fstat(fd).st_ino != ino     # number reused
+            except OSError:
+                pass                                  # closed
+        commit.sync_dirs([str(tmp_path), str(tmp_path / "gone")])
+        assert commit.sync_files([]) == []
+    finally:
+        os.close(r)
+        os.close(w)
+
+
+def test_flush_issues_a_rounds_fsyncs_in_waves(tmp_path, monkeypatch,
+                                               wave_impl):
+    """A batch of N regular PUTs flushes in three waves per drive — 2N
+    file fds; 2N + 1 directories (the bucket dir once); the N object
+    dirs again behind the renames — and the counters behind
+    commit_flush_width / commit_fsyncs_per_put / commit_*_ms tick."""
+    monkeypatch.setattr(eo, "_SINGLE_CORE", False)
+    commit.CONFIG.pack_threshold = 0          # regular objects only
+    lay = mk_layer(tmp_path)
+    n = 6
+    log = SyncLog(monkeypatch)
+    f0, w0 = counter("mt_commit_fsyncs_total"), \
+        counter("mt_commit_flush_waves_total")
+    flush0, body0, queue0 = (hist(f"mt_commit_{s}_seconds")
+                             for s in ("flush", "body", "queue"))
+    assert not gated_puts(lay, [(f"r{i}", pattern(20_000 + i), None)
+                                for i in range(n)])
+    flush1, body1, queue1 = (hist(f"mt_commit_{s}_seconds")
+                             for s in ("flush", "body", "queue"))
+    issued = counter("mt_commit_fsyncs_total") - f0
+    waves = counter("mt_commit_flush_waves_total") - w0
+    assert issued == len(lay.disks) * (5 * n + 1)
+    assert waves == len(lay.disks) * 3
+    assert issued / waves > 2                 # commit_flush_width
+    # the os.* loop issues them where the wrapper sees them; the native
+    # wave issues none through os.fsync
+    assert len(log.fsyncs) == (issued if wave_impl == "python" else 0)
+    # the gate op is a batch and a drive op too: 2 flushes, n + 1 ops
+    assert flush1[1] - flush0[1] == 2 * len(lay.disks)
+    assert body1[1] - body0[1] == queue1[1] - queue0[1] \
+        == len(lay.disks) * (n + 1)
+    assert queue1[0] > queue0[0] and body1[0] > body0[0] \
+        and flush1[0] > flush0[0]
+    for i in range(n):
+        assert lay.get_object("pbkt", f"r{i}")[1] == pattern(20_000 + i)
+    close_write_planes(lay)
+
+
+MIXED_PUTS = [("reg0", (1 << 20) + 4097), ("pack0", 50_000), ("inl0", 300),
+              ("reg1", (1 << 20) + 5), ("pack1", 9_000), ("inl1", 17)]
+
+
+def test_every_fsync_returns_before_its_replace(tmp_path, monkeypatch):
+    """Regular, packed and inline ops in ONE batch, the os.* form of
+    the waves (the one a wrapped os.fsync sees): for every version, on
+    every drive, the fsyncs of its part file (or the segment and its
+    journal), of its xl.meta tmp file, of its data dir and of its
+    object dir have all RETURNED before its os.replace runs, and the
+    object dir is fsynced again after it (the next round)."""
+    monkeypatch.setattr(eo, "_SINGLE_CORE", False)
+    monkeypatch.setattr(commit, "_wave_lib", lambda: None)
+    lay = mk_layer(tmp_path)
+    log = SyncLog(monkeypatch)
+    puts = [(name, pattern(size), None) for name, size in MIXED_PUTS]
+    assert not gated_puts(lay, puts)
+    flips = [(src, dst, t) for src, dst, t in log.replaces
+             if dst.endswith("/xl.meta") and "/pbkt/" in dst]
+    assert len(flips) == len(puts) * len(lay.disks)
+    for src, dst, t in flips:
+        obj_dir = os.path.dirname(dst)
+        root = obj_dir[:obj_dir.index("/pbkt/")]
+        name = os.path.basename(obj_dir)
+
+        def landed(path):
+            return [e for p, _s, e in log.fsyncs if p == path and e <= t]
+        assert landed(src), (name, "xl.meta tmp file")
+        assert landed(obj_dir), (name, "object dir before the flip")
+        if name.startswith("reg"):
+            (ddir,) = [d for d in glob.glob(obj_dir + "/*")
+                       if os.path.isdir(d)]
+            assert landed(ddir + "/part.1"), (name, "part file")
+            assert landed(ddir), (name, "data dir")
+        if name.startswith("pack"):
+            seg = os.path.join(root, ".mt.sys", "seg")
+            assert landed(seg + "/journal"), (name, "segment journal")
+            assert any(landed(f) for f in glob.glob(seg + "/seg.*.dat")), \
+                (name, "segment file")
+        if not name.startswith("inl"):       # fresh object: bucket dir
+            assert landed(os.path.dirname(obj_dir)), (name, "bucket dir")
+        # ... and the rename's own directory entry persisted after it
+        assert [s for p, s, _e in log.fsyncs
+                if p == obj_dir and s >= t], (name, "round-2 dir fsync")
+    for name, body, _ in puts:
+        assert lay.get_object("pbkt", name)[1] == body
+    close_write_planes(lay)
+
+
+def test_every_fd_is_synced_and_closed_before_its_replace(tmp_path,
+                                                          monkeypatch):
+    """The same batch through the native waves, whose fsyncs no wrapper
+    sees: a wave closes an fd only after its fsync returned, so at every
+    version's os.replace each fd the drive's batch registered before it
+    — part files, segment, journal, xl.meta tmp files — must already be
+    closed; the replace's own tmp file among them."""
+    if commit._wave_lib() is None:
+        pytest.skip("native/syncwave.c did not build here")
+    monkeypatch.setattr(eo, "_SINGLE_CORE", False)
+    lay = mk_layer(tmp_path)
+    registered: dict = {}           # drive root -> [(fd, ino, path)]
+    mu = threading.Lock()
+    real_defer = commit.GroupCollector.defer_fd
+
+    def defer_fd(self, fd, storage=None, key=None):
+        path = os.readlink(f"/proc/self/fd/{fd}")
+        with mu:
+            registered.setdefault(storage.root, []).append(
+                (fd, os.fstat(fd).st_ino, path))
+        real_defer(self, fd, storage=storage, key=key)
+    monkeypatch.setattr(commit.GroupCollector, "defer_fd", defer_fd)
+    checked = []
+
+    def on_replace(src, dst):
+        if not dst.endswith("/xl.meta") or "/pbkt/" not in dst:
+            return
+        root = dst[:dst.index("/pbkt/")]
+        with mu:
+            mine = list(registered.get(root, []))
+        assert any(path == src for _fd, _ino, path in mine), src
+        for fd, ino, path in mine:
+            try:
+                still_open = os.fstat(fd).st_ino == ino
+            except OSError:
+                still_open = False
+            assert not still_open, (path, "open across", dst)
+        checked.append(dst)
+    log = SyncLog(monkeypatch, on_replace=on_replace)
+    puts = [(name, pattern(size), None) for name, size in MIXED_PUTS]
+    assert not gated_puts(lay, puts)
+    assert len(checked) == len(puts) * len(lay.disks)
+    assert not log.fsyncs
+    for name, body, _ in puts:
+        assert lay.get_object("pbkt", name)[1] == body
+    close_write_planes(lay)
+
+
+def test_wave_fsync_error_latches_its_own_stream_only(tmp_path,
+                                                      monkeypatch,
+                                                      wave_impl):
+    """A failing fsync of one fd inside a wave latches FaultyDisk onto
+    the stream that registered the fd, on that drive only: the victim
+    commits at quorum, batch-mates see no error."""
+    from minio_tpu.storage.writers import StreamWriter
+    monkeypatch.setattr(eo, "_SINGLE_CORE", False)
+    commit.CONFIG.pack_threshold = 0
+    lay = mk_layer(tmp_path)
+    bad_root = lay.disks[1].root
+
+    def is_victim(path):
+        return path.startswith(bad_root + "/pbkt/victim/") \
+            and path.endswith("/part.1")
+    SyncLog(monkeypatch, fail=is_victim)      # the os.* form fails here
+    r, w = os.pipe()
+    real_defer = commit.GroupCollector.defer_fd
+
+    def defer_fd(self, fd, storage=None, key=None):
+        # the native form: hand it an fd whose fsync fails (EINVAL)
+        if wave_impl == "native" and \
+                is_victim(os.readlink(f"/proc/self/fd/{fd}")):
+            os.close(fd)
+            fd = os.dup(w)
+        real_defer(self, fd, storage=storage, key=key)
+    monkeypatch.setattr(commit.GroupCollector, "defer_fd", defer_fd)
+    latched = []
+    real_latch = StreamWriter._latch_err
+
+    def spy(self, idx, err):
+        latched.append((self.disks[idx].root, type(err).__name__))
+        real_latch(self, idx, err)
+    monkeypatch.setattr(StreamWriter, "_latch_err", spy)
+    puts = [("mate0", pattern(20_000), None),
+            ("victim", pattern(21_000), None),
+            ("mate1", pattern(22_000), None),
+            ("mate2", pattern(23_000), None)]
+    try:
+        assert not gated_puts(lay, puts)
+    finally:
+        os.close(r)
+        os.close(w)
+    assert latched == [(bad_root, "FaultyDisk")]
+    for name, body, _ in puts:
+        assert lay.get_object("pbkt", name)[1] == body
+    close_write_planes(lay)
+
+
+def test_batched_flush_same_bytes_and_same_fsyncs_as_eager(tmp_path,
+                                                           monkeypatch,
+                                                           wave_impl):
+    """One batch of regular PUTs leaves xl.meta and shard files
+    bit-identical to ``commit.enable=off`` (packing, which only exists
+    with grouping on, out of reach), and the fsync syscalls it issued
+    are exactly those its op bodies deferred minus the ones
+    deduplication saved (the bucket dir the batch-mates share).  What
+    the bodies defer is the eager path's five fsyncs per drive op plus
+    one: the object dir is registered before the flip (the data dir's
+    entry) and again behind it (the rename's)."""
+    monkeypatch.setattr(eo, "_SINGLE_CORE", False)
+    commit.CONFIG.pack_threshold = 0
+    deferred = []
+    for meth in ("defer_fd", "defer_dir"):
+        real = getattr(commit.GroupCollector, meth)
+
+        def spy(self, *a, _real=real, **kw):
+            deferred.append(1)
+            return _real(self, *a, **kw)
+        monkeypatch.setattr(commit.GroupCollector, meth, spy)
+    puts = [(f"o{i}", pattern(size), PutObjectOptions(
+        mod_time=1_234_567_890 + i))
+        for i, size in enumerate([(1 << 20) + 3, 70_000,
+                                  (1 << 20) + 4096, 30_000])]
+    states, calls, grouped = {}, {}, {}
+    for mode, enable in (("eager", False), ("grouped", True)):
+        det_uuids(monkeypatch)
+        commit.CONFIG.enable = enable
+        lay = mk_layer(tmp_path / mode)
+        log = SyncLog(monkeypatch)
+        before = {k: counter(k) for k in (
+            "mt_commit_fsyncs_total",
+            "mt_commit_group_fsyncs_saved_total")}
+        assert not gated_puts(lay, puts)
+        grouped[mode] = {k: counter(k) - v for k, v in before.items()}
+        calls[mode] = len(log.fsyncs)
+        states[mode] = {name: disk_state(lay, name)
+                        for name, _, _ in puts}
+        for name, body, _ in puts:
+            assert lay.get_object("pbkt", name)[1] == body
+        close_write_planes(lay)
+    assert states["eager"] == states["grouped"]
+    assert all(meta and parts for st in states["grouped"].values()
+               for meta, parts in st.values())
+    assert grouped["eager"]["mt_commit_fsyncs_total"] == 0
+    issued = grouped["grouped"]["mt_commit_fsyncs_total"]
+    saved = grouped["grouped"]["mt_commit_group_fsyncs_saved_total"]
+    assert saved > 0
+    assert issued == len(deferred) - saved
+    assert len(deferred) == calls["eager"] + len(puts) * len(lay.disks)
+    assert calls["grouped"] == (issued if wave_impl == "python" else 0)
 
 
 # -- compaction --------------------------------------------------------------

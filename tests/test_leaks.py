@@ -3,9 +3,12 @@ server/cluster start-stop cycles must not accumulate threads or leave
 sockets listening.
 """
 
+import os
 import socket
 import threading
 import time
+
+import pytest
 
 from minio_tpu.objectlayer.erasure_object import ErasureObjects
 from minio_tpu.s3.client import S3Client
@@ -102,6 +105,11 @@ def test_select_disconnect_releases_governor_and_threads(tmp_path):
             b"<InputSerialization><CSV/></InputSerialization>"
             b"<OutputSerialization><CSV/></OutputSerialization>"
             b"</SelectObjectContentRequest>")
+        # the layer's pool spawns workers on demand and keeps them: warm
+        # it to full size, or its ramp-up under the Select's read
+        # fan-out reads as a leak (as in the start/stop test above)
+        list(layer._pool.map(time.sleep,
+                             [0.05] * layer._pool._max_workers))
         baseline = _settled_thread_count()
         assert GOVERNOR.inuse_bytes("select") == 0
         path = "/selleak/big.csv?select&select-type=2"
@@ -521,3 +529,95 @@ def test_rpc_server_stop_closes_listener(tmp_path):
         assert s.connect_ex(("127.0.0.1", port)) != 0
     finally:
         s.close()
+
+
+@pytest.mark.parametrize("hung", [False, True], ids=["idle", "hung-wave"])
+def test_flush_waves_leave_no_thread_behind(tmp_path, monkeypatch, hung):
+    """A group-commit flush issues its fsyncs in waves (storage/commit.py
+    sync_files / sync_dirs); whatever threads a wave uses are joined
+    inside the call, and the drives' writers are the plane's only
+    threads: after close_write_planes no mt-putw* thread is alive and
+    the process holds no more OS threads than before the plane started
+    — also when the close finds a writer parked inside a hung wave."""
+    from minio_tpu.storage import commit
+    from minio_tpu.storage.writers import close_write_planes
+
+    def os_threads():
+        return len(os.listdir("/proc/self/task"))
+
+    def plane_threads():
+        return [th for th in threading.enumerate()
+                if th.name.startswith("mt-putw") and th.is_alive()
+                and th.name not in preexisting]
+    monkeypatch.setattr(commit.CONFIG, "_loaded", True)
+    monkeypatch.setattr(commit.CONFIG, "enable", True)
+    release, parked = threading.Event(), threading.Event()
+    armed = threading.Event()
+    real_sync_files = commit.sync_files
+
+    def sync_files(fds):
+        if hung and armed.is_set() and not parked.is_set():
+            parked.set()
+            release.wait(20)
+        return real_sync_files(fds)
+    monkeypatch.setattr(commit, "sync_files", sync_files)
+    disks = []
+    for i in range(4):
+        d = tmp_path / f"sp{i}"
+        d.mkdir()
+        disks.append(XLStorage(str(d)))
+    layer = ErasureObjects(disks, parity=2, block_size=4096,
+                           backend="numpy")
+    layer._pipe_depth = 2            # force regardless of core count
+    layer.make_bucket("spbkt")
+    body = b"s" * (2 << 20)          # a part file: waves of several fds
+    # ramp the layer's lazy pool and the wave helper up before counting
+    layer.put_object("spbkt", "warm", body)
+    list(layer._pool.map(time.sleep, [0.05] * layer._pool._max_workers))
+    close_write_planes(layer)
+    preexisting = {th.name for th in threading.enumerate()
+                   if th.name.startswith("mt-putw")}
+    base = os_threads()
+    plane = layer._write_plane
+    done: list = []
+    armed.set()
+
+    def put():
+        try:
+            layer.put_object("spbkt", "obj", body)
+            done.append(None)
+        except Exception as e:  # noqa: BLE001 — close may abort it
+            done.append(e)
+    t = threading.Thread(target=put, daemon=True)
+    t.start()
+    try:
+        if hung:
+            assert parked.wait(10)
+            gen0 = plane._gen
+
+            def release_when_closing():
+                end = time.monotonic() + 15.0
+                while time.monotonic() < end and plane._gen == gen0:
+                    time.sleep(0.02)
+                release.set()
+            threading.Thread(target=release_when_closing,
+                             daemon=True).start()
+        else:
+            t.join(15)
+            assert done == [None]
+        assert plane_threads()
+        close_write_planes(layer, timeout=10.0)
+        t.join(15)
+        assert not t.is_alive() and done
+        deadline = time.monotonic() + 10.0
+        while time.monotonic() < deadline and (
+                plane_threads() or os_threads() > base):
+            time.sleep(0.05)
+        assert not plane_threads(), [th.name for th in plane_threads()]
+        assert os_threads() <= base, (os_threads(), base)
+        # the plane reopens lazily
+        layer.put_object("spbkt", "after", body)
+        assert layer.get_object("spbkt", "after")[1] == body
+    finally:
+        release.set()
+        close_write_planes(layer)

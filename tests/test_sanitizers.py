@@ -1,7 +1,8 @@
 """Sanitizer tier for the native libraries (buildscripts/race.sh role).
 
-All four C/C++ libraries (native/gf8.cc, native/snappy.cc,
-native/jsonscan.cc, hashing/native/highwayhash.c) are rebuilt with
+The C/C++ libraries (native/gf8.cc, native/snappy.cc,
+native/jsonscan.cc, native/syncwave.c, hashing/native/highwayhash.c)
+are rebuilt with
 ``-fsanitize=address,undefined`` into a scratch build dir
 (MT_NATIVE_BUILD_DIR) and exercised — through their normal Python
 bindings, under concurrent load — in a subprocess running with libasan
@@ -98,6 +99,17 @@ WORKLOAD = textwrap.dedent("""
         records.ndjson_prefilter(data, "k", "=", "v7")
         records.ndjson_prefilter(data, "n", ">", 100)
 
+    def syncwave_work():
+        import tempfile
+        from minio_tpu.storage import commit
+        assert commit._wave_lib() is not None, "syncwave build failed"
+        with tempfile.TemporaryDirectory() as d:
+            for n in (1, 7, 8, 9, 40):          # around the slice count
+                fds = [os.open(os.path.join(d, f"f{i}"),
+                               os.O_CREAT | os.O_WRONLY) for i in range(n)]
+                assert commit.sync_files(fds) == [0] * n
+                commit.sync_dirs([d] * n + [os.path.join(d, "gone")])
+
     def run(fn):
         try:
             for _ in range(5):
@@ -106,7 +118,8 @@ WORKLOAD = textwrap.dedent("""
             errors.append(f"{fn.__name__}: {e!r}")
 
     threads = [threading.Thread(target=run, args=(f,))
-               for f in (gf8_work, snappy_work, hh_work, jsonscan_work)
+               for f in (gf8_work, snappy_work, hh_work, jsonscan_work,
+                         syncwave_work)
                for _ in range(3)]
     for t in threads: t.start()
     for t in threads: t.join()

@@ -105,6 +105,18 @@ WORKLOAD = textwrap.dedent("""
         for _ in range(10):
             records.ndjson_prefilter(data, "k", "=", "v7")
 
+    def syncwave_work():
+        # a flush wave's slices share the fd / path / errno arrays
+        import tempfile
+        from minio_tpu.storage import commit
+        assert commit._wave_lib() is not None, "syncwave tsan build failed"
+        with tempfile.TemporaryDirectory() as d:
+            for _ in range(10):
+                fds = [os.open(os.path.join(d, f"f{i}"),
+                               os.O_CREAT | os.O_WRONLY) for i in range(40)]
+                assert commit.sync_files(fds) == [0] * 40
+                commit.sync_dirs([d] * 9)
+
     def run(fn):
         try:
             fn()
@@ -112,7 +124,8 @@ WORKLOAD = textwrap.dedent("""
             errors.append(f"{fn.__name__}: {e!r}")
 
     threads = [threading.Thread(target=run, args=(f,))
-               for f in (gf8_work, hh_work, snappy_work, jsonscan_work)
+               for f in (gf8_work, hh_work, snappy_work, jsonscan_work,
+                         syncwave_work)
                for _ in range(3)]
     for t in threads: t.start()
     for t in threads: t.join()
