@@ -128,15 +128,24 @@ def _device_hh256_batch(blocks):
     of ``hh256_batch`` to its return — one dispatch of one compiled
     program (slice, pad, kernel, limb reassembly, remainder, finalize)
     — until the digests' handle is held; ``hash.fetch`` waits for them
-    and copies them down."""
+    and copies them down.  Each dispatch counts its rows into
+    ``mt_tpu_hash_rows_total{kind="real"|"hashed"}``."""
     from . import device
     if device.use_pallas():
         from . import hh_pallas as hh
     else:
         from . import hh_kernels as hh
     blocks = device.upload("hash", blocks)
-    with _obstrace.span("tpu", "hash.launch", nbytes=blocks.nbytes):
+    # rows asked for against rows the program hashes (the Pallas form
+    # pads B to whole 128-row tiles), from the function it pads by
+    real, hashed = blocks.shape[0], hh.hashed_rows(*blocks.shape)
+    with _obstrace.span("tpu", "hash.launch", nbytes=blocks.nbytes,
+                        detail=lambda: {"op": "hash", "rows": real,
+                                        "rowsHashed": hashed}):
         digests = hh.hh256_batch(blocks)
+    m = _metrics.GLOBAL
+    m.inc("mt_tpu_hash_rows_total", {"kind": "real"}, float(real))
+    m.inc("mt_tpu_hash_rows_total", {"kind": "hashed"}, float(hashed))
     return device.fetch("hash", digests)
 
 

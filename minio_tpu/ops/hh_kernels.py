@@ -261,6 +261,12 @@ def _hh256_scan(packets_h, packets_l, init, tail=None, rem=0):
     return jnp.stack([h0l, h0h, h1l, h1h, h2l, h2h, h3l, h3h], axis=-1)
 
 
+def hashed_rows(B: int, n: int) -> int:
+    """Rows the program hashes for (B, n) blocks: the scan carries B
+    rows and pads none (hh_pallas.hashed_rows is the kernel's)."""
+    return B
+
+
 def hh256_batch(blocks, key: bytes = MAGIC_KEY):
     """HighwayHash-256 of B equal-sized blocks on device.
 

@@ -205,6 +205,26 @@ def _init_consts() -> tuple[int, ...]:
     return tuple(out)
 
 
+def _row_tile(B: int) -> tuple[int, int]:
+    """(S, rows) for a batch of B shard rows: the kernel's sublane tile
+    and the rows the padded operand holds.  The shard tile adapts to the
+    batch: a 16-shard tail call must not pad (and hash) 1008 garbage
+    rows.  Mosaic requires the 2nd-minor block dim to be 8-divisible or
+    equal to the whole array dim, so small batches use S=G (one tile
+    block), larger ones S=8 + padding."""
+    G = -(-B // 128)
+    S = G if G < 8 else 8
+    return S, -(-B // (S * 128)) * S * 128
+
+
+def hashed_rows(B: int, n: int) -> int:
+    """Rows the compiled program hashes for (B, n) blocks: B padded to
+    whole 128-lane tiles (``_row_tile``, the pad ``_hh256_batch``
+    applies); B itself where ``hh256_batch`` hands the call to the XLA
+    form."""
+    return B if n < 32 or B == 0 else _row_tile(B)[1]
+
+
 def hh256_batch(blocks, key: bytes = MAGIC_KEY):
     """Drop-in for hh_kernels.hh256_batch, pallas packet loop.
 
@@ -226,14 +246,8 @@ def _hh256_batch(blocks):
     Everything below is a function of the static shape."""
     B, n = blocks.shape
     P, rem = n // 32, n % 32
-    # adapt the shard tile to the batch: a 16-shard tail call must not
-    # pad (and hash) 1008 garbage rows.  Mosaic requires the 2nd-minor
-    # block dim to be 8-divisible or equal to the whole array dim, so:
-    # small batches use S=G (one tile block), larger ones S=8 + padding
-    G = -(-B // 128)
-    S = G if G < 8 else 8
-    tb = S * 128
-    b_pad = -B % tb
+    S, bt = _row_tile(B)
+    b_pad = bt - B
     p_pad = -P % _PC_NAT
     # pad in 2-D BYTE layout (safe: 2-D u8 operands reach pallas in
     # canonical layout), then ONE kernel: the byte-plane transpose is
@@ -246,7 +260,6 @@ def _hh256_batch(blocks):
     x = blocks[:, :P * 32]
     if b_pad or p_pad:
         x = jnp.pad(x, ((0, b_pad), (0, p_pad * 32)))
-    bt = B + b_pad
 
     planes = _run_nat(x, P, S)                   # (NB, 32, S, 128)
     flat = [planes[:, idx].reshape(bt)[:B] for idx in range(32)]

@@ -6,6 +6,11 @@ file, found through ``fi.seg`` (storage/commit.py)."""
 
 import os
 
+import numpy as np
+
+from minio_tpu.hashing import highwayhash
+from minio_tpu.ops import gf8, gf8_ref
+
 
 def shard_extent(disk, bucket: str, key: str, part: int = 1):
     """(path, offset, length) of part ``part``'s framed shard on
@@ -39,3 +44,22 @@ def flip_byte(disk, bucket: str, key: str, at: int, mask: int = 0xFF,
         b = f.read(1)[0]
         f.seek(pos)
         f.write(bytes([b ^ mask]))
+
+
+def reference_stripes(data: bytes, bs: int, k: int, m: int) -> list:
+    """Per erasure block the (k+m, shard) stripe: reedsolomon Split +
+    gf8_ref parity, the tail block at its own shard size."""
+    out = []
+    for off in range(0, len(data), bs):
+        shards = gf8.split(data[off:off + bs], k)
+        out.append(np.concatenate(
+            [shards, gf8_ref.encode_parity(shards, m)]))
+    return out
+
+
+def reference_framed(data: bytes, bs: int, k: int, m: int) -> list[bytes]:
+    """What each shard's file must hold: [32 B host HighwayHash][shard
+    block] per erasure block."""
+    stripes = reference_stripes(data, bs, k, m)
+    return [b"".join(highwayhash.hh256(s[i].tobytes()) + s[i].tobytes()
+                     for s in stripes) for i in range(k + m)]

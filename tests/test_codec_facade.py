@@ -15,6 +15,8 @@ from minio_tpu.hashing import bitrot, highwayhash
 from minio_tpu.ops import gf8, gf8_native, gf8_ref
 from minio_tpu.ops.codec import Erasure
 
+from . import shard_files
+
 K, M = 4, 2
 BS = 4096                   # shard size 1024
 BS_RAGGED = 4099            # not divisible by k: per-block zero padding
@@ -54,22 +56,12 @@ def _body(n: int, seed: int) -> bytes:
 
 
 def _reference_stripes(data: bytes, bs: int) -> list[np.ndarray]:
-    """Per erasure block the (K+M, shard) stripe: reedsolomon Split +
-    gf8_ref parity, the tail block at its own shard size."""
-    out = []
-    for off in range(0, len(data), bs):
-        shards = gf8.split(data[off:off + bs], K)
-        out.append(np.concatenate(
-            [shards, gf8_ref.encode_parity(shards, M)]))
-    return out
+    return shard_files.reference_stripes(data, bs, K, M)
 
 
 def _reference_framed(data: bytes, bs: int) -> list[bytes]:
-    """[32 B host HighwayHash][shard block] per erasure block, per
-    shard — built before any native library is masked."""
-    stripes = _reference_stripes(data, bs)
-    return [b"".join(highwayhash.hh256(s[i].tobytes()) + s[i].tobytes()
-                     for s in stripes) for i in range(K + M)]
+    """Built before any native library is masked."""
+    return shard_files.reference_framed(data, bs, K, M)
 
 
 @pytest.mark.parametrize("recycled", [False, True],

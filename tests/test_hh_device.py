@@ -167,3 +167,66 @@ def test_pallas_batch_under_an_outer_trace():
     traced = np.asarray(jax.jit(lambda x: hh_pallas.hh256_batch(x))(blocks))
     assert np.array_equal(traced, direct)
     assert np.array_equal(direct, _host_digests(blocks))
+
+
+# -- rows hashed: what mt_tpu_hash_rows_total counts (ISSUE 31) ---------------
+
+@pytest.mark.parametrize("B,rows", [(1, 128), (4, 128), (16, 128),
+                                    (128, 128), (129, 256), (1024, 1024),
+                                    (1025, 2048)])
+def test_hashed_rows_is_the_pad_the_program_applies(B, rows, monkeypatch):
+    """``hh_pallas.hashed_rows`` and the operand ``_hh256_batch`` hands
+    the kernel come from one function of B: a 2+2 stripe's 4 rows and a
+    12+4 stripe's 16 both hash a 128-row tile.  The XLA form and the
+    shapes ``hh256_batch`` hands to it pad none."""
+    import jax
+    import jax.numpy as jnp
+    from minio_tpu.ops import hh_pallas
+    seen = []
+
+    def kernel_stub(x, n_packets, S):
+        seen.append((x.shape[0], S))
+        return jnp.zeros((x.shape[0] // (S * 128), 32, S, 128), jnp.uint32)
+
+    monkeypatch.setattr(hh_pallas, "_run_nat", kernel_stub)
+    # under a function of this test's own: a trace of ``_hh256_batch``
+    # itself would be cached, stub and all, for every later caller
+    out = jax.eval_shape(lambda x: hh_pallas._hh256_batch.__wrapped__(x),
+                         jax.ShapeDtypeStruct((B, 64), jnp.uint8))
+    assert out.shape == (B, 32)
+    (padded, S), = seen
+    assert padded == rows == hh_pallas.hashed_rows(B, 64)
+    assert padded % (S * 128) == 0 and 0 <= padded - B < S * 128
+    assert hh_pallas.hashed_rows(B, 31) == B
+    assert hk.hashed_rows(B, 64) == B
+
+
+def _hash_rows():
+    from minio_tpu.admin.metrics import GLOBAL
+    snap = GLOBAL.snapshot()
+    return {kind: snap.get(("mt_tpu_hash_rows_total", (("kind", kind),)), 0)
+            for kind in ("real", "hashed")}
+
+
+@pytest.mark.parametrize("pallas,hashed", [(False, 4), (True, 128)],
+                         ids=["xla", "pallas"])
+def test_device_hash_dispatch_counts_its_rows(pallas, hashed, monkeypatch):
+    """One dispatch of the device bitrot hash raises
+    ``mt_tpu_hash_rows_total`` by its B rows (``real``) and by the rows
+    the form in force hashes (``hashed``), and says both in
+    ``hash.launch``'s span detail."""
+    from minio_tpu.obs import trace
+    from minio_tpu.ops import codec, device
+    monkeypatch.setattr(device, "use_pallas", lambda: pallas)
+    blocks = np.random.default_rng(31).integers(
+        0, 256, (4, 64), dtype=np.uint8)
+    before = _hash_rows()
+    with trace.HTTP_TRACE.subscribe() as sub:
+        got = codec._device_hh256_batch(blocks)
+        spans = list(sub.drain(20, timeout=2.0))
+    assert np.array_equal(got, _host_digests(blocks))
+    after = _hash_rows()
+    assert {k: after[k] - before[k] for k in after} == \
+        {"real": 4, "hashed": hashed}
+    launch, = [s for s in spans if s.get("funcName") == "hash.launch"]
+    assert launch["tpu"] == {"op": "hash", "rows": 4, "rowsHashed": hashed}

@@ -517,13 +517,15 @@ def _leg_counts() -> dict:
 
 
 def _tpu_counters() -> dict:
-    """{(family, op, dir or backend): value} of the mt_tpu_* counters."""
+    """{(family, op, dir or backend or kind): value} of the mt_tpu_*
+    counters."""
     from minio_tpu.admin.metrics import GLOBAL
     out = {}
     for (name, labels), v in GLOBAL.snapshot().items():
         if name.startswith("mt_tpu_"):
             d = dict(labels)
-            out[(name, d.get("op"), d.get("dir") or d.get("backend"))] = v
+            out[(name, d.get("op"), d.get("dir") or d.get("backend")
+                 or d.get("kind"))] = v
     return out
 
 
@@ -572,6 +574,10 @@ def test_device_put_counts_legs_and_link_bytes(tmp_path):
         (k + m) * (3 * shard + tail_shard)
     assert ctr[("mt_tpu_ops_total", "encode", "tpu")] == 1
     assert ctr[("mt_tpu_bytes_total", "encode", "tpu")] == 200_000
+    # rows per hash dispatch: 4 shards x 3 full blocks, then the 4 tail
+    # rows; the XLA form hashes what it is handed
+    for kind in ("real", "hashed"):
+        assert ctr[("mt_tpu_hash_rows_total", None, kind)] == 16
     # and what it wrote reads back
     assert bytes(layer.get_object("linkb", "obj")[1]) == b"k" * 200_000
 
