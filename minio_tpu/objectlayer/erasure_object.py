@@ -640,6 +640,10 @@ class ErasureObjects(MultipartOps, ObjectLayer):
                 raise serrors.StorageError("commit aborted (BadDigest)")
             return vd
 
+        # a drive's writer thread asks before it would park: with the
+        # digest still out it runs its batch's other bodies first
+        meta_gate.ready = gate.is_set
+
         def resolve():
             try:
                 self._stamp_etag(fi, etag_future.result(), opts, size,

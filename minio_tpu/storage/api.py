@@ -145,7 +145,10 @@ class StorageAPI(abc.ABC):
         visible).  Backends that can, write the part bytes first and
         gate only the metadata merge — the hash runs beside the data
         fan-out (pkg/hash/reader.go overlap); this default resolves the
-        gate up front (no overlap, always correct)."""
+        gate up front (no overlap, always correct).  A gate may carry
+        ``ready()`` (would the call return at once?): a local drive
+        under a group commit then does not park its writer thread on it
+        but runs the merge once its batch's other bodies have run."""
         from .datatypes import ErasureInfo
         from .xl_storage import SYS_DIR as sys_vol
         if meta_gate is not None:

@@ -27,16 +27,24 @@ Three pieces live here:
     tmp→fsync→rename visibility order the eager path enforces, just
     batched.  Registering DUP'D fds (not paths) is load-bearing: the
     op body closes its own fd and may rename the file before the
-    flush, and an fd fsync is immune to both.
+    flush, and an fd fsync is immune to both.  A body that reaches a
+    gate someone else opens (an overlapped PUT's digest) before it is
+    open hands over its second half (``yield_tail``) instead of parking
+    the drive's only writer thread; the halves run in op order before
+    the flush, and before any op that cannot queue behind them.
 
-  * the wave helper (``native/syncwave.c``, loaded on first use) — one
-    call that issues a wave's fsyncs together and never holds the
-    interpreter lock.  Under a loaded interpreter every ``os.*`` call
-    of a thread ends with a wait for the GIL, and a batch's ~40 fsyncs
-    (+ closes and opens) issued one ``os.*`` call at a time made the
-    drive's writer thread wait for the interpreter, not for the drive.
-    Without a compiler (or ``MT_NATIVE=0``) the same calls run one by
-    one from Python.
+  * the writer thread's syscalls (``native/syncwave.c``, loaded on
+    first use), each group of them one call that never holds the
+    interpreter lock: a flush wave's fsyncs, issued together
+    (:func:`sync_files`, :func:`sync_dirs`), and the landing of an op
+    body's files (:func:`land_part`: the two mkdirs, then the part
+    file created, written, dup'd or fsynced, closed; :func:`land_file`:
+    the same for the ``xl.meta`` tmp file).  Under a loaded interpreter
+    every ``os.*`` call of a thread ends with a wait for the GIL, and a
+    batch's ~40 fsyncs and an op body's ~10 calls, issued one ``os.*``
+    call at a time, made the drive's writer thread wait for the
+    interpreter, not for the drive.  Without a compiler (or
+    ``MT_NATIVE=0``) the same calls run one by one from Python.
 
   * :class:`SegmentStore` — per-drive journaled append-only segment
     files under ``<root>/.mt.sys/seg/`` that pack many small objects'
@@ -63,6 +71,7 @@ import threading
 import time
 
 import msgpack
+import numpy as np
 
 from ..admin.metrics import GLOBAL as _metrics
 from ..utils.locktrace import mtlock
@@ -159,10 +168,28 @@ def disarm() -> None:
     _TLS.collector = None
 
 
+def _note_calls(n: int) -> None:
+    """A landing on a drive's writer thread made ``n`` blocking calls
+    into the OS (1 for the native form, the ``os.*`` sequence's own
+    count for the other): each ends in a wait for the interpreter lock,
+    which is what a loaded writer thread's wall is made of.  Tallied on
+    the batch's collector (``mt_commit_body_calls_total``); a landing
+    with none armed is no writer-thread body and counts nothing."""
+    col = collector()
+    if col is not None:
+        col.body_calls += n
+
+
 _WAVE_SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))), "native", "syncwave.c")
 _WAVE_SO = os.path.join(os.path.dirname(_WAVE_SRC), "build",
                         "libmtsyncwave.so")
+
+
+class _Landed(ctypes.Structure):
+    """syncwave.c ``mt_land_t``: how a landing ended."""
+    _fields_ = [("step", ctypes.c_int), ("err", ctypes.c_int),
+                ("fresh", ctypes.c_int), ("fd", ctypes.c_int)]
 
 
 @functools.cache
@@ -178,6 +205,13 @@ def _wave_lib():
         lib.mt_sync_dirs.argtypes = [ctypes.POINTER(ctypes.c_char_p),
                                      ctypes.c_int]
         lib.mt_sync_dirs.restype = None
+        lib.mt_land_file.argtypes = [
+            ctypes.c_char_p, ctypes.c_void_p, ctypes.c_size_t,
+            ctypes.c_int, ctypes.POINTER(_Landed)]
+        lib.mt_land_file.restype = ctypes.c_int
+        lib.mt_land_part.argtypes = [ctypes.c_char_p] * 3 \
+            + lib.mt_land_file.argtypes[1:]
+        lib.mt_land_part.restype = ctypes.c_int
     return lib
 
 
@@ -220,6 +254,103 @@ def sync_dirs(paths: list[str]) -> None:
     lib.mt_sync_dirs((ctypes.c_char_p * n)(*map(os.fsencode, paths)), n)
 
 
+# -- landing an op body's files ---------------------------------------------
+
+def write_full(fd: int, data) -> None:
+    """write(2) until the buffer is drained (short writes are legal on
+    signal delivery even for regular files)."""
+    mv = data
+    if not isinstance(data, bytes):
+        mv = memoryview(data)
+        # a view of the caller's bytes; only a strided one is gathered
+        mv = mv.cast("B") if mv.c_contiguous else mv.tobytes()
+    written = os.write(fd, mv)
+    while written < len(mv):
+        written += os.write(fd, mv[written:])
+
+
+def mkdir_fresh(path: str) -> bool:
+    """mkdir; False where it was there already."""
+    try:
+        os.mkdir(path)
+        return True
+    except FileExistsError:
+        return False
+
+
+# syncwave.c's MT_SYNC_*: what follows a landed file's write
+_SYNC_NONE, _SYNC_DUP, _SYNC_NOW = 0, 1, 2
+
+
+def _land_native(fn, paths: list[str], data, storage) -> bool:
+    """One native landing (``fn`` = ``mt_land_part`` / ``mt_land_file``
+    over ``paths``, the last of them the file).  The caller's buffer
+    goes down by address, no copy made; a failing step comes back as
+    the ``OSError`` (subclass) the same ``os.*`` call would have
+    raised, with that step's path as its ``filename``."""
+    _note_calls(1)
+    col = collector()
+    mv = memoryview(data)
+    # a view of the caller's bytes; only a strided one is gathered first
+    arr = np.frombuffer(mv if mv.c_contiguous else mv.tobytes(),
+                        dtype=np.uint8)
+    sync = _SYNC_NONE if not _FSYNC \
+        else _SYNC_NOW if col is None else _SYNC_DUP
+    out = _Landed()
+    if fn(*map(os.fsencode, paths), arr.ctypes.data_as(ctypes.c_void_p),
+          arr.size, sync, ctypes.byref(out)) != 0:
+        # steps 1, 2 are land_part's mkdirs (paths[0], paths[1]); every
+        # later step is the file's
+        raise OSError(out.err, os.strerror(out.err),
+                      paths[min(out.step, len(paths)) - 1])
+    if out.fd >= 0:
+        col.defer_fd(out.fd, storage=storage)
+    return bool(out.fresh)
+
+
+def land_file(path: str, data, storage=None) -> None:
+    """Create (or truncate) ``path``, write ``data`` until drained, then
+    the durability step, then close: with a collector armed on this
+    thread a dup'd fd is registered for the batch's flush, without one
+    the fsync happens here before the return.  ``_write_file_atomic``'s
+    body up to, not including, its ``os.replace``."""
+    lib = _wave_lib()
+    if lib is not None:
+        _land_native(lib.mt_land_file, [path], data, storage)
+        return
+    _note_calls(4)              # open, write, dup | fsync, close
+    col = collector()
+    fd = os.open(path, os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o644)
+    try:
+        write_full(fd, data)
+        if _FSYNC:
+            if col is not None:
+                col.defer_fd(os.dup(fd), storage=storage)
+            else:
+                os.fsync(fd)
+    finally:
+        os.close(fd)
+
+
+def land_part(obj: str, ddir: str, part: str, data, storage=None) -> bool:
+    """The one-shot landing of a version's part file: ``mkdir(obj)``
+    (there already: not fresh, no error), ``mkdir(ddir)``, then ``part``
+    as :func:`land_file` lands it.  Returns whether ``obj`` was created
+    here.  Raises what the ``os.*`` sequence raises, the failing step's
+    path as ``filename``: ``FileNotFoundError`` on ``obj`` (its parent
+    is missing: the caller's nested-name / wiped-volume case),
+    ``FileExistsError`` on ``ddir``."""
+    lib = _wave_lib()
+    if lib is not None:
+        return _land_native(lib.mt_land_part, [obj, ddir, part], data,
+                            storage)
+    _note_calls(2)              # the mkdirs
+    fresh = mkdir_fresh(obj)
+    os.mkdir(ddir)
+    land_file(part, data, storage)
+    return fresh
+
+
 class GroupCollector:
     """Deferred-durability ledger for ONE drive-writer batch.
 
@@ -238,6 +369,10 @@ class GroupCollector:
         self._fds: list = []
         self._dirs: dict[str, list] = {}    # path -> registering ops
         self._after: list = []              # (fn, op) continuations
+        # (fn, op): second halves of bodies that reached a gate before
+        # what it waits for was there (yield_tail), in op order
+        self._tails: list = []
+        self.tail_s: dict = {}      # op -> seconds its tail took
         # read-after-deferred-write map: final_path -> bytes for
         # xl.meta replaces still parked in ``_after`` — a batch-mate's
         # read-merge-write of the SAME object (or a heal riding the
@@ -247,6 +382,7 @@ class GroupCollector:
         self.synced = 0             # fsync syscalls actually issued
         self.waves = 0              # flush waves that issued any
         self.seg_bytes = 0          # bytes packed into segments
+        self.body_calls = 0         # blocking calls the bodies made
         self.streams: set = set()
 
     # -- registration (op bodies) ------------------------------------------
@@ -284,6 +420,45 @@ class GroupCollector:
         data-dir purges that must not precede the commit point."""
         self._after.append((fn, self.current_op))
 
+    def yield_tail(self, fn) -> None:
+        """The running body has reached a gate that someone else opens
+        (an overlapped PUT's digest) before it is open, and hands over
+        its second half instead of parking this drive's only writer
+        thread on it: the thread goes on to the batch's next body, and
+        :meth:`run_tails` runs the halves in the order they were handed
+        over."""
+        self._tails.append((fn, self.current_op))
+
+    def tails(self) -> bool:
+        """Second halves are waiting: a body that reaches its own gate
+        now queues behind them, whatever its gate says (op order)."""
+        return bool(self._tails)
+
+    def run_tails(self) -> None:
+        """Run the bodies' second halves, in op order, each as its op
+        (trace context, error latched onto its own stream's drive, its
+        wall added to the op's body time).  Called once a batch's bodies
+        have run, and before any body that could not queue behind them:
+        no drive op ever starts with an earlier op's half still owed."""
+        if not self._tails:
+            return
+        tails, self._tails = self._tails, []
+        interrupted = self.current_op
+        for fn, op in tails:
+            self._enter(op)
+            t0 = time.perf_counter()
+            try:
+                fn()
+            except Exception as e:  # noqa: BLE001 — latched per op
+                self._latch([op], e)
+            self.tail_s[op] = time.perf_counter() - t0
+        self._enter(interrupted)
+
+    def _enter(self, op) -> None:
+        self.current_op = op
+        if op is not None:
+            op.bind()
+
     def pending_put(self, path: str, data: bytes) -> None:
         self._pending[path] = data
 
@@ -311,7 +486,9 @@ class GroupCollector:
         continuation therefore runs only after every fsync registered
         before it has RETURNED, exactly as when they ran one by one;
         order inside a wave is arbitrary, as it always was (fds by
-        storage, dirs in dict order)."""
+        storage, dirs in dict order).  Second halves still owed
+        (:meth:`yield_tail`) run first: they register what is flushed."""
+        self.run_tails()
         while self._fds or self._dirs or self._after:
             fds, self._fds = self._fds, []
             dirs, self._dirs = self._dirs, {}
@@ -346,7 +523,13 @@ class GroupCollector:
     def publish(self, n_ops: int) -> None:
         """Tick the mt_commit_group_* families for one flushed batch —
         only when the plane actually engaged (grouped ops or deferred
-        durability work), so an idle or disabled plane emits nothing."""
+        durability work), so an idle or disabled plane emits nothing.
+        ``mt_commit_body_calls_total`` rides beside
+        ``mt_commit_body_seconds`` instead (the two divide per op): one
+        add per batch, not one more lock per op."""
+        if self.body_calls:
+            _metrics.inc("mt_commit_body_calls_total", {},
+                         self.body_calls)
         if n_ops <= 1 and self.deferred == 0:
             return
         _metrics.inc("mt_commit_group_batches_total", {})
@@ -490,8 +673,7 @@ class SegmentStore:
                 self._rotate()
                 s = self._segs[self._cur]
             sid, off = self._cur, s["size"]
-            from .xl_storage import _write_full
-            _write_full(self._cur_fd, data)
+            write_full(self._cur_fd, data)
             s["size"] = off + len(data)
             s["live"][off] = (len(data), vol, name, vid)
             self._journal({"op": "add", "sid": sid, "off": off,

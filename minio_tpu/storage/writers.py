@@ -31,7 +31,9 @@ writer thread per drive with a bounded in-order queue:
     issued together, each wave ONE call that does not hold the
     interpreter lock (commit.sync_files / sync_dirs over
     native/syncwave.c) — followed by the round's continuations (the
-    xl.meta replaces) on the drive's writer thread.  Op bodies,
+    xl.meta replaces) on the drive's writer thread.  An op body lands
+    its part file and its xl.meta tmp file the same way, one such call
+    each (commit.land_part / land_file).  Op bodies,
     continuations and settlement all stay on the one thread per drive,
     so the FIFO contract above is untouched.  A drive op's time is
     queue + body + flush (``mt_commit_{queue,body,flush}_seconds``).
@@ -108,6 +110,17 @@ class _Op:
         self.parent = parent
         self.t_enq = 0.0         # perf_counter when it joined its queue
 
+    def bind(self) -> None:
+        """Make this thread's trace context the op's: per-drive spans
+        must carry the originating request ID even though the worker
+        thread outlives any one request; the X-ray clock rides along so
+        a remote drive's RPC leg is attributed (async detail) to the
+        right request, and the span parent so this op's storage spans
+        land under the submitting span in the request's causal tree."""
+        _trace.set_request_id(self.rid)
+        _trace.set_span_parent(self.parent)
+        _stages.set_clock(self.clock)
+
     def run_body(self, disk) -> tuple:
         """Execute the op body WITHOUT settling; returns ``(err, dt)``.
         Group commit splits body from settlement so a whole batch's
@@ -119,15 +132,7 @@ class _Op:
         st = self.stream
         if st.cancelled or st.errs[self.idx] is not None:
             return (None, 0.0)
-        # per-drive spans must carry the originating request ID even
-        # though the worker thread outlives any one request; the X-ray
-        # clock rides along so a remote drive's RPC leg is attributed
-        # (async detail) to the right request, and the span parent so
-        # this op's storage spans land under the submitting span in the
-        # request's causal tree
-        _trace.set_request_id(self.rid)
-        _trace.set_span_parent(self.parent)
-        _stages.set_clock(self.clock)
+        self.bind()
         t0 = time.perf_counter()
         try:
             self.fn(self.idx, disk)
@@ -212,7 +217,11 @@ class _DriveWriter:
         once — rounds of a file wave, a directory wave and the round's
         continuations settle the whole batch — THEN settle each op so
         per-stream quorum is re-checked only after its covering fsync
-        landed."""
+        landed.  A body that reaches a gate someone else opens (an
+        overlapped PUT's digest) hands its second half to the collector
+        and the thread goes on to the next body: the halves run, in op
+        order, when the bodies have, and count as their ops' body
+        time."""
         col = _commit.GroupCollector()
         _commit.arm(col)
         settles: list[tuple] = []
@@ -221,10 +230,11 @@ class _DriveWriter:
             for op in ops:
                 _commit.observe_stage("queue", t_batch - op.t_enq)
                 col.current_op = op
-                settle = op.run_body(self.disk)
-                settles.append(settle)
-                _commit.observe_stage("body", settle[1])
+                settles.append(op.run_body(self.disk))
+            col.run_tails()
             col.current_op = None
+            for op, (_, dt) in zip(ops, settles):
+                _commit.observe_stage("body", dt + col.tail_s.get(op, 0.0))
             t_flush = time.perf_counter()
             col.flush()
             _commit.observe_stage("flush", time.perf_counter() - t_flush)
@@ -242,7 +252,7 @@ class _DriveWriter:
             for op, (err, dt) in zip(ops, settles):
                 # flush-time failures latched into stream errs; settle
                 # re-reads nothing — _op_done only adds err if unset
-                op.settle(err, dt)
+                op.settle(err, dt + col.tail_s.get(op, 0.0))
                 self.ops += 1
 
     def close(self, timeout: float) -> None:

@@ -20,6 +20,7 @@ page-cache writeback, not alignment, is the governing factor).  Set
 
 from __future__ import annotations
 
+import functools
 import itertools
 import os
 import shutil
@@ -102,16 +103,6 @@ def _read_odirect(full: str, offset: int, length: int) -> bytes | None:
         os.close(fd)
 
 
-def _write_full(fd: int, data) -> None:
-    """write(2) until the buffer is drained (short writes are legal on
-    signal delivery even for regular files)."""
-    mv = memoryview(data).cast("B") if not isinstance(data, bytes) \
-        else data
-    written = os.write(fd, mv)
-    while written < len(mv):
-        written += os.write(fd, mv[written:])
-
-
 _TMP_SEQ = itertools.count()
 
 
@@ -131,17 +122,10 @@ def _write_file_atomic(final_path: str, data, storage=None) -> None:
     content is published so a batch-mate's read-merge-write of the
     same path (two versions of one object in one batch) sees it."""
     tmp = final_path + f".tmp.{os.getpid():x}.{next(_TMP_SEQ):x}"
-    fd = os.open(tmp, os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o644)
     col = _commit.collector()
-    try:
-        _write_full(fd, data)
-        if _FSYNC:
-            if col is not None:
-                col.defer_fd(os.dup(fd), storage=storage)
-            else:
-                os.fsync(fd)
-    finally:
-        os.close(fd)
+    # create, write, dup (collector armed) or fsync (none), close: one
+    # call below the interpreter where the library is (commit.land_file)
+    _commit.land_file(tmp, data, storage=storage)
     if col is None:
         os.replace(tmp, final_path)
         return
@@ -697,6 +681,26 @@ class XLStorage(StorageAPI):
                 and meta.shared_data_dir_count(fi.version_id, old_ddir) == 0:
             self._purge_later(os.path.join(dst_obj_dir, old_ddir))
 
+    def _in_obj_dir(self, volume: str, dst_obj: str, land=None) -> bool:
+        """Make the object directory and say whether it is fresh.
+        ``land`` is a step that begins with that mkdir and goes on from
+        there (commit.land_part); None is the mkdir alone.  A missing
+        parent is a nested object name, made here and the step run
+        again, unless it is the VOLUME that is gone: a wiped volume must
+        NOT be resurrected."""
+        try:
+            return land() if land else _commit.mkdir_fresh(dst_obj)
+        except FileNotFoundError as e:
+            if e.filename != dst_obj:
+                raise
+            if not os.path.isdir(self._vol_path(volume)):
+                self._vols_seen.discard(volume)
+                raise errors.VolumeNotFound(volume) from None
+            os.makedirs(dst_obj, exist_ok=True)   # nested object name
+            if land:
+                land()
+            return True
+
     def write_data_commit(self, volume: str, path: str, fi: FileInfo,
                           data, shard_index: int | None = None,
                           version_dict: dict | None = None,
@@ -717,30 +721,36 @@ class XLStorage(StorageAPI):
         ``meta_gate`` (overlapped PUT): the part bytes — the GIL-free
         bulk of this call — land FIRST, then the gate blocks until the
         object's md5 resolved and yields the final version dict; the
-        merge below uses it.  A gate abort (BadDigest) raises before
+        merge uses it.  A gate abort (BadDigest) raises before
         any version becomes visible, leaving only an orphan data dir
-        the caller purges."""
+        the caller purges.  Under a group commit the drive's one writer
+        thread does not wait at a gate that says it is not ``ready()``:
+        the merge is handed to the collector and runs, in op order,
+        once the batch's other bodies have landed their bytes."""
         self._check_vol(volume)
         dst_obj = self._file_path(volume, path)
-        try:
-            os.mkdir(dst_obj)
-            fresh = True
-        except FileExistsError:
-            fresh = False
-        except FileNotFoundError:
-            # parent missing: wiped volume must NOT be resurrected
-            if not os.path.isdir(self._vol_path(volume)):
-                self._vols_seen.discard(volume)
-                raise errors.VolumeNotFound(volume) from None
-            os.makedirs(dst_obj, exist_ok=True)   # nested object name
-            fresh = True
         stream_ddir = None
         col = _commit.collector()
-        if fi.data_dir:
-            ddir = dst_obj + "/" + fi.data_dir
+        streaming = hasattr(data, "__next__")
+        ddir = dst_obj + "/" + fi.data_dir
+        if not fi.data_dir:
+            fresh = self._in_obj_dir(volume, dst_obj)
+        elif not (streaming or _ODIRECT):
+            # one-shot: both mkdirs and the part file (create, write,
+            # dup or fsync, close) in one call below the interpreter
+            # where the library is — the 16-drive commit fan-out runs
+            # this per drive (commit.land_part)
+            t_op = time.monotonic_ns()
+            fresh = self._in_obj_dir(
+                volume, dst_obj, lambda: _commit.land_part(
+                    dst_obj, ddir, ddir + "/part.1", data, storage=self))
+            t_op = self._prof("create", t_op, len(data))
+            _fsync_dir(ddir)
+            self._prof("fsync", t_op)
+        else:
+            fresh = self._in_obj_dir(volume, dst_obj)
             os.mkdir(ddir)
             part = ddir + "/part.1"
-            streaming = hasattr(data, "__next__")
             t_op = time.monotonic_ns()
             try:
                 if streaming:
@@ -754,7 +764,7 @@ class XLStorage(StorageAPI):
                                  0o644)
                     try:
                         for chunk in data:
-                            _write_full(fd, chunk)
+                            _commit.write_full(fd, chunk)
                         t_op = self._prof("create", t_op)
                         if _FSYNC:
                             if col is not None:
@@ -763,25 +773,11 @@ class XLStorage(StorageAPI):
                                 os.fsync(fd)
                     finally:
                         os.close(fd)
-                elif not (_ODIRECT
-                          and self._create_file_odirect(part, data)):
-                    # raw fd write: the 16-drive commit fan-out runs
-                    # this 32 times per object; BufferedWriter setup
-                    # costs more than the write for one-shot dumps
-                    fd = os.open(part,
-                                 os.O_WRONLY | os.O_CREAT | os.O_TRUNC,
-                                 0o644)
-                    try:
-                        _write_full(fd, data)
-                        t_op = self._prof("create", t_op, len(data))
-                        if _FSYNC:
-                            if col is not None:
-                                col.defer_fd(os.dup(fd), storage=self)
-                            else:
-                                os.fsync(fd)
-                    finally:
-                        os.close(fd)
-                else:                # O_DIRECT landed the part whole
+                else:
+                    # O_DIRECT lands the part whole; a filesystem
+                    # without it gets the buffered landing
+                    if not self._create_file_odirect(part, data):
+                        _commit.land_file(part, data, storage=self)
                     t_op = self._prof("create", t_op, len(data))
                 _fsync_dir(ddir)
                 self._prof("fsync", t_op)
@@ -789,6 +785,27 @@ class XLStorage(StorageAPI):
                 if stream_ddir is not None:
                     shutil.rmtree(stream_ddir, ignore_errors=True)
                 raise
+        merge = functools.partial(
+            self._merge_version, volume, path, fi, dst_obj, fresh,
+            shard_index, version_dict, meta_gate, stream_ddir)
+        if col is not None:
+            ready = getattr(meta_gate, "ready", None)
+            if ready is not None and stream_ddir is None \
+                    and (col.tails() or not ready()):
+                # the digest is not there yet (or an earlier op of this
+                # batch waits for its own): the drive's one writer
+                # thread goes on to the batch's next body instead of
+                # parking here, and the merge runs when the bodies have
+                col.yield_tail(merge)
+                return
+            col.run_tails()      # owed by earlier ops: theirs go first
+        merge()
+
+    def _merge_version(self, volume: str, path: str, fi: FileInfo,
+                       dst_obj: str, fresh: bool, shard_index,
+                       version_dict, meta_gate, stream_ddir) -> None:
+        """write_data_commit's second half: pass the gate, merge the
+        version into xl.meta."""
         if meta_gate is not None:
             # md5 beside the write above; the park is caller-side work,
             # not drive time — keep it out of the latency windows that
@@ -849,17 +866,7 @@ class XLStorage(StorageAPI):
         write_data_commit path pays."""
         self._check_vol(volume)
         dst_obj = self._file_path(volume, path)
-        try:
-            os.mkdir(dst_obj)
-            fresh = True
-        except FileExistsError:
-            fresh = False
-        except FileNotFoundError:
-            if not os.path.isdir(self._vol_path(volume)):
-                self._vols_seen.discard(volume)
-                raise errors.VolumeNotFound(volume) from None
-            os.makedirs(dst_obj, exist_ok=True)
-            fresh = True
+        fresh = self._in_obj_dir(volume, dst_obj)
         col = _commit.collector()
         nbytes = len(data)
         t_op = time.monotonic_ns()
@@ -1179,6 +1186,12 @@ def _traced_op(op: str, fn, in_arg: int | None):
     def traced(self, *a, **kw):
         if getattr(_IN_TRACED_OP, "depth", 0):
             return fn(self, *a, **kw)
+        col = _commit.collector()
+        if col is not None and op != "write_data_commit":
+            # earlier ops of this batch that yielded at their gate are
+            # owed their second half before any op that cannot queue
+            # behind them starts (write_data_commit sees to its own)
+            col.run_tails()
         _IN_TRACED_OP.depth = 1
         _IN_TRACED_OP.exclude_ns = 0
         # monotonic for the duration (an NTP step must not corrupt the

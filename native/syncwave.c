@@ -1,7 +1,11 @@
-// One wave of a drive's group-commit flush (storage/commit.py
-// GroupCollector.flush): the fsyncs of a round's files, or of its
-// directories, issued together from ONE call that never holds the
-// interpreter lock.
+// The syscalls of a drive's writer thread (storage/commit.py), each
+// group of them ONE call that never holds the interpreter lock:
+//
+//   * a wave of a group-commit flush (GroupCollector.flush): the fsyncs
+//     of a round's files, or of its directories, issued together;
+//   * the landing of a drive op's body (land_part / land_file, at the
+//     end of this file): a file created, written, dup'd or fsynced, and
+//     closed, for the part file behind its two mkdirs.
 //
 // Why native: under a loaded interpreter every blocking call a Python
 // thread makes ends with a wait for the GIL, so a drive's writer thread
@@ -21,6 +25,9 @@
 #include <errno.h>
 #include <fcntl.h>
 #include <pthread.h>
+#include <stddef.h>
+#include <sys/stat.h>
+#include <sys/types.h>
 #include <unistd.h>
 
 #define MT_SYNC_SLICES 8
@@ -78,4 +85,98 @@ void mt_sync_files(const int *fds, int n, int *errs) {
 // _fsync_dir tolerates them.
 void mt_sync_dirs(const char *const *dirs, int n) {
     wave(0, dirs, n, 0);
+}
+
+// -- a drive op's body: one file landed per call ---------------------------
+//
+// Under the same load the body of a drive op (xl_storage.py
+// write_data_commit: 2 mkdir, then open / write / dup / close for the
+// part file and again for the xl.meta tmp file) paid the interpreter
+// once per syscall (PERF.md section 6, PR 32).  The calls, their order
+// and their objects are the Python sequence's; what differs is that the
+// calling thread gives the interpreter lock up once per file.
+
+// the step a landing failed at (mt_land_t.step); 0 = it did not fail
+enum { MT_LAND_MKDIR_OBJ = 1, MT_LAND_MKDIR_DDIR, MT_LAND_OPEN,
+       MT_LAND_WRITE, MT_LAND_SYNC, MT_LAND_CLOSE };
+
+// what follows the write: nothing (MT_FSYNC=0), a dup whose fsync the
+// armed collector issues at its flush, or the fsync itself (eager path)
+enum { MT_SYNC_NONE = 0, MT_SYNC_DUP = 1, MT_SYNC_NOW = 2 };
+
+typedef struct {
+    int step;   // 0, or the MT_LAND_* step that failed
+    int err;    // that step's errno
+    int fresh;  // land_part: mkdir(obj) created it (EEXIST is no error)
+    int fd;     // MT_SYNC_DUP: the dup'd descriptor, the caller's to
+                // fsync and close; else -1
+} mt_land_t;
+
+static int land_fail(mt_land_t *out, int step, int err) {
+    out->step = step;
+    out->err = err;
+    return -1;
+}
+
+// open(O_WRONLY|O_CREAT|O_TRUNC, 0644), write until drained, dup or
+// fsync, close: _write_file_atomic's body up to, not including, its
+// os.replace.  No descriptor is left open on any failing step.
+int mt_land_file(const char *path, const void *buf, size_t len, int sync,
+                 mt_land_t *out) {
+    int fd;
+    out->step = out->err = 0;
+    out->fd = -1;
+    do {
+        fd = open(path, O_WRONLY | O_CREAT | O_TRUNC | O_CLOEXEC, 0644);
+    } while (fd < 0 && errno == EINTR);
+    if (fd < 0) return land_fail(out, MT_LAND_OPEN, errno);
+    const char *p = (const char *)buf;
+    while (len > 0) {           // short writes are legal, EINTR too
+        ssize_t w = write(fd, p, len);
+        if (w < 0) {
+            if (errno == EINTR) continue;
+            int e = errno;
+            close(fd);
+            return land_fail(out, MT_LAND_WRITE, e);
+        }
+        p += w;
+        len -= (size_t)w;
+    }
+    int dfd = -1, rc = 0;
+    if (sync == MT_SYNC_DUP) {
+        // os.dup's descriptor is not inheritable: the same, in one call
+        dfd = fcntl(fd, F_DUPFD_CLOEXEC, 0);
+        rc = dfd < 0 ? -1 : 0;
+    } else if (sync == MT_SYNC_NOW) {
+        do { rc = fsync(fd); } while (rc < 0 && errno == EINTR);
+    }
+    if (rc < 0) {
+        int e = errno;
+        close(fd);
+        return land_fail(out, MT_LAND_SYNC, e);
+    }
+    // EINTR from close: Linux has released the descriptor already and
+    // os.close reports none (PEP 475); any other errno is the landing's
+    if (close(fd) < 0 && errno != EINTR) {
+        int e = errno;
+        if (dfd >= 0) close(dfd);
+        return land_fail(out, MT_LAND_CLOSE, e);
+    }
+    out->fd = dfd;
+    return 0;
+}
+
+// mkdir(obj) (EEXIST: not fresh, no error), mkdir(ddir), then the part
+// file as mt_land_file lands it: write_data_commit's one-shot branch.
+int mt_land_part(const char *obj, const char *ddir, const char *part,
+                 const void *buf, size_t len, int sync, mt_land_t *out) {
+    out->fd = -1;
+    out->fresh = 1;
+    if (mkdir(obj, 0777) < 0) {
+        if (errno != EEXIST) return land_fail(out, MT_LAND_MKDIR_OBJ, errno);
+        out->fresh = 0;
+    }
+    if (mkdir(ddir, 0777) < 0)
+        return land_fail(out, MT_LAND_MKDIR_DDIR, errno);
+    return mt_land_file(part, buf, len, sync, out);
 }

@@ -24,16 +24,26 @@ Contracts pinned here:
     registered before a version's os.replace has RETURNED before it,
     the post-rename directory fsync comes after it, a wave's fsync
     error latches onto its own stream's drive only, and the same fsyncs
-    land the same bytes as with grouping off.
+    land the same bytes as with grouping off;
+  * landing — an op body's part file and xl.meta tmp file each land in
+    one call (commit.land_part / land_file): the native form and the
+    os.* form leave the same tree, the same ``fresh`` answer, the same
+    registered fds and the same errors; a buffer goes down without a
+    copy; the fsync is deferred exactly when a collector is armed.
 """
 
 import glob
 import hashlib
+import json
 import os
 import shutil
+import subprocess
+import sys
 import threading
 import time
+import tracemalloc
 
+import numpy as np
 import pytest
 
 from minio_tpu.admin.metrics import GLOBAL as metrics
@@ -46,6 +56,7 @@ from minio_tpu.objectlayer.interface import (ObjectNotFound,
 from minio_tpu.storage import commit
 from minio_tpu.storage import errors as serrors
 from minio_tpu.storage import xl_storage
+from minio_tpu.storage.datatypes import ErasureInfo, FileInfo
 from minio_tpu.storage.writers import close_write_planes
 from minio_tpu.storage.xl_storage import XLStorage
 
@@ -358,15 +369,25 @@ def test_group_metrics_tick_when_groups_form(tmp_path):
 
 # -- flush waves -------------------------------------------------------------
 
-@pytest.fixture(params=["native", "python"])
-def wave_impl(request, monkeypatch):
-    """Both forms of a flush wave: the one call into native/syncwave.c,
-    and the os.* loop that stands in for it without a compiler (the
-    only form whose fsyncs a wrapped ``os.fsync`` can see)."""
-    if request.param == "python":
+LAND_FORMS = ("native", "python")
+
+
+def land_form(monkeypatch, form):
+    """Select one form of the writer thread's syscalls (flush waves,
+    commit.land_part / land_file): the calls into native/syncwave.c, or
+    the os.* sequence that stands in for them without a compiler."""
+    if form == "python":
         monkeypatch.setattr(commit, "_wave_lib", lambda: None)
     elif commit._wave_lib() is None:
         pytest.skip("native/syncwave.c did not build here")
+
+
+@pytest.fixture(params=LAND_FORMS)
+def wave_impl(request, monkeypatch):
+    """Both forms of a flush wave: the one call into native/syncwave.c,
+    and the os.* loop (the only form whose fsyncs a wrapped ``os.fsync``
+    can see)."""
+    land_form(monkeypatch, request.param)
     return request.param
 
 
@@ -490,7 +511,8 @@ def test_flush_issues_a_rounds_fsyncs_in_waves(tmp_path, monkeypatch,
     """A batch of N regular PUTs flushes in three waves per drive — 2N
     file fds; 2N + 1 directories (the bucket dir once); the N object
     dirs again behind the renames — and the counters behind
-    commit_flush_width / commit_fsyncs_per_put / commit_*_ms tick."""
+    commit_flush_width / commit_fsyncs_per_put / commit_*_ms /
+    commit_body_calls_per_op tick."""
     monkeypatch.setattr(eo, "_SINGLE_CORE", False)
     commit.CONFIG.pack_threshold = 0          # regular objects only
     lay = mk_layer(tmp_path)
@@ -498,6 +520,7 @@ def test_flush_issues_a_rounds_fsyncs_in_waves(tmp_path, monkeypatch,
     log = SyncLog(monkeypatch)
     f0, w0 = counter("mt_commit_fsyncs_total"), \
         counter("mt_commit_flush_waves_total")
+    c0 = counter("mt_commit_body_calls_total")
     flush0, body0, queue0 = (hist(f"mt_commit_{s}_seconds")
                              for s in ("flush", "body", "queue"))
     assert not gated_puts(lay, [(f"r{i}", pattern(20_000 + i), None)
@@ -509,6 +532,11 @@ def test_flush_issues_a_rounds_fsyncs_in_waves(tmp_path, monkeypatch,
     assert issued == len(lay.disks) * (5 * n + 1)
     assert waves == len(lay.disks) * 3
     assert issued / waves > 2                 # commit_flush_width
+    # commit_body_calls_per_op: a body lands its part and its xl.meta
+    # tmp file in one call each, or in ten os.* calls (the gate op's
+    # body makes none)
+    assert counter("mt_commit_body_calls_total") - c0 \
+        == len(lay.disks) * n * (2 if wave_impl == "native" else 10)
     # the os.* loop issues them where the wrapper sees them; the native
     # wave issues none through os.fsync
     assert len(log.fsyncs) == (issued if wave_impl == "python" else 0)
@@ -703,7 +731,12 @@ def test_batched_flush_same_bytes_and_same_fsyncs_as_eager(tmp_path,
         before = {k: counter(k) for k in (
             "mt_commit_fsyncs_total",
             "mt_commit_group_fsyncs_saved_total")}
-        assert not gated_puts(lay, puts)
+        with monkeypatch.context() as mp:
+            if mode == "eager":
+                # the reference count: an eager body's own fsyncs are
+                # seen only where os.fsync makes them (commit.land_*)
+                mp.setattr(commit, "_wave_lib", lambda: None)
+            assert not gated_puts(lay, puts)
         grouped[mode] = {k: counter(k) - v for k, v in before.items()}
         calls[mode] = len(log.fsyncs)
         states[mode] = {name: disk_state(lay, name)
@@ -747,3 +780,594 @@ def test_compaction_rewrites_live_extents(tmp_path):
     # compaction must not strand packed objects off the segment plane
     assert all(r is not None for r in seg_refs(lay, "c1"))
     close_write_planes(lay)
+
+
+# -- landing an op body's files ----------------------------------------------
+
+def land_fi(vid="", ddir="dd"):
+    return FileInfo(volume="bkt", name="o", version_id=vid, data_dir=ddir,
+                    mod_time=1_234_567_890, size=8,
+                    erasure=ErasureInfo(data_blocks=2, parity_blocks=1,
+                                        block_size=1024, index=1,
+                                        distribution=[1, 2, 3]))
+
+
+def tree(root):
+    """{path under root: bytes, or None for a directory}; an xl.meta
+    tmp file under its name without the pid and counter."""
+    out = {}
+    for base, dirs, files in os.walk(root):
+        rel = os.path.relpath(base, root)
+        if rel.startswith(".mt.sys"):
+            continue
+        out[rel] = None
+        for f in files:
+            with open(os.path.join(base, f), "rb") as fh:
+                out[os.path.join(rel, f.split(".tmp.")[0]
+                                 + (".tmp" if ".tmp." in f else ""))] \
+                    = fh.read()
+    return out
+
+
+def open_fds():
+    return len(os.listdir("/proc/self/fd"))
+
+
+def one_commit(root, scene, form, monkeypatch):
+    """One write_data_commit on a new drive, a collector armed, through
+    one form of the landing; returns everything it left behind."""
+    root.mkdir()
+    d = XLStorage(str(root))
+    d.make_vol("bkt")
+    vol = os.path.join(d.root, "bkt")
+    name, fi = "o", land_fi()
+    if scene == "existing":
+        d.write_data_commit("bkt", "o", land_fi(vid="v0", ddir="d0"),
+                            b"old bytes", shard_index=1)
+        fi = land_fi(vid="v1")
+    elif scene == "nested":
+        name = "a/b/o"
+    elif scene == "volume_gone":
+        d.stat_vol("bkt")                # the volume is remembered
+        shutil.rmtree(vol)
+    elif scene == "ddir_exists":
+        os.makedirs(os.path.join(vol, "o", "dd"))
+    elif scene == "unwritable":
+        if os.geteuid() == 0:            # root writes anywhere: a file
+            open(os.path.join(vol, "o"), "wb").close()   # in the way
+        else:
+            os.makedirs(os.path.join(vol, "o"))
+            os.chmod(os.path.join(vol, "o"), 0o555)
+    out = {"exc": None}
+    n0 = open_fds()
+    with monkeypatch.context() as mp:
+        land_form(mp, form)
+        fsyncs = []
+        real_fsync = os.fsync
+        mp.setattr(os, "fsync", lambda fd: (fsyncs.append(fd),
+                                            real_fsync(fd)))
+        col = commit.GroupCollector()
+        commit.arm(col)
+        try:
+            d.write_data_commit("bkt", name, fi, pattern(5000),
+                                shard_index=1)
+        except Exception as e:           # noqa: BLE001 — compared below
+            out["exc"] = (type(e).__name__, getattr(e, "errno", None),
+                          os.path.relpath(e.filename, d.root)
+                          if getattr(e, "filename", None) else None)
+        finally:
+            out["calls"] = col.body_calls
+            out["body_fsyncs"] = len(fsyncs)
+            out["fds"] = sorted(
+                os.path.relpath(os.readlink(f"/proc/self/fd/{r[0]}"),
+                                d.root).split(".tmp.")[0]
+                for r in col._fds)
+            out["dirs"] = sorted(os.path.relpath(p, d.root)
+                                 for p in col._dirs)
+            out["before_flush"] = tree(d.root)
+            col.flush()
+            commit.disarm()
+        out["after_flush"] = tree(d.root)
+    out["leaked_fds"] = open_fds() - n0
+    if scene == "unwritable" and os.geteuid() != 0:
+        os.chmod(os.path.join(vol, "o"), 0o755)
+    return out
+
+
+@pytest.mark.parametrize("scene", ["fresh", "existing", "nested",
+                                   "volume_gone", "ddir_exists",
+                                   "unwritable"])
+def test_landing_native_and_os_forms_leave_the_same(tmp_path, monkeypatch,
+                                                    scene):
+    """write_data_commit's one-shot branch through commit.land_part +
+    land_file: the native calls and the os.* sequence leave
+    byte-identical trees (before the flush, where the version is not
+    visible yet, and after it), the same ``fresh`` answer (the bucket
+    dir is registered for a fresh object only), the same registered
+    fds, the same error with the same errno and path, and no open
+    descriptor; only the number of blocking calls differs."""
+    got = {form: one_commit(tmp_path / form, scene, form, monkeypatch)
+           for form in LAND_FORMS}
+    nat, py = got["native"], got["python"]
+    calls = {form: g.pop("calls") for form, g in got.items()}
+    assert nat == py
+    assert nat["leaked_fds"] == 0 and nat["body_fsyncs"] == 0
+    ok = scene in ("fresh", "existing", "nested")
+    obj = "bkt/a/b/o" if scene == "nested" else "bkt/o"
+    if ok:
+        assert nat["exc"] is None
+        assert nat["fds"] == [f"{obj}/dd/part.1", f"{obj}/xl.meta"]
+        assert (os.path.dirname(obj) in nat["dirs"]) \
+            == (scene != "existing")
+        assert f"{obj}/xl.meta" not in nat["before_flush"] \
+            or scene == "existing"
+        assert nat["before_flush"][f"{obj}/xl.meta.tmp"] \
+            == nat["after_flush"][f"{obj}/xl.meta"]
+        assert nat["after_flush"][f"{obj}/dd/part.1"] == pattern(5000)
+        assert f"{obj}/xl.meta.tmp" not in nat["after_flush"]
+        # one call per landing against the os.* sequence's 2 mkdirs and
+        # 4 calls per file (nested: + the landing that found no parent)
+        assert calls["native"] == (3 if scene == "nested" else 2)
+        assert calls["python"] == (12 if scene == "nested" else 10)
+    if scene == "existing":
+        assert nat["after_flush"]["bkt/o/d0/part.1"] == b"old bytes"
+    if scene == "volume_gone":
+        assert nat["exc"][0] == "VolumeNotFound"
+        assert "bkt" not in nat["after_flush"]      # not resurrected
+    if scene == "ddir_exists":
+        assert nat["exc"] == ("FileExistsError", 17, "bkt/o/dd")
+        assert nat["fds"] == []
+    if scene == "unwritable":
+        assert nat["exc"][0] == ("NotADirectoryError"
+                                 if os.geteuid() == 0
+                                 else "PermissionError")
+        assert nat["exc"][2] == "bkt/o/dd" and nat["fds"] == []
+
+
+def land_buffers(n):
+    body = pattern(n)
+    rows = np.frombuffer(body * 2, dtype=np.uint8).reshape(2, n)
+    return {"bytes": body, "bytearray": bytearray(body),
+            "memoryview": memoryview(body),
+            "numpy_row": rows[1]}
+
+
+@pytest.mark.parametrize("form", LAND_FORMS)
+@pytest.mark.parametrize("kind", sorted(land_buffers(16)))
+def test_landing_takes_the_callers_buffer_without_a_copy(tmp_path,
+                                                         monkeypatch,
+                                                         form, kind):
+    """bytes, bytearray, memoryview (read-only) and a NumPy uint8 row
+    all go down by address: landing 4 MiB allocates nothing of that
+    size (a 5 MiB ``bytes(...)`` under the GIL would give back what the
+    one call saves)."""
+    land_form(monkeypatch, form)
+    n = 4 << 20
+    buf = land_buffers(n)[kind]
+    obj = str(tmp_path / "o")
+    tracemalloc.start()
+    try:
+        fresh = commit.land_part(obj, obj + "/dd", obj + "/dd/part.1", buf)
+        commit.land_file(obj + "/xl.meta.tmp", buf)
+        peak = tracemalloc.get_traced_memory()[1]
+    finally:
+        tracemalloc.stop()
+    assert fresh is True
+    assert peak < n // 16, peak
+    for path in (obj + "/dd/part.1", obj + "/xl.meta.tmp"):
+        with open(path, "rb") as f:
+            assert f.read() == pattern(n)
+    # a strided view is gathered first and lands the same bytes
+    commit.land_file(obj + "/strided", land_buffers(64)["numpy_row"][::2])
+    with open(obj + "/strided", "rb") as f:
+        assert f.read() == pattern(64)[::2]
+
+
+@pytest.mark.parametrize("form", LAND_FORMS)
+@pytest.mark.parametrize("armed", [True, False],
+                         ids=["collector_armed", "no_collector"])
+def test_landing_defers_the_fsync_exactly_when_a_collector_is_armed(
+        tmp_path, monkeypatch, form, armed):
+    """/dev/null takes a write and refuses an fsync (EINVAL), whoever
+    issues it.  Armed: no fsync in the body — the landing succeeds and
+    ONE dup'd fd of the file is registered.  Not armed: the fsync runs
+    inside the call, before it returns — the landing raises its EINVAL,
+    and leaves no descriptor open."""
+    land_form(monkeypatch, form)
+    col = commit.GroupCollector()
+    if armed:
+        commit.arm(col)
+    n0 = open_fds()
+    try:
+        if armed:
+            commit.land_file("/dev/null", b"x")
+            assert len(col._fds) == 1 and open_fds() == n0 + 1
+            assert os.readlink(f"/proc/self/fd/{col._fds[0][0]}") \
+                == "/dev/null"
+            # ... and a regular file's registered fd is the file itself
+            commit.land_file(str(tmp_path / "f"), b"abc")
+            fd = col._fds[1][0]
+            assert os.fstat(fd).st_ino == os.stat(tmp_path / "f").st_ino
+            assert os.get_inheritable(fd) is False
+        else:
+            with pytest.raises(OSError) as e:
+                commit.land_file("/dev/null", b"x")
+            assert e.value.errno == 22
+            assert not col._fds
+            commit.land_file(str(tmp_path / "f"), b"abc")    # fsync ok
+    finally:
+        commit.disarm()
+        for rec in col._fds:
+            os.close(rec[0])
+    assert open_fds() == n0
+    with open(tmp_path / "f", "rb") as f:
+        assert f.read() == b"abc"
+
+
+@pytest.mark.parametrize("form", LAND_FORMS)
+def test_landed_version_flips_only_after_the_wave_that_covers_both_fds(
+        tmp_path, monkeypatch, form):
+    """Kill ordering, one op: between the body and the flush a crash
+    finds the part file and the xl.meta tmp file but NO xl.meta; the
+    os.replace runs only once the file wave has fsynced and closed both
+    registered fds (the part's and the tmp file's)."""
+    land_form(monkeypatch, form)
+    (tmp_path / "d").mkdir()
+    d = XLStorage(str(tmp_path / "d"))
+    d.make_vol("bkt")
+    col = commit.GroupCollector()
+    commit.arm(col)
+    seen = []
+    real_replace = os.replace
+
+    def replace(src, dst, **kw):
+        for fd, ino in inodes:
+            try:
+                assert os.fstat(fd).st_ino != ino, "open across the flip"
+            except OSError:
+                pass                                  # closed
+        seen.append((os.path.basename(src).split(".tmp.")[0],
+                     os.path.basename(dst)))
+        real_replace(src, dst, **kw)
+    monkeypatch.setattr(os, "replace", replace)
+    try:
+        d.write_data_commit("bkt", "o", land_fi(), pattern(3000),
+                            shard_index=1)
+        inodes = [(r[0], os.fstat(r[0]).st_ino) for r in col._fds]
+        assert len(inodes) == 2
+        before = tree(d.root)
+        assert "bkt/o/xl.meta" not in before and not seen
+        assert before["bkt/o/dd/part.1"] == pattern(3000)
+        col.flush()
+    finally:
+        commit.disarm()
+    assert seen == [("xl.meta", "xl.meta")]
+    assert d.read_version("bkt", "o", "").data_dir == "dd"
+
+
+FSYNC_SHIM = """
+#define _GNU_SOURCE
+#include <dlfcn.h>
+static int count;
+int mt_shim_fsyncs(void) { return __atomic_load_n(&count, __ATOMIC_SEQ_CST); }
+int fsync(int fd) {
+    static int (*real)(int);
+    if (!real) real = (int (*)(int))dlsym(RTLD_NEXT, "fsync");
+    __atomic_add_fetch(&count, 1, __ATOMIC_SEQ_CST);
+    return real(fd);
+}
+"""
+
+EAGER_COUNT = """
+import ctypes, json, os, sys
+from minio_tpu.storage import commit
+from minio_tpu.storage.xl_storage import XLStorage
+from tests.test_commit_plane import land_fi, pattern, tree
+shim = ctypes.CDLL(sys.argv[1])
+out = {}
+for form in ("native", "python"):
+    if form == "python":
+        commit._wave_lib = lambda: None
+    elif commit._wave_lib() is None:
+        continue
+    root = os.path.join(sys.argv[2], form)
+    os.mkdir(root)
+    d = XLStorage(root)
+    d.make_vol("bkt")
+    counts = []
+    for vid, ddir in (("v0", "d0"), ("v1", "d1")):   # fresh, then not
+        n0 = shim.mt_shim_fsyncs()
+        d.write_data_commit("bkt", "o", land_fi(vid=vid, ddir=ddir),
+                            pattern(70_000), shard_index=1)
+        counts.append(shim.mt_shim_fsyncs() - n0)
+    n0 = shim.mt_shim_fsyncs()
+    d.write_all("bkt", "doc", b"a document")
+    counts.append(shim.mt_shim_fsyncs() - n0)
+    out[form] = [counts, sorted(tree(root))]
+print(json.dumps(out))
+"""
+
+
+def test_eager_landing_issues_the_os_forms_fsyncs(tmp_path):
+    """No collector armed (grouping off, a peer's RPC ops): the native
+    landing makes its fsyncs inside the C call, where no wrapped
+    ``os.fsync`` sees them.  A preloaded ``fsync`` that counts sees
+    both forms: the same number per op — the part file, its data dir,
+    the xl.meta tmp file, the object dir, and the bucket dir for a
+    fresh object — and the same tree."""
+    (tmp_path / "shim.c").write_text(FSYNC_SHIM)
+    so = str(tmp_path / "shim.so")
+    try:
+        subprocess.run(["cc", "-shared", "-fPIC", "-O1", "-o", so,
+                        str(tmp_path / "shim.c"), "-ldl"], check=True,
+                       capture_output=True, timeout=120)
+    except (OSError, subprocess.SubprocessError):
+        pytest.skip("no C compiler for the fsync-counting shim")
+    res = subprocess.run(
+        [sys.executable, "-c", EAGER_COUNT, so, str(tmp_path)],
+        env={**os.environ, "LD_PRELOAD": so, "JAX_PLATFORMS": "cpu",
+             "MT_FSYNC": "1"},
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        capture_output=True, text=True, timeout=300)
+    assert res.returncode == 0, res.stderr[-2000:]
+    got = json.loads(res.stdout.strip().splitlines()[-1])
+    if "native" not in got:
+        pytest.skip("native/syncwave.c did not build here")
+    assert got["native"] == got["python"]
+    assert got["native"][0] == [5, 4, 2]
+
+
+# -- a body that reaches its gate before the digest ---------------------------
+
+class Gate:
+    """An overlapped PUT's meta gate, opened by hand; ``vd`` None is a
+    failed digest."""
+
+    def __init__(self, vd, opened=False):
+        self.vd, self.calls, self.ev = vd, 0, threading.Event()
+        if opened:
+            self.ev.set()
+
+    def ready(self):
+        return self.ev.is_set()
+
+    def open_in(self, seconds):
+        threading.Timer(seconds, self.ev.set).start()
+
+    def __call__(self):
+        self.calls += 1
+        assert self.ev.wait(20)
+        if self.vd is None:
+            raise serrors.StorageError("commit aborted (BadDigest)")
+        return self.vd
+
+
+class StubOp:
+    """What a collector needs of a writer-plane op."""
+
+    def __init__(self):
+        self.idx, self.stream, self.errs, self.bound = 0, self, [], 0
+
+    def _latch_err(self, idx, err):
+        self.errs.append(err)
+
+    def bind(self):
+        self.bound += 1
+
+
+def gated_commit(d, col, name, vid, gate_open, data=None, vd=True):
+    """One write_data_commit of ``name`` under ``col`` as an op of its
+    own; returns (gate, op)."""
+    fi = land_fi(vid=vid, ddir="d-" + vid)
+    gate = Gate(fi.to_dict() if vd else None, opened=gate_open)
+    op = col.current_op = StubOp()
+    d.write_data_commit("bkt", name, fi,
+                        pattern(3000) if data is None else data,
+                        shard_index=1, meta_gate=gate)
+    return gate, op
+
+
+def metas(d):
+    """xl.meta files and tmp files on the drive, by object."""
+    return sorted(k for k in tree(d.root) if "xl.meta" in k)
+
+
+@pytest.fixture
+def armed_drive(tmp_path):
+    d = XLStorage(str(tmp_path))
+    d.make_vol("bkt")
+    col = commit.GroupCollector()
+    commit.arm(col)
+    flips = []
+    real_replace = os.replace
+
+    def replace(src, dst, **kw):
+        flips.append(os.path.relpath(dst, d.root))
+        real_replace(src, dst, **kw)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(os, "replace", replace)
+        try:
+            yield d, col, flips
+        finally:
+            commit.disarm()
+
+
+@pytest.mark.parametrize("form", LAND_FORMS)
+def test_body_yields_at_a_gate_that_is_not_open(armed_drive, monkeypatch,
+                                                form):
+    """The digest is not there when the part has landed: the body
+    returns with its merge handed to the collector (the gate is not
+    even called: nothing parks), a batch-mate whose own gate IS open
+    queues behind it all the same, and the merges run in op order."""
+    land_form(monkeypatch, form)
+    d, col, flips = armed_drive
+    ga, opa = gated_commit(d, col, "a", "va", gate_open=False)
+    assert ga.calls == 0 and metas(d) == []
+    assert tree(d.root)["bkt/a/d-va/part.1"] == pattern(3000)
+    assert col.tails()
+    gb, opb = gated_commit(d, col, "b", "vb", gate_open=True)
+    assert gb.calls == 0 and metas(d) == []
+    ga.ev.set()
+    col.run_tails()
+    assert (ga.calls, gb.calls) == (1, 1) and not col.tails()
+    assert metas(d) == ["bkt/a/xl.meta.tmp", "bkt/b/xl.meta.tmp"]
+    # each as its op; b, the op that was running, is restored after
+    assert (opa.bound, opb.bound) == (1, 2) and col.current_op is opb
+    assert set(col.tail_s) == {opa, opb} and not opa.errs + opb.errs
+    assert len(col._fds) == 4 and not flips
+    col.flush()
+    assert flips == ["bkt/a/xl.meta", "bkt/b/xl.meta"]
+    assert d.read_version("bkt", "a", "va").data_dir == "d-va"
+    assert d.read_version("bkt", "b", "vb").data_dir == "d-vb"
+
+
+def test_open_gate_and_nothing_owed_merges_in_the_body(armed_drive):
+    d, col, _ = armed_drive
+    g, _ = gated_commit(d, col, "a", "va", gate_open=True)
+    assert g.calls == 1 and not col.tails()
+    assert metas(d) == ["bkt/a/xl.meta.tmp"]
+
+
+def test_two_versions_of_one_object_merge_in_op_order(armed_drive):
+    """The hazard the queueing rule is for: v1 (fresh, gate shut) and v2
+    (not fresh, gate open) of ONE object in one batch.  v2 merging first
+    would be overwritten by v1, which reads no xl.meta."""
+    d, col, flips = armed_drive
+    g1, _ = gated_commit(d, col, "o", "v1", gate_open=False)
+    gated_commit(d, col, "o", "v2", gate_open=True)
+    g1.ev.set()
+    col.flush()                  # a flush runs what is owed first
+    assert flips == ["bkt/o/xl.meta", "bkt/o/xl.meta"]
+    assert sorted(v.version_id for v in d.list_versions("bkt", "o")) \
+        == ["v1", "v2"]
+
+
+@pytest.mark.parametrize("later", ["read_version", "ungated_commit",
+                                   "write_packed", "write_metadata"])
+def test_a_later_op_that_cannot_queue_runs_what_is_owed_first(armed_drive,
+                                                              later):
+    """Per-drive FIFO: any other drive op of the batch (and a
+    write_data_commit without a gate to ask) starts only once the
+    earlier ops' second halves have run."""
+    d, col, flips = armed_drive
+    g, _ = gated_commit(d, col, "a", "va", gate_open=False)
+    g.open_in(0.05)
+    col.current_op = StubOp()
+    if later == "read_version":
+        assert d.read_version("bkt", "a", "va").data_dir == "d-va"
+    elif later == "ungated_commit":
+        d.write_data_commit("bkt", "c", land_fi(vid="vc", ddir="d-vc"),
+                            pattern(100), shard_index=1)
+        assert metas(d) == ["bkt/a/xl.meta.tmp", "bkt/c/xl.meta.tmp"]
+    elif later == "write_packed":
+        d.write_packed("bkt", "p", land_fi(vid="vp", ddir=""),
+                       pattern(100), shard_index=1)
+    else:
+        d.write_metadata("bkt", "a", land_fi(vid="vm", ddir=""))
+    assert g.calls == 1 and not col.tails()
+    col.flush()
+    assert flips[0] == "bkt/a/xl.meta"
+    assert d.read_version("bkt", "a", "va").data_dir == "d-va"
+    if later == "write_metadata":        # merged into, not written over
+        assert sorted(v.version_id for v in d.list_versions("bkt", "a")) \
+            == ["va", "vm"]
+
+
+def test_a_failed_digest_latches_on_its_own_op_only(armed_drive):
+    d, col, flips = armed_drive
+    ga, opa = gated_commit(d, col, "a", "va", gate_open=False, vd=False)
+    gb, opb = gated_commit(d, col, "b", "vb", gate_open=False)
+    ga.ev.set()
+    gb.ev.set()
+    col.run_tails()
+    assert [type(e).__name__ for e in opa.errs] == ["StorageError"]
+    assert not opb.errs
+    col.flush()
+    assert flips == ["bkt/b/xl.meta"]
+    assert metas(d) == ["bkt/b/xl.meta"]      # no version of a, no tmp
+
+
+@pytest.mark.parametrize("scene", ["no_collector", "streamed"])
+def test_gate_parks_in_the_body_where_nothing_can_take_the_merge(
+        tmp_path, scene):
+    """Grouping off (or a peer's RPC op): no collector, the body waits
+    at its gate as before.  A streamed part keeps its own abort path
+    (the part is discarded at once) and waits too."""
+    d = XLStorage(str(tmp_path))
+    d.make_vol("bkt")
+    col = commit.GroupCollector()
+    if scene == "streamed":
+        commit.arm(col)
+    try:
+        fi = land_fi(vid="va", ddir="d-va")
+        gate = Gate(fi.to_dict())
+        gate.open_in(0.05)
+        data = iter([pattern(1000), pattern(2000)]) \
+            if scene == "streamed" else pattern(3000)
+        d.write_data_commit("bkt", "a", fi, data, shard_index=1,
+                            meta_gate=gate)
+        assert gate.calls == 1 and not col.tails()
+        col.flush()
+    finally:
+        commit.disarm()
+    assert d.read_version("bkt", "a", "va").data_dir == "d-va"
+
+
+def test_writer_lands_a_batchs_parts_before_it_waits_for_a_digest(
+        tmp_path, monkeypatch):
+    """One drive's writer thread, one batch of three overlapped PUTs
+    whose digests are all still out: the three parts land first, then
+    the three merges in op order, then ONE flush; a body's wall
+    includes its second half."""
+    from minio_tpu.storage.writers import WriterPlane
+    d = XLStorage(str(tmp_path))
+    d.make_vol("bkt")
+    log = []
+    real_part, real_atomic = commit.land_part, xl_storage._write_file_atomic
+    monkeypatch.setattr(commit, "land_part", lambda obj, *a, **kw: (
+        log.append("part " + os.path.basename(obj)),
+        real_part(obj, *a, **kw))[1])
+    monkeypatch.setattr(xl_storage, "_write_file_atomic", lambda p, *a, **kw: (
+        log.append("meta " + os.path.basename(os.path.dirname(p))),
+        real_atomic(p, *a, **kw))[1])
+    plane = WriterPlane(queue_depth=8)
+    sw = plane.stream([d])
+    hold, parked = threading.Event(), threading.Event()
+    gates = {}
+
+    def commit_op(name):
+        fi = land_fi(vid="v" + name, ddir="d" + name)
+        gates[name] = Gate(fi.to_dict())
+        return lambda idx, disk: disk.write_data_commit(
+            "bkt", name, fi, pattern(3000), shard_index=1,
+            meta_gate=gates[name])
+    b0, batches0 = hist("mt_commit_body_seconds"), \
+        counter("mt_commit_group_batches_total")
+    try:
+        sw.submit(0, lambda i, disk: (parked.set(), hold.wait(20)))
+        assert parked.wait(20)
+        for name in "abc":
+            sw.submit(0, commit_op(name))
+        hold.set()
+        end = time.monotonic() + 20
+        while len(log) < 3 and time.monotonic() < end:
+            time.sleep(0.002)
+        time.sleep(0.05)                 # parked on a's gate by now
+        assert log == ["part a", "part b", "part c"]
+        assert [g.calls for g in gates.values()] == [1, 0, 0]
+        for g in gates.values():
+            g.ev.set()
+        assert sw.drain(20) and sw.errs == [None]
+    finally:
+        hold.set()
+        for g in gates.values():
+            g.ev.set()
+        plane.close()
+    assert log[3:] == ["meta a", "meta b", "meta c"]
+    assert counter("mt_commit_group_batches_total") - batches0 == 1
+    body = hist("mt_commit_body_seconds")
+    assert body[1] - b0[1] == 4              # the hold op and the three
+    assert body[0] - b0[0] >= 0.05           # a's wait is body time
+    for name in "abc":
+        assert d.read_version("bkt", name, "v" + name).data_dir \
+            == "d" + name

@@ -621,3 +621,49 @@ def test_flush_waves_leave_no_thread_behind(tmp_path, monkeypatch, hung):
     finally:
         release.set()
         close_write_planes(layer)
+
+
+LAND_STEPS = {
+    # step -> (call on commit given the scratch dir, the error it raises)
+    "mkdir_obj": (lambda c, d: c.land_part(
+        d + "/gone/o", d + "/gone/o/dd", d + "/gone/o/dd/part.1", b"x"),
+        FileNotFoundError),
+    "mkdir_ddir": (lambda c, d: c.land_part(
+        d + "/o", d + "/o/dd", d + "/o/dd/part.1", b"x"),
+        FileExistsError),
+    "open": (lambda c, d: c.land_file(d + "/o/dd", b"x"),
+             IsADirectoryError),
+    "write": (lambda c, d: c.land_file("/dev/full", b"x" * 70_000),
+              OSError),                      # ENOSPC
+    "sync": (lambda c, d: c.land_file("/dev/null", b"x"),
+             OSError),                       # fsync(/dev/null): EINVAL
+}
+
+
+@pytest.mark.parametrize("form", ["native", "python"])
+@pytest.mark.parametrize("step", sorted(LAND_STEPS))
+def test_landing_leaves_no_fd_open_on_a_failing_step(tmp_path, monkeypatch,
+                                                     step, form):
+    """An op body's landing (storage/commit.py land_part / land_file,
+    native/syncwave.c beside the flush waves) that fails at any of its
+    steps — either mkdir, the open, the write, the fsync — raises that
+    step's OSError and holds no descriptor afterwards, in the native
+    form and in the os.* form alike; a landing that works holds none
+    either (no collector armed: nothing is dup'd)."""
+    from minio_tpu.storage import commit
+    if form == "python":
+        monkeypatch.setattr(commit, "_wave_lib", lambda: None)
+    elif commit._wave_lib() is None:
+        pytest.skip("native/syncwave.c did not build here")
+    d = str(tmp_path)
+    assert commit.land_part(d + "/o", d + "/o/dd", d + "/o/dd/part.1",
+                            b"abc") is True
+    call, exc = LAND_STEPS[step]
+    before = sorted(os.listdir("/proc/self/fd"))
+    for _ in range(3):
+        with pytest.raises(exc):
+            call(commit, d)
+    commit.land_file(d + "/o/f", b"abc")
+    assert sorted(os.listdir("/proc/self/fd")) == before
+    with open(d + "/o/dd/part.1", "rb") as f:
+        assert f.read() == b"abc"

@@ -3,7 +3,8 @@ of the buildscripts/race.sh role (the ASan/UBSan half lives in
 tests/test_sanitizers.py).
 
 The GIL-released C paths (native/gf8.cc matmuls, the framed
-highwayhash verify/fill, snappy, jsonscan) run concurrently in
+highwayhash verify/fill, snappy, jsonscan, native/syncwave.c's flush
+waves and op-body landings) run concurrently in
 production: every drive fan-out and every GET verify can execute them
 from multiple threads at once.  This tier rebuilds them with
 ``-fsanitize=thread`` into a scratch dir and drives them from many
@@ -117,6 +118,50 @@ WORKLOAD = textwrap.dedent("""
                 assert commit.sync_files(fds) == [0] * 40
                 commit.sync_dirs([d] * 9)
 
+    def landing_work():
+        # an op body's landings (commit.land_part / land_file), with and
+        # without a collector, and every failing step they can be made
+        # to take: no descriptor may stay open, no byte be misread
+        import tempfile
+        from minio_tpu.storage import commit
+        assert commit._wave_lib() is not None, "syncwave tsan build failed"
+        body = np.frombuffer(os.urandom(300_000), dtype=np.uint8)
+        with tempfile.TemporaryDirectory() as d:
+            for i in range(10):
+                obj = os.path.join(d, f"o{i}")
+                part = obj + "/dd/part.1"
+                col = commit.GroupCollector() if i % 2 else None
+                if col is not None:
+                    commit.arm(col)
+                try:
+                    assert commit.land_part(obj, obj + "/dd", part, body)
+                    commit.land_file(obj + "/xl.meta.tmp", bytes(body[:600]))
+                    for bad, exc in (
+                            (lambda: commit.land_part(
+                                obj, obj + "/dd", part, body),
+                             FileExistsError),
+                            (lambda: commit.land_part(
+                                d + "/gone/o", d + "/gone/o/dd",
+                                d + "/gone/o/dd/part.1", body),
+                             FileNotFoundError),
+                            (lambda: commit.land_file(obj, body),
+                             IsADirectoryError),
+                            (lambda: commit.land_file("/dev/full", body),
+                             OSError)):
+                        try:
+                            bad()
+                        except exc:
+                            pass
+                        else:
+                            raise AssertionError("a failing step passed")
+                finally:
+                    commit.disarm()
+                if col is not None:
+                    assert len(col._fds) == 2
+                    col.flush()
+                with open(part, "rb") as f:
+                    assert f.read() == body.tobytes()
+
     def run(fn):
         try:
             fn()
@@ -127,6 +172,9 @@ WORKLOAD = textwrap.dedent("""
                for f in (gf8_work, hh_work, snappy_work, jsonscan_work,
                          syncwave_work)
                for _ in range(3)]
+    # one writer thread per drive of a 16-drive set, landing at once
+    threads += [threading.Thread(target=run, args=(landing_work,))
+                for _ in range(16)]
     for t in threads: t.start()
     for t in threads: t.join()
     assert not errors, errors
