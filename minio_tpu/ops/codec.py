@@ -241,13 +241,11 @@ class Erasure:
     def _apply_matrix(self, rows: np.ndarray, shards,
                       op: str = "decode") -> np.ndarray:
         """The serial engine.  ``op`` (``encode`` / ``decode``) names
-        the legs of the one-chip device form (rs_kernels.apply_matrix);
-        the mesh form is one sharded program and has none."""
-        if self.backend == "tpu":
+        the legs of a device form's dispatch (prep / upload / launch /
+        fetch: rs_kernels.apply_matrix on one chip, rs_mesh.apply_matrix
+        for the one sharded program of a mesh)."""
+        if self.is_device:
             return self._impl.apply_matrix(rows, shards, op=op)
-        impl_apply = getattr(self._impl, "apply_matrix", None)
-        if impl_apply is not None:
-            return impl_apply(rows, shards)
         shards = np.asarray(shards, dtype=np.uint8)
         if shards.ndim == 3:
             return np.stack([gf8.gf_matmul(rows, s) for s in shards])
@@ -503,7 +501,9 @@ class Erasure:
 
           * ``mesh`` + HighwayHash256S: the fused multi-chip pipeline —
             parity via ICI XOR fan-in, per-shard digests all_gathered,
-            one sharded dispatch per block batch (rs_mesh);
+            one sharded dispatch per block batch (rs_mesh), counted
+            and timed as ONE ``encode`` dispatch of the body's bytes,
+            like ``encode_object``;
           * ``numpy`` with both native libraries: shard bytes and parity
             land once in the framed layout, digests filled in place by
             one GIL-free pass — into ``out`` when its shape is
@@ -515,9 +515,12 @@ class Erasure:
             whichever device backend asked)."""
         if self.backend == "mesh" and algo == bitrot.HIGHWAYHASH256S:
             from . import rs_mesh
-            return list(rs_mesh.encode_object_framed_fused(
-                self.data_blocks, self.parity_blocks, self.block_size,
-                data))
+            total = _nbytes(data)
+            with self._dispatch("encode", total,
+                                blocks=-(-total // self.block_size)):
+                return list(rs_mesh.encode_object_framed_fused(
+                    self.data_blocks, self.parity_blocks,
+                    self.block_size, data))
         ss = self.shard_size()
         if self._fills_in_place(algo):
             framed2d = self.encode_object_framed(data, out=out)

@@ -78,6 +78,12 @@ def plan(B: int, k: int, ro: int, n: int,
             "tn": tn, "n_pad": n_pad, "pc": tn // 32}
 
 
+def hashed_lanes(p: dict) -> int:
+    """Hash lanes one call of the kernel runs under plan ``p``: every
+    row-block carries S x 128 of them, shard rows or pad."""
+    return p["B_pad"] // p["bs"] * p["S"] * 128
+
+
 def _kernel(m_ref, in_ref, par_ref, dig_ref, st, tbuf, *, k: int,
             ro: int, gs: int, bs: int, S: int, pc: int,
             n_packets: int, hash_parity: bool, init_consts):
@@ -165,7 +171,7 @@ def _kernel(m_ref, in_ref, par_ref, dig_ref, st, tbuf, *, k: int,
             dig_ref[0, idx] = st[idx]
 
 
-@functools.partial(jax.jit, static_argnames=(
+@device.named_jit("mt_rs_fused", static_argnames=(
     "k", "ro", "gs", "bs", "S", "pc", "n_packets", "hash_parity"))
 def _fused_call(mat_bd, data, *, k: int, ro: int, gs: int, bs: int,
                 S: int, pc: int, n_packets: int, hash_parity: bool):
@@ -196,6 +202,7 @@ def _fused_call(mat_bd, data, *, k: int, ro: int, gs: int, bs: int,
         scratch_shapes=[pltpu.VMEM((32, S, 128), _U32),
                         pltpu.VMEM((tn, S, 128), jnp.uint8)],
         interpret=device.interpret(),
+        name="mt_rs_fused",
     )(mat_bd, data)
 
 

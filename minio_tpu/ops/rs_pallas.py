@@ -129,6 +129,18 @@ def _device_matrix_bd(key: bytes, rows: int, cols: int,
     return jnp.asarray(bd)
 
 
+def lane_tile(n: int) -> int:
+    """Lane tile for shards of n bytes: bucketed to ~n/4 so padding
+    waste stays under ~25% at every shard width (a 5462-byte shard must
+    not pad 50% to 8192, nor a 300-byte one 13x to 4096), capped at _TN
+    for real widths."""
+    q = max(n // 4, 1)
+    tn = _LANES
+    while tn * 2 <= q and tn < _TN:
+        tn *= 2
+    return tn
+
+
 def apply_matrix(M: np.ndarray, shards) -> jax.Array:
     """out[b] = M (GF) @ shards[b], fused pallas path.
 
@@ -145,13 +157,7 @@ def apply_matrix(M: np.ndarray, shards) -> jax.Array:
         shards = jnp.pad(shards, ((0, bpad), (0, 0), (0, 0)))
     gs = _GS if shards.shape[0] % _GS == 0 else 1
     mb = _device_matrix_bd(M.tobytes(), M.shape[0], M.shape[1], gs)
-    # bucket the lane tile to ~n/4 so padding waste stays under ~25%
-    # at every shard width (a 5462-byte shard must not pad 50% to 8192,
-    # nor a 300-byte one 13x to 4096), capped at _TN for real widths
-    q = max(n // 4, 1)
-    tn = _LANES
-    while tn * 2 <= q and tn < _TN:
-        tn *= 2
+    tn = lane_tile(n)
     pad = (-n) % tn
     if pad:
         shards = jnp.pad(shards, ((0, 0), (0, 0), (0, pad)))
