@@ -14,7 +14,7 @@ pkg/trace.Type):
   ``internode``  RPC client/server spans (parallel/rpc.py)
   ``tpu``        the device codec path, ``<op>.<leg>``: whole
                  dispatches (``encode.dispatch``, ``hash.dispatch``,
-                 ``encode.batch`` ...) with shard geometry and bytes,
+                 ``encode-bitrot.batch`` ...) with shard geometry and bytes,
                  and their legs (prep/upload/launch/fetch/frame), all
                  through :class:`span` (ops/codec.py + friends)
   ``scanner``    data-crawler per-bucket spans (background/crawler.py)

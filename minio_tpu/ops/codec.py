@@ -22,9 +22,15 @@ The object layer reaches the kernels through two methods and nothing
 else: ``Erasure.encode_framed`` (a batch of body bytes -> the k+m
 bitrot-framed shard rows; which route encodes and frames is decided
 there) and ``Erasure.reconstruct_files`` (k surviving shard files ->
-the wanted ones, degraded GET and heal).  The device bitrot leg
-(``_streaming_encode_batch_device``) lives here, next to the kernels
-it drives.
+the wanted ones, degraded GET and heal).  On a device backend a PUT's
+full blocks cross the link once: the stripes go up, parity and the k+m
+bitrot digests come down from ONE fused program per stripe that rides
+the combiner (ops/rs_fused.py on one chip, ops/rs_mesh.py over a
+mesh), and the rows are framed on the host through views.  On one chip
+a tail block (every object under a block) keeps the two-dispatch route:
+parity through ``rs_kernels``, then the device bitrot leg
+(``_streaming_encode_batch_device``, here next to the kernels it
+drives); ``Erasure.encode_framed`` says why.
 """
 
 from __future__ import annotations
@@ -504,6 +510,13 @@ class Erasure:
             one sharded dispatch per block batch (rs_mesh), counted
             and timed as ONE ``encode`` dispatch of the body's bytes,
             like ``encode_object``;
+          * ``tpu`` + HighwayHash256S, a body of a block or more
+            (:meth:`_encode_framed_chip`): the same contract on one
+            chip for the full blocks — the stripes up, parity and the
+            k+m digests down from ONE fused program per stripe
+            (ops/rs_fused.py), framed on the host through views — and
+            the route below for a tail block; counted and timed as ONE
+            ``encode`` dispatch of the body's bytes too;
           * ``numpy`` with both native libraries: shard bytes and parity
             land once in the framed layout, digests filled in place by
             one GIL-free pass — into ``out`` when its shape is
@@ -512,12 +525,26 @@ class Erasure:
             framing, with the digests from the device too when the
             codec runs there (op ``hash``: counted and timed like a
             codec dispatch; its kernels are the one-chip forms
-            whichever device backend asked)."""
-        if self.backend == "mesh" and algo == bitrot.HIGHWAYHASH256S:
-            from . import rs_mesh
-            total = _nbytes(data)
+            whichever device backend asked).
+
+        Why a tail keeps two dispatches on one chip: a width is a
+        program, and the fused program's build holds the interpreter
+        several times longer than the two small programs it replaces
+        (the kernel's body is ~10,000 traced operations).  A server
+        meets ONE full-block width (``shard_size()``), which every
+        object of a block or more is made of, and as many tail widths
+        as its clients have object sizes: seven at once cost 17-23 s of
+        a 50 s set-up (PERF.md section 6, PR 34 and PR 35).  The shape
+        decides, nothing else."""
+        device_hash = self.is_device and algo == bitrot.HIGHWAYHASH256S
+        total = _nbytes(data)
+        if device_hash and (self.backend == "mesh"
+                            or total >= self.block_size):
             with self._dispatch("encode", total,
                                 blocks=-(-total // self.block_size)):
+                if self.backend == "tpu":
+                    return list(self._encode_framed_chip(data))
+                from . import rs_mesh
                 return list(rs_mesh.encode_object_framed_fused(
                     self.data_blocks, self.parity_blocks,
                     self.block_size, data))
@@ -528,12 +555,82 @@ class Erasure:
                 return list(framed2d)
         shards = self.encode_object(data)
         if self.is_device and bitrot.is_streaming(algo):
-            with dispatch_span(
-                    "hash", "tpu", sum(_nbytes(s) for s in shards),
-                    lambda: {"op": "hash", "shards": len(shards),
-                             "shardSize": ss}):
-                return _streaming_encode_batch_device(shards, ss)
+            return self._device_framed(shards)
         return bitrot.streaming_encode_batch(shards, ss, algo)
+
+    def _device_framed(self, shards) -> list[bytes]:
+        """The device bitrot leg over k+m equal shard files, as ONE
+        ``hash`` dispatch counted and timed like a codec dispatch."""
+        ss = self.shard_size()
+        with dispatch_span(
+                "hash", "tpu", sum(_nbytes(s) for s in shards),
+                lambda: {"op": "hash", "shards": len(shards),
+                         "shardSize": ss}):
+            return _streaming_encode_batch_device(shards, ss)
+
+    def _encode_bitrot(self, staged: np.ndarray):
+        """(parity per stripe, digests) of staged full-block stripes
+        (``rs_fused.launch_encode_bitrot``'s contract), shared with
+        concurrent PUTs through the combiner's ``encode-bitrot`` bucket
+        when the batcher is on (stripes are batch-axis independent, so
+        each caller's slice is what it would get alone)."""
+        from . import rs_fused
+        rows = np.asarray(self.matrix)[self.data_blocks:]
+        n = self.shard_size()
+        b = _batcher(self)
+        if b is None:
+            return rs_fused.launch_encode_bitrot(rows, staged, n)()
+        return b.submit(
+            self, "encode-bitrot", rows, staged,
+            fn=lambda rows, cat: rs_fused.launch_encode_bitrot(
+                rows, cat, n)())
+
+    def _encode_framed_chip(self, data, digest: int = 32) -> np.ndarray:
+        """The one-chip device route of :meth:`encode_framed` for a body
+        of a block or more: (k+m, framed_len) uint8, bit-identical to
+        the host streaming-bitrot layout.  All full blocks go in one
+        fused submission.  ``encode.prep`` is the one copy of their
+        bytes into staged stripes (row i of a stripe holds bytes
+        [i*width, (i+1)*width) of its block, zeros after the block's
+        end and up to the kernel's lane tile: no program pads); the
+        dispatch's own legs are rs_fused's; ``hash.frame`` lands
+        payloads and digests in the on-disk layout through views, one
+        copy each.  A tail block takes the two-dispatch route
+        (``encode_data``, then the device bitrot leg) and lands behind
+        them."""
+        from . import rs_fused
+        k, m = self.data_blocks, self.parity_blocks
+        bs, width = self.block_size, self.shard_size()
+        buf = np.frombuffer(data, dtype=np.uint8) \
+            if not isinstance(data, np.ndarray) \
+            else np.asarray(data, np.uint8).ravel()
+        nfull, tail_len, _, flen = gf8.framed_layout(bs, k, buf.size,
+                                                     digest)
+        with _obstrace.span("tpu", "encode.prep", nbytes=nfull * bs):
+            staged = np.zeros(
+                (nfull, k, rs_fused.staged_width(k, m, width)), np.uint8)
+            src = buf[:nfull * bs].reshape(nfull, bs)
+            whole, rest = divmod(bs, width)
+            staged[:, :whole, :width] = \
+                src[:, :whole * width].reshape(nfull, whole, width)
+            if rest:
+                staged[:, whole, :rest] = src[:, whole * width:]
+        parity, digs = self._encode_bitrot(staged)
+        tail = self._device_framed(self.encode_data(buf[nfull * bs:])) \
+            if tail_len else None
+        F = digest + width
+        with _obstrace.span("tpu", "hash.frame", nbytes=(k + m) * flen):
+            out = np.empty((k + m, flen), dtype=np.uint8)
+            frames = out[:, :nfull * F].reshape(k + m, nfull, F)
+            frames[:k, :, digest:] = \
+                staged[:, :, :width].transpose(1, 0, 2)
+            for b, par in enumerate(parity):
+                frames[k:, b, digest:] = par[:, :width]
+            frames[:, :, :digest] = digs.transpose(1, 0, 2)
+            if tail:
+                for row, framed in zip(out, tail):
+                    row[nfull * F:] = np.frombuffer(framed, np.uint8)
+        return out
 
     def reconstruct_files(self, surviving, present, wanted,
                           part_size: int,

@@ -144,14 +144,49 @@ def interpret() -> bool:
     return not (_AOT_TPU or platform() == "tpu")
 
 
+# the programs ``named_jit`` made: ``compile_stats()`` reports what was
+# built of each
+_NAMED: set = set()
+
+
 def named_jit(name: str, **jit_kw):
     """``jax.jit`` under a stable program name: the trace's ``XLA
     Modules`` line and ``compile_stats()["by_function"]`` show
     ``jit_<name>`` / ``<name>`` whatever the Python function is called,
-    so a reducer finds a kernel after its callers are restructured."""
+    so a reducer finds a kernel after its callers are restructured.
+
+    A program is built once per key (static arguments, operand shapes
+    and dtypes) and process: ``jax.jit`` itself parks concurrent first
+    callers of one signature on the first one's build (trace, lowering,
+    compile or cache retrieval) with the interpreter released, and a
+    build that raises sends its waiters on to build for themselves.
+    ``compile_stats()`` counts the keys built of each program
+    (``builds``).  Nothing stands between a caller and the jitted
+    function: a wrapper in front of it, whether it parked the waiters
+    on an event of its own, traced in turn under one lock or only
+    counted, read ``n16.small-zipf``'s preload 6-9 s longer in sixteen
+    runs (PERF.md section 6, PR 35)."""
     def wrap(fn):
         fn.__name__ = fn.__qualname__ = name
+        _NAMED.add(name)
         return jax.jit(fn, **jit_kw)
+    return wrap
+
+
+def once_cache(maxsize: int):
+    """``functools.lru_cache`` for a factory of programs: concurrent
+    first callers of a key get ONE object (the cache alone runs the
+    factory in every thread that misses, and each product would trace
+    and compile for itself)."""
+    def wrap(factory):
+        cached = functools.lru_cache(maxsize=maxsize)(factory)
+        mu = threading.Lock()
+
+        @functools.wraps(factory)
+        def get(*key):
+            with mu:
+                return cached(*key)
+        return get
     return wrap
 
 
@@ -218,9 +253,14 @@ jax.monitoring.register_event_listener(_on_event)
 def compile_stats() -> dict:
     with _mu:
         out = {k: round(v, 3) for k, v in _stats.items()}
+        # a named program's key compiles once (a cache retrieval is a
+        # compile request too), so its requests are the keys built
         out["by_function"] = {
-            name: {"compiles": n, "seconds": round(secs, 3)}
+            name: {"compiles": n, "seconds": round(secs, 3),
+                   "builds": n if name in _NAMED else 0}
             for name, (n, secs) in _by_function.items()}
+        out["builds"] = sum(row["builds"]
+                            for row in out["by_function"].values())
         return out
 
 

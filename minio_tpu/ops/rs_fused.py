@@ -32,6 +32,16 @@ full-parity hash then runs post-ring on the small parity rows.
 Digests are bit-identical to the host HighwayHash-256 with the bitrot
 magic key (tests/test_fused_kernel.py pins ragged geometries, tails
 and the k/m matrix from the BASELINE configs).
+
+Two callers.  On one chip this is the route of a PUT's full blocks
+(``launch_encode_bitrot``, called by ``Erasure.encode_framed`` on
+``--backend tpu``): the kernel, the plane reassembly, the remainder
+packet and finalization are ONE compiled program per block width
+(``jit_mt_encode_bitrot``), the host stages the stripes at the plan's
+lane tile so nothing is padded on the device, and a batch goes out one
+stripe per dispatch.  Over a mesh ops/rs_mesh.py calls the kernel
+inside its sharded program.  Off a TPU the same contract runs in the
+XLA forms (``_encode_bitrot_xla``), so tier-1 drives the route.
 """
 
 from __future__ import annotations
@@ -44,7 +54,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import device, gf8, hh_pallas as hhp, hh_kernels as hk, rs_pallas
+from ..admin.metrics import GLOBAL as _metrics
+from ..obs import trace as _trace
+from . import (device, gf8, hh_pallas as hhp, hh_kernels as hk, rs_kernels,
+               rs_pallas)
 
 _U32 = jnp.uint32
 # lane-tile ceiling: 2048 bytes = 64 packets per chunk, the same
@@ -241,6 +254,100 @@ def _digests_from_planes(planes, data, parity, *, k: int, ro: int,
               jnp.concatenate(tails, axis=1)).reshape(B * R, rem)
         state8 = hk._remainder_update(state8, rb, rem)
     return hhp._finalize(state8).reshape(B, R, 32)
+
+
+@device.named_jit("mt_encode_bitrot", static_argnames=("gs", "n_real"))
+def _encode_bitrot(mat_bd, shards, *, gs: int, n_real: int):
+    """One chip's whole PUT program: the kernel, the plane reassembly,
+    the sub-packet remainder and finalization traced together, ONE
+    compiled program per operand shape and one dispatch per call (op by
+    op the jnp around the kernel is a chain of one-op programs).
+    ``shards`` is staged at the plan's sizes (``staged_width``), so
+    nothing is padded or sliced on the device.  Returns (parity,
+    digests of the k data then the ro parity rows over ``n_real``
+    bytes each), both flattened: a 1-D array crosses the link at the
+    link's rate, the kernel's own (B, ro, n) uint8 tiles its few rows
+    to 32 sublanes and comes down several times slower than its bytes
+    (my chip runs, PR 34: (2, 2, 5 MiB) in 57 ms, flat in 6.3)."""
+    B, k, _ = shards.shape
+    ro = mat_bd.shape[0] // (8 * gs)
+    p = plan(B, k, ro, n_real)
+    parity, planes = _fused_call(
+        mat_bd, shards, k=k, ro=ro, gs=gs, bs=p["bs"], S=p["S"],
+        pc=p["pc"], n_packets=n_real // 32, hash_parity=True)
+    digests = _digests_from_planes(
+        planes, shards, parity, k=k, ro=ro, bs=p["bs"], S=p["S"], B=B,
+        n_real=n_real, hash_parity=True)
+    return parity.reshape(-1), digests.reshape(-1)
+
+
+@device.named_jit("mt_encode_bitrot")
+def _encode_bitrot_xla(matrix_bits, shards):
+    """``_encode_bitrot``'s contract in the XLA formulations (the
+    bitplane matmul of rs_kernels, the lax.scan HighwayHash of
+    hh_kernels: what ``mesh._fused_encode_hash`` runs per device), one
+    program under the same name: the form off a TPU."""
+    n = shards.shape[2]
+    parity = rs_kernels._gf2_apply(matrix_bits, shards)
+    digests = hk.hh256_batch(
+        jnp.concatenate([shards, parity], axis=1).reshape(-1, n))
+    return parity.reshape(-1), digests.reshape(-1)
+
+
+def staged_width(k: int, ro: int, n: int) -> int:
+    """The width a caller stages (B, k, n) stripes at for
+    :func:`launch_encode_bitrot`, zero-tailed: the kernel's lane tile
+    on a TPU, n itself for the XLA form."""
+    return plan(1, k, ro, n)["n_pad"] if device.use_pallas() else n
+
+
+def launch_encode_bitrot(M: np.ndarray, staged: np.ndarray, n: int):
+    """The one-chip PUT dispatch: (B, k, staged_width) stripes of real
+    width ``n`` go up (``encode.upload``), ONE program per stripe is
+    launched (``encode.launch``, until its handles are held), and the
+    hash lanes it runs are counted against the k+ro digests per stripe
+    it is asked for.  Returns the call that lands the results
+    (``encode.fetch``): (parity, one (ro, staged width) array per
+    stripe; digests (B, k+ro, 32)) on the host.
+
+    One stripe per dispatch whatever batch the combiner formed: a
+    program's row-block is static, so every batch size would be a
+    program of its own, first met and compiled mid-traffic with its
+    callers parked behind it (PERF.md section 6, PR 33).  One program
+    per shard width serves any batch; all of a batch's stripes are up
+    and launched before any result is fetched."""
+    M = np.ascontiguousarray(M, dtype=np.uint8)
+    ro, k = M.shape
+    B, _, width = staged.shape
+    if device.use_pallas():
+        p = plan(1, k, ro, n)
+        call = functools.partial(
+            _encode_bitrot,
+            rs_pallas._device_matrix_bd(M.tobytes(), ro, k, p["gs"]),
+            gs=p["gs"], n_real=n)
+        hashed = hashed_lanes(p)
+    else:
+        call = functools.partial(_encode_bitrot_xla,
+                                 rs_kernels._put_matrix(M))
+        hashed = hk.hashed_rows(k + ro, n)
+    handles = []
+    for b in range(B):
+        dev = device.upload("encode", staged[b:b + 1])
+        with _trace.span("tpu", "encode.launch", nbytes=dev.nbytes):
+            handles.append(call(dev))
+    _metrics.inc("mt_tpu_hash_rows_total", {"kind": "real"},
+                 float(B * (k + ro)))
+    _metrics.inc("mt_tpu_hash_rows_total", {"kind": "hashed"},
+                 float(B * hashed))
+
+    def land():
+        parity = [device.fetch("encode", par).reshape(ro, width)
+                  for par, _ in handles]
+        digests = np.stack([device.fetch("encode", dig)
+                            for _, dig in handles])
+        return parity, digests.reshape(B, k + ro, 32)
+
+    return land
 
 
 def encode_hash_device(M: np.ndarray, shards, *, n_real: int | None

@@ -76,7 +76,7 @@ def _ring_xor(part, S: int):
     return jax.lax.fori_loop(0, S - 1, step, part)
 
 
-@functools.lru_cache(maxsize=64)
+@device.once_cache(64)
 def _sharded_apply_pallas(mesh, r: int, kl: int, gs: int, tn: int):
     """shard_map'd per-device pallas matmul + packed-byte ring XOR.
 
@@ -236,7 +236,7 @@ def _ring_digests(d_dig, parity, n_real: int, S: int):
     return jnp.concatenate([d_dig, p_dig], axis=1)
 
 
-@functools.lru_cache(maxsize=64)
+@device.once_cache(64)
 def _fused_pallas_single(mesh, r: int, kl: int, gs: int, bs: int,
                          S_h: int, pc: int, n_real: int, hp: bool):
     """Fused encode+bitrot through the SINGLE-kernel formulation
@@ -265,7 +265,7 @@ def _fused_pallas_single(mesh, r: int, kl: int, gs: int, bs: int,
                             **_FUSED_SPECS)
 
 
-@functools.lru_cache(maxsize=64)
+@device.once_cache(64)
 def _fused_pallas(mesh, r: int, kl: int, gs: int, tn: int,
                   n_real: int):
     """Fused encode+bitrot, pallas per-chip form: local pallas matmul

@@ -459,7 +459,8 @@ class CodecBatcher:
     @staticmethod
     def _slice(out, off: int, n: int):
         """Per-waiter view of a batch result (array or tuple of
-        batch-axis arrays, e.g. the fused path's (parity, digests))."""
+        batch-axis sequences, e.g. the fused path's (parity, digests);
+        the one-chip form's parity is a list of per-stripe arrays)."""
         if isinstance(out, tuple):
             return tuple(o[off:off + n] for o in out)
         return out[off:off + n]

@@ -94,10 +94,17 @@ def test_encode_framed_bit_identical(codec_for, backend, bs, total,
         assert all(np.shares_memory(r, out) for r in rows)
 
 
-def test_encode_framed_routes_are_counted_apart(codec_for):
+@pytest.mark.parametrize("total,hashes", [
+    (2 * BS + 777, 1),              # blocks fused, the tail's bitrot leg
+    (2 * BS, 0),                    # whole blocks: the fused route alone
+    (777, 1),                       # under a block: the two-dispatch route
+], ids=["blocks+tail", "whole-blocks", "sub-block"])
+def test_encode_framed_routes_are_counted_apart(codec_for, total, hashes):
     """What the benchmark reads: one ``op=encode`` dispatch per batch
-    on the device route with the body's bytes, ``op=hash`` beside it;
-    the host one-copy route counts ``encode-framed`` and no hash."""
+    on the device route with the body's bytes; ``op=hash`` beside it
+    only where a tail block takes the device bitrot leg (full blocks
+    get their digests off the encode's own fused dispatch); the host
+    one-copy route counts ``encode-framed`` and no hash."""
     def ops(backend):
         return {op: _metrics.GLOBAL.snapshot().get(
             ("mt_tpu_ops_total", (("backend", backend), ("op", op))), 0)
@@ -107,13 +114,13 @@ def test_encode_framed_routes_are_counted_apart(codec_for):
         return _metrics.GLOBAL.snapshot().get(
             ("mt_tpu_bytes_total", (("backend", backend), ("op", op))), 0)
 
-    data = _body(2 * BS + 777, 3)
+    data = _body(total, 3)
     tpu = codec_for("tpu", BS)
     before, b0 = ops("tpu"), nbytes("tpu", "encode")
     tpu.encode_framed(data, bitrot.HIGHWAYHASH256S)
     after = ops("tpu")
     assert {op: after[op] - before[op] for op in after} == \
-        {"encode": 1, "encode-framed": 0, "hash": 1}
+        {"encode": 1, "encode-framed": 0, "hash": hashes}
     assert nbytes("tpu", "encode") - b0 == len(data)
     host = codec_for("numpy", BS)
     before = ops("numpy")

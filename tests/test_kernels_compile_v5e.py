@@ -83,6 +83,17 @@ def test_single_chip_kernels_compile(topo, bs):
         pc=p["pc"], n_packets=ss // 32, hash_parity=True),
         spec((p["gs"] * 8 * M_PAR, p["gs"] * 8 * K), jnp.int8),
         spec((p["B_pad"], K, p["n_pad"]), jnp.uint8))
+    # the one-chip PUT route's program: kernel + plane reassembly +
+    # remainder + finalize, results flat, one stripe per dispatch (the
+    # operand arrives staged at the lane tile, nothing pads); also at a
+    # width under one 32-byte packet (no packet loop to run)
+    for n in (ss, 9):
+        p = rs_fused.plan(1, K, M_PAR, n)
+        assert p["B_pad"] == 1
+        rs_fused._encode_bitrot.lower(
+            spec((p["gs"] * 8 * M_PAR, p["gs"] * 8 * K), jnp.int8),
+            spec((1, K, p["n_pad"]), jnp.uint8),
+            gs=p["gs"], n_real=n).compile()
 
 
 @pytest.mark.parametrize("bs", BLOCK_SIZES)
@@ -117,3 +128,4 @@ def test_mesh_1x4_forms_compile(topo, bs):
         mesh, M_PAR, kl, p["gs"], p["bs"], p["S"], p["pc"], ss,
         False).lower(
         mats(p["gs"]), data(p["B_pad"], p["n_pad"])).compile()
+

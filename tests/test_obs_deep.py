@@ -533,8 +533,12 @@ def test_device_put_counts_legs_and_link_bytes(tmp_path):
     """A device-codec PUT of a known size and geometry moves
     mt_tpu_leg_seconds and mt_tpu_link_bytes_total by exactly what the
     code dispatches: 2+2, 64 KiB blocks, 200,000 B = 3 full blocks and
-    a 3,392 B tail, so the RS and the hash legs each run twice (full
-    blocks, tail).  Link bytes are array nbytes, padding included."""
+    a 3,392 B tail.  The full blocks cross the link once: ONE fused
+    encode+bitrot submission that goes out as three single-stripe
+    dispatches.  The tail block keeps the two-dispatch route: its RS
+    dispatch, then the device bitrot leg.  Link bytes are array nbytes,
+    padding included (the XLA forms stage a fused stripe at its exact
+    width)."""
     disks = []
     for i in range(4):
         d = tmp_path / f"d{i}"
@@ -548,34 +552,41 @@ def test_device_put_counts_legs_and_link_bytes(tmp_path):
     legs = {k: v - legs0.get(k, 0) for k, v in _leg_counts().items()}
     ctr = {k: v - ctr0.get(k, 0) for k, v in _tpu_counters().items()}
 
-    for op in ("encode", "hash"):
-        for leg in ("upload", "launch", "fetch"):
-            assert legs[(op, leg)] == 2, (op, leg, legs)
-        assert legs[(op, "dispatch")] == 1, legs
-        assert legs[(op, "prep")] >= 1, legs
-    assert legs[("hash", "frame")] == 1, legs
-    assert legs[("encode", "batch")] == 2, legs
+    # fused: per stripe one upload, one launch, two fetches (parity,
+    # digests); the tail's RS dispatch: one of each
+    assert legs[("encode", "upload")] == 3 + 1, legs
+    assert legs[("encode", "launch")] == 3 + 1, legs
+    assert legs[("encode", "fetch")] == 6 + 1, legs
+    assert legs[("encode", "dispatch")] == 1, legs
+    # the staged full blocks; the tail's split and its lane pad
+    assert legs[("encode", "prep")] == 3, legs
+    assert legs[("encode-bitrot", "batch")] == 1, legs
+    assert legs[("encode", "batch")] == 1, legs
+    # the tail's device bitrot leg, and the two framings
+    for leg in ("dispatch", "prep", "upload", "launch", "fetch"):
+        assert legs[("hash", leg)] == 1, (leg, legs)
+    assert legs[("hash", "frame")] == 2, legs
 
     k, m, shard, tail_shard = 2, 2, 32768, 1696    # ceil(3392 / 2)
     lanes = -(-tail_shard // 128) * 128            # lane pad: 1792
     link = {(op, d): ctr[("mt_tpu_link_bytes_total", op, d)]
             for op in ("encode", "hash") for d in ("h2d", "d2h")}
-    # RS: 3 blocks padded to a batch of 4 go up, 3 come down; the tail
-    # stripe goes up and comes down at its lane-padded width
-    assert link[("encode", "h2d")] == 4 * k * shard + k * lanes
-    assert link[("encode", "d2h")] == 3 * m * shard + m * lanes
-    # hash: every shard's bytes go up again, data and parity, unpadded;
-    # 32 B per block and shard come down
-    assert link[("hash", "h2d")] == (k + m) * (3 * shard + tail_shard)
-    assert link[("hash", "d2h")] == (k + m) * 4 * 32
-    # the device bitrot leg now counts like a codec dispatch
+    # full blocks: data up once, parity and 32 B per shard down; the
+    # tail stripe goes up and comes down at its lane-padded width
+    assert link[("encode", "h2d")] == 3 * k * shard + k * lanes
+    assert link[("encode", "d2h")] == \
+        3 * (m * shard + (k + m) * 32) + m * lanes
+    # the tail's shards go up again, data and parity, unpadded; 32 B
+    # per shard come down
+    assert link[("hash", "h2d")] == (k + m) * tail_shard
+    assert link[("hash", "d2h")] == (k + m) * 32
     assert ctr[("mt_tpu_ops_total", "hash", "tpu")] == 1
     assert ctr[("mt_tpu_bytes_total", "hash", "tpu")] == \
-        (k + m) * (3 * shard + tail_shard)
+        (k + m) * tail_shard
     assert ctr[("mt_tpu_ops_total", "encode", "tpu")] == 1
     assert ctr[("mt_tpu_bytes_total", "encode", "tpu")] == 200_000
-    # rows per hash dispatch: 4 shards x 3 full blocks, then the 4 tail
-    # rows; the XLA form hashes what it is handed
+    # rows: 4 shards per fused stripe, then the 4 tail rows; the XLA
+    # forms hash what they are handed
     for kind in ("real", "hashed"):
         assert ctr[("mt_tpu_hash_rows_total", None, kind)] == 16
     # and what it wrote reads back
