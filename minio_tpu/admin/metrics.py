@@ -56,16 +56,25 @@ class Metrics:
     def observe(self, name: str, labels: dict[str, str] | None = None,
                 value: float = 0.0,
                 buckets: tuple = TTFB_BUCKETS) -> None:
+        self.observe_many(name, labels, (value,), buckets)
+
+    def observe_many(self, name: str, labels: dict[str, str] | None,
+                     values, buckets: tuple = TTFB_BUCKETS) -> None:
+        """:meth:`observe` for every value of ``values`` under ONE lock
+        acquisition: a fan-out folds its children's times into a family
+        with one registry call, not one per child."""
         key = (name, tuple(sorted((labels or {}).items())), buckets)
+        nb = len(buckets)
         with self._mu:
             h = self._hists.get(key)
             if h is None:
-                h = self._hists[key] = [0] * (len(buckets) + 1) + [0.0]
-            for i, ub in enumerate(buckets):
-                if value <= ub:
-                    h[i] += 1
-            h[len(buckets)] += 1          # +Inf / _count
-            h[-1] += value                # _sum
+                h = self._hists[key] = [0] * (nb + 1) + [0.0]
+            for value in values:
+                for i, ub in enumerate(buckets):
+                    if value <= ub:
+                        h[i] += 1
+                h[nb] += 1                # +Inf / _count
+                h[-1] += value            # _sum
 
     def snapshot(self) -> dict[tuple, float]:
         with self._mu:

@@ -600,6 +600,7 @@ class ObsDocsDriftRule(Rule):
                    "``STAGE_NAMES`` catalog), every watchdog rule "
                    "name (the ``RULE_NAMES`` catalog), and every "
                    "``mt_{s3_stage,forensic,flight,quorum,drive_op,"
+                   "drive_call,read_leg,tpu_leg,"
                    "trace_tree,alert,history,bucket,tenant,metering,"
                    "commit}"
                    "_*`` metric family "
@@ -608,7 +609,8 @@ class ObsDocsDriftRule(Rule):
                    "must be able to trust it is complete")
 
     _FAMILY_RE = re.compile(
-        r"^mt_(?:s3_stage|forensic|flight|quorum|drive_op|trace_tree"
+        r"^mt_(?:s3_stage|forensic|flight|quorum|drive_op|drive_call"
+        r"|read_leg|tpu_leg|trace_tree"
         r"|alert|history|bucket|tenant|metering|commit)_\w+$")
 
     def check_tree(self, mods: list[Module], repo: str):

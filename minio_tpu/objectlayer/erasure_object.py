@@ -30,8 +30,11 @@ from typing import Optional
 
 import numpy as np
 
+from ..admin.metrics import GLOBAL as _metrics
+from ..admin.metrics import KERNEL_BUCKETS
 from ..hashing import bitrot, md5fast
 from ..obs import critpath as _critpath
+from ..obs import stages as _stages
 from ..obs import trace as _trace
 from ..ops import gf8
 from ..ops.codec import Erasure
@@ -294,7 +297,7 @@ class ErasureObjects(MultipartOps, ObjectLayer):
 
     # -- drive fan-out helpers --------------------------------------------
 
-    def _fanout_items(self, fn, items, ends=None):
+    def _fanout_items(self, fn, items, ends=None, plane=None):
         """Run fn(item) concurrently over arbitrary items; returns
         (results, errs) aligned with items (parallelWriter/Reader
         analog, cmd/erasure-encode.go:36).  On a single-core host the
@@ -304,7 +307,16 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         ``ends`` (optional, pre-sized to ``len(items)``): each child's
         completion time in monotonic ns lands at its item position —
         the completion vector the quorum critical-path engine
-        (obs/critpath.py) reduces."""
+        (obs/critpath.py) reduces.
+
+        ``plane`` (optional, ``meta`` / ``get`` / ``delete``): the
+        runner also takes each child's START, and after the gather the
+        CALLING thread folds the children into the read-leg family
+        under ``op=<plane>``: ``leg="queue"`` = start_i - submit per
+        child (its wait for a pool thread and for the GIL to start),
+        ``leg="gather"`` = caller resumed - last end.  One registry
+        call per leg and fan-out; the drive call between a child's
+        start and end is in ``mt_drive_call_seconds``."""
 
         def run(x):
             try:
@@ -312,19 +324,35 @@ class ErasureObjects(MultipartOps, ObjectLayer):
             except Exception as e:  # noqa: BLE001 — per-item isolation
                 return None, e
 
+        starts = [0] * len(items) if plane is not None and items else None
+        if starts is not None and ends is None:
+            ends = [0] * len(items)
         if ends is None:
             runner, seq = run, items
         else:
             def runner(pair):
+                if starts is not None:
+                    starts[pair[0]] = time.monotonic_ns()
                 out = run(pair[1])
                 ends[pair[0]] = time.monotonic_ns()
                 return out
             seq = list(enumerate(items))
+        submit = time.monotonic_ns()
         if self._serial_fanout:
             out = [runner(x) for x in seq]
         else:
             out = list(self._pool.map(self._with_request_id(runner),
                                       seq))
+        if starts is not None:
+            resumed = time.monotonic_ns()
+            family = _trace.LEG_FAMILIES["read"]
+            _metrics.observe_many(
+                family, {"op": plane, "leg": "queue"},
+                [(s0 - submit) / 1e9 for s0 in starts],
+                buckets=KERNEL_BUCKETS)
+            _metrics.observe(
+                family, {"op": plane, "leg": "gather"},
+                (resumed - max(ends)) / 1e9, buckets=KERNEL_BUCKETS)
         return [r for r, _ in out], [e for _, e in out]
 
     @staticmethod
@@ -337,7 +365,6 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         detail never lands on the wrong request, and drive-op spans
         parent under the submitting span in the request's tree (the
         span-discipline lint pins this shape)."""
-        from ..obs import stages as _stages
         rid = _trace.get_request_id()
         parent = _trace.get_span_parent()
         clock = _stages.current()
@@ -350,9 +377,10 @@ class ErasureObjects(MultipartOps, ObjectLayer):
 
         return run_ctx
 
-    def _fanout(self, fn, disks=None, ends=None):
+    def _fanout(self, fn, disks=None, ends=None, plane=None):
         """fn(disk) on every drive concurrently; offline (None) drives
-        report DiskNotFound in the aligned error list."""
+        report DiskNotFound in the aligned error list.  ``ends`` and
+        ``plane`` as in :meth:`_fanout_items`."""
 
         def run(d):
             if d is None:
@@ -361,7 +389,7 @@ class ErasureObjects(MultipartOps, ObjectLayer):
 
         return self._fanout_items(run,
                                   self.disks if disks is None else disks,
-                                  ends=ends)
+                                  ends=ends, plane=plane)
 
     def _fanout_indexed(self, fn, shuffled_disks, ends=None):
         """fn((shard_idx, disk)) per drive, aligned errors; offline drives
@@ -547,7 +575,6 @@ class ErasureObjects(MultipartOps, ObjectLayer):
 
     def _put_object_bytes(self, bucket: str, object_name: str, data: bytes,
                           opts: PutObjectOptions) -> ObjectInfo:
-        from ..obs import stages as _stages
         self._check_bucket(bucket)
         n = len(self.disks)
         k, m = self._geometry(opts.parity)
@@ -730,7 +757,6 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         (pkg/hash/reader.go:186, cmd/object-api-utils.go:843-855)."""
         if opts.content_md5 or (opts.preserve_etag is None
                                 and _strict_compat()):
-            from ..obs import stages as _stages
             etag = _md5_timed(_stages.current(), md5fast.md5,
                               data).hexdigest()
             if opts.content_md5 and etag != opts.content_md5.lower():
@@ -980,7 +1006,6 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         (``framed_shape``).  Returns (framed_rows, release_cb) —
         release fires once every drive wrote the batch (memory stays
         O(depth x batch))."""
-        from ..obs import stages as _stages
         t0 = time.perf_counter()
         try:
             # a real stage frame (not a finally-add): time the codec
@@ -1013,7 +1038,6 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         md5_links: collections.deque = collections.deque()
         inflight: collections.deque = collections.deque()
         total = batches = 0
-        from ..obs import stages as _stages
         clock = _stages.current()
         for chunk in chunks:
             total += len(chunk)
@@ -1061,7 +1085,6 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         stats = {"md5_s": 0.0, "encode_s": 0.0}
         depth = max(1, self._pipe_depth)
         sw = self._write_plane.stream(shuffled)
-        from ..obs import stages as _stages
         src = None
         t_wall0 = time.perf_counter()
         lk = self.ns_lock.new_lock(bucket, object_name)
@@ -1184,7 +1207,6 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         # cmd/xl-storage.go:1544-1546)
         from ..utils.readahead import readahead
 
-        from ..obs import stages as _stages
         src = None
         lk = self.ns_lock.new_lock(bucket, object_name)
         with _stages.stage("lock_wait"):
@@ -1275,24 +1297,36 @@ class ErasureObjects(MultipartOps, ObjectLayer):
     def _read_quorum_fileinfo(self, bucket: str, object_name: str,
                               version_id: Optional[str] = None
                               ) -> tuple[FileInfo, list[FileInfo | None]]:
-        t0 = _critpath.now_ns()
-        ends = [0] * len(self.disks)
-        fis, errs = self._fanout(
-            lambda d: d.read_version(bucket, object_name, version_id),
-            ends=ends)
-        nf = sum(1 for e in errs
-                 if isinstance(e, (serrors.FileNotFound,
-                                   serrors.FileVersionNotFound)))
-        if nf > len(self.disks) // 2:
-            if version_id is not None and any(
-                    isinstance(e, serrors.FileVersionNotFound) for e in errs):
-                raise VersionNotFound(f"{bucket}/{object_name}@{version_id}")
-            raise ObjectNotFound(f"{bucket}/{object_name}")
-        quorum = max(1, len(self.disks) // 2)
-        fi = meta.find_file_info_in_quorum(fis, quorum)
-        _critpath.record("read_meta", quorum,
-                         self._drive_labels(self.disks), ends, t0,
-                         errs=errs)
+        """One quorum metadata read: ``read_version`` on every drive,
+        then the pick of the FileInfo a read quorum agrees on.  Every
+        caller (HEAD, GET, the hot-read validation and leader, metadata
+        updates) is timed here and nowhere else: stage ``meta_read``
+        and leg ``meta.fanout`` are this whole interval on the caller's
+        thread, ``meta.pick`` the interpreter's part after the gather;
+        the children are plane ``meta`` of :meth:`_fanout_items`."""
+        with _stages.stage("meta_read"), \
+                _trace.span("read", "meta.fanout"):
+            t0 = _critpath.now_ns()
+            ends = [0] * len(self.disks)
+            fis, errs = self._fanout(
+                lambda d: d.read_version(bucket, object_name, version_id),
+                ends=ends, plane="meta")
+            with _trace.span("read", "meta.pick"):
+                nf = sum(1 for e in errs
+                         if isinstance(e, (serrors.FileNotFound,
+                                           serrors.FileVersionNotFound)))
+                if nf > len(self.disks) // 2:
+                    if version_id is not None and any(
+                            isinstance(e, serrors.FileVersionNotFound)
+                            for e in errs):
+                        raise VersionNotFound(
+                            f"{bucket}/{object_name}@{version_id}")
+                    raise ObjectNotFound(f"{bucket}/{object_name}")
+                quorum = max(1, len(self.disks) // 2)
+                fi = meta.find_file_info_in_quorum(fis, quorum)
+            _critpath.record("read_meta", quorum,
+                             self._drive_labels(self.disks), ends, t0,
+                             errs=errs)
         return fi, fis
 
     def get_object_info(self, bucket: str, object_name: str,
@@ -1300,7 +1334,8 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         opts = opts or ObjectOptions()
         self._check_bucket(bucket)
         lk = self.ns_lock.new_lock(bucket, object_name)
-        lk.lock(write=False)   # rlock, as GetObjectInfo does
+        with _stages.stage("lock_wait"):
+            lk.lock(write=False)   # rlock, as GetObjectInfo does
         try:
             fi, _ = self._read_quorum_fileinfo(bucket, object_name,
                                                opts.version_id)
@@ -1333,7 +1368,6 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         # the validated cache.  Every non-happy path returns None and
         # falls through here, so the reference error semantics below
         # stay the single source of truth.
-        from ..obs import stages as _stages
         plane = self.hotread
         if plane is not None:
             with _stages.stage("cache"):
@@ -1437,7 +1471,6 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         hit compares before serving (diskcache.py ETag-validation
         role, quorum-consistent so a committed overwrite on ANY node
         is always seen)."""
-        from ..obs import stages as _stages
         self._check_bucket(bucket)
         lk = self.ns_lock.new_lock(bucket, object_name)
         with _stages.stage("lock_wait"):
@@ -1459,7 +1492,6 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         any drive data fan-out).  Returns ``(fi, info, data)``; data
         is None for delete markers and out-of-range starts (the
         caller falls through to the reference error path)."""
-        from ..obs import stages as _stages
         self._check_bucket(bucket)
         lk = self.ns_lock.new_lock(bucket, object_name)
         with _stages.stage("lock_wait"):
@@ -1532,17 +1564,21 @@ class ErasureObjects(MultipartOps, ObjectLayer):
                 framed_off = logical_off + bb0 * hlen
                 framed_len = seg_len + (bb1 - bb0) * hlen
                 covered = min(bb1 * bs, part.size) - bb0 * bs
-                from ..obs import stages as _stages
-                with _stages.stage("drive_read"):
+                with _stages.stage("drive_read"), \
+                        _trace.span("read", "get.fanout"):
                     shards = self._read_shard_segments(
                         bucket, object_name, fi, part, shuffled, sfis,
                         dead, framed_off, framed_len, seg_len, ssize,
                         algo)
-                with _stages.stage("decode"):
+                with _stages.stage("decode"), \
+                        _trace.span("read", "get.assemble"):
                     part_bytes = self._assemble(shards, fi, covered)
                 lo = max(p0 - bb0 * bs, 0)
                 hi = min(p1 - bb0 * bs, covered)
-                yield part_bytes[lo:hi].tobytes()
+                # the body's second host copy (after _assemble's)
+                with _trace.span("read", "get.copy_out"):
+                    chunk = part_bytes[lo:hi].tobytes()
+                yield chunk
             part_start += part.size
         # shards that failed mid-stream are heal candidates
         # (on-read heal trigger, cmd/erasure-object.go:330-342)
@@ -1581,13 +1617,18 @@ class ErasureObjects(MultipartOps, ObjectLayer):
                     bucket,
                     f"{object_name}/{fi.data_dir}/part.{part.number}",
                     framed_off, framed_len)
+            # the drive call above is timed where it runs
+            # (mt_drive_call_seconds); the verify is the child's other half
             try:
-                # one native verify pass + one strided payload copy
-                fast = bitrot.verify_extract(framed, ssize, seg_len, algo)
-                if fast is not None:
-                    return fast
-                r = bitrot.StreamingBitrotReader(framed, ssize, algo)
-                return np.frombuffer(r.read_at(0, seg_len), dtype=np.uint8)
+                with _trace.span("read", "get.verify"):
+                    # one native verify pass + one strided payload copy
+                    fast = bitrot.verify_extract(framed, ssize, seg_len,
+                                                 algo)
+                    if fast is not None:
+                        return fast
+                    r = bitrot.StreamingBitrotReader(framed, ssize, algo)
+                    return np.frombuffer(r.read_at(0, seg_len),
+                                         dtype=np.uint8)
             except bitrot.BitrotError as e:
                 raise serrors.FileCorrupt(str(e)) from e
 
@@ -1599,7 +1640,8 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         while got < k and candidates:
             batch, candidates = candidates[:k - got], candidates[k - got:]
             bends = [0] * len(batch)
-            res, errs = self._fanout_items(read_one, batch, ends=bends)
+            res, errs = self._fanout_items(read_one, batch, ends=bends,
+                                           plane="get")
             for pos, (j, r, e) in enumerate(zip(batch, res, errs)):
                 ends_all[j] = bends[pos]
                 if e is None:
@@ -1673,16 +1715,19 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         # delete path): a delete racing a PUT commit must not interleave
         # per-drive version mutations
         lk = self.ns_lock.new_lock(bucket, object_name)
-        lk.lock(write=True)
+        with _stages.stage("lock_wait"):
+            lk.lock(write=True)
         try:
             if opts.versioned and opts.version_id is None:
                 # versioned delete without a version: write a delete marker
                 dm = FileInfo(volume=bucket, name=object_name,
                               version_id=str(uuid.uuid4()), deleted=True,
                               data_dir="", mod_time=mod_time)
-                _, errs = self._fanout(
-                    lambda d: d.delete_version(bucket, object_name, dm,
-                                               force_del_marker=True))
+                with _stages.stage("drive_commit"):
+                    _, errs = self._fanout(
+                        lambda d: d.delete_version(
+                            bucket, object_name, dm,
+                            force_del_marker=True), plane="delete")
                 try:
                     meta.reduce_errs(errs, self._write_quorum(),
                                      WriteQuorumError)
@@ -1698,8 +1743,10 @@ class ErasureObjects(MultipartOps, ObjectLayer):
             vid = opts.version_id or ""
             fi = FileInfo(volume=bucket, name=object_name, version_id=vid,
                           mod_time=mod_time)
-            _, errs = self._fanout(
-                lambda d: d.delete_version(bucket, object_name, fi))
+            with _stages.stage("drive_commit"):
+                _, errs = self._fanout(
+                    lambda d: d.delete_version(bucket, object_name, fi),
+                    plane="delete")
             nf = sum(1 for e in errs
                      if isinstance(e, (serrors.FileNotFound,
                                        serrors.FileVersionNotFound)))

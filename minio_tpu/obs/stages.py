@@ -15,6 +15,11 @@ named stages as the request crosses them:
                      OPA webhook when configured
   ``body_read``      reading the request body off the socket
   ``lock_wait``      namespace-lock acquisition (local or dsync)
+  ``meta_read``      the quorum metadata read: the ``read_version``
+                     fan-out over the set's drives + the pick of the
+                     quorum FileInfo (HEAD, GET, a cache hit's
+                     validation; exclusive, so inside ``cache`` it
+                     comes out of ``cache``'s self time)
   ``memgov``         memory-governor admission accounting
   ``cache``          hot-read plane serve (hit validation included)
   ``encode``         erasure encode + bitrot framing (PUT)
@@ -25,9 +30,14 @@ named stages as the request crosses them:
   ``decode``         shard assembly / erasure decode (GET)
   ``batch_wait``     cross-request codec batcher queue wait
   ``drive_read``     shard-segment fan-out wall time (GET)
-  ``drive_commit``   commit fan-out wall time (PUT)
+  ``drive_commit``   commit fan-out wall time (PUT; a DELETE's
+                     ``delete_version`` fan-out under its own API label)
   ``write_enqueue``  writer-plane enqueue stalls (pipelined PUT)
   ``write_drain``    writer-plane drain wait (pipelined PUT)
+  ``stream_wait``    the request thread pulling the next chunk of a
+                     streamed GET body: with readahead, its wait for
+                     the producer thread; without, the producer's own
+                     stages nest inside it and take their time out
   ``body_write``     writing the response body to the socket
   ``rpc``            internode RPC legs (async detail — overlaps the
                      request thread by design)
@@ -66,10 +76,10 @@ from typing import Optional
 # docs/observability.md; the xray tests check emitted names stay inside
 # this set.
 STAGE_NAMES = (
-    "admission", "auth", "policy", "body_read", "lock_wait", "memgov",
-    "cache", "encode", "md5", "decode", "batch_wait", "drive_read",
-    "drive_commit", "write_enqueue", "write_drain", "body_write",
-    "rpc", "other",
+    "admission", "auth", "policy", "body_read", "lock_wait", "meta_read",
+    "memgov", "cache", "encode", "md5", "decode", "batch_wait",
+    "drive_read", "drive_commit", "write_enqueue", "write_drain",
+    "stream_wait", "body_write", "rpc", "other",
 )
 
 # bench A/B switch (MT_XRAY_DISABLE=1 runs the hot paths with the

@@ -17,6 +17,11 @@ pkg/trace.Type):
                  ``encode-bitrot.batch`` ...) with shard geometry and bytes,
                  and their legs (prep/upload/launch/fetch/frame), all
                  through :class:`span` (ops/codec.py + friends)
+  ``read``       the read side's legs, ``<plane>.<leg>``: the quorum
+                 metadata read (``meta.fanout``, ``meta.pick``) and a
+                 GET's shard read (``get.fanout``, ``get.verify``,
+                 ``get.assemble``, ``get.copy_out``), through
+                 :class:`span` (objectlayer/erasure_object.py)
   ``scanner``    data-crawler per-bucket spans (background/crawler.py)
   ``healing``    heal-sweep / MRF per-object spans (background/heal.py)
   ``replication``  per-object replication spans
@@ -31,11 +36,13 @@ Publishing is skipped entirely when nobody is subscribed, mirroring the
 reference's ``globalHTTPTrace.NumSubscribers() > 0`` guard — the hot
 path pays a single predicate (:func:`active`), no dict construction.
 
-:class:`span` is the one way a leg of the device codec path is timed:
-one enter/exit feeds the always-on span ring, the
-``mt_tpu_leg_seconds{op,leg}`` histogram and — when ops/device.py has
-installed an annotator — the profiler's own trace, so the program's
-spans sit on the device trace's clock.  This package never imports JAX.
+:class:`span` is the one way a leg of the device codec path or of the
+read side is timed: one enter/exit feeds the always-on span ring, the
+type's leg histogram (``LEG_FAMILIES``: wall; one span in
+``CPU_SAMPLE_EVERY`` also its thread's CPU time, in the ``_cpu`` twin)
+and — when ops/device.py has installed an annotator — the profiler's own
+trace, so the program's spans sit on the device trace's clock.  This
+package never imports JAX.
 """
 
 from __future__ import annotations
@@ -60,7 +67,7 @@ HTTP_TRACE = PubSub(max_queue=4000)
 # scanner/healing/replication are the background planes (pkg/trace
 # TraceScanner/TraceHealing/TraceReplication) — per-object spans from
 # the autonomous loops, same zero-subscriber idle contract as the rest.
-TRACE_TYPES = ("http", "storage", "internode", "tpu",
+TRACE_TYPES = ("http", "storage", "internode", "tpu", "read",
                "scanner", "healing", "replication", "watchdog")
 
 # headers never to leak into traces (cmd/http-tracer.go redacts these;
@@ -380,7 +387,23 @@ def make_span(trace_type: str, func_name: str, *, start_ns: int,
 
 # trace type -> the histogram family its helper spans observe, labelled
 # by the two halves of the span's ``<op>.<leg>`` name
-LEG_FAMILIES = {"tpu": "mt_tpu_leg_seconds"}
+LEG_FAMILIES = {"tpu": "mt_tpu_leg_seconds",
+                "read": "mt_read_leg_seconds"}
+# each family's twin for the CPU time of the thread that ran the leg:
+# ``mt_x_leg_seconds`` -> ``mt_x_leg_cpu_seconds``
+_LEG_CPU_FAMILIES = {t: f[:-len("seconds")] + "cpu_seconds"
+                     for t, f in LEG_FAMILIES.items()}
+# The CPU clock is SAMPLED: one span in this many of each name reads
+# it, the first one always.  ``time.thread_time_ns()`` has no vDSO
+# path; where a sandbox traps the syscall (gVisor, the benchmark's
+# machines) it costs 6 us alone and 36 us under load, 60x
+# ``monotonic_ns()``, with the interpreter lock held: read at both ends
+# of every span it took ~5 % of ``n16.small-zipf``'s ``ops_per_s``
+# (PERF.md section 6, PR 36).  A sampled span observes its CPU time AND
+# its wall into the twin (``clock="cpu"`` / ``"wall"``), so the share is
+# read inside one family, over the same spans.
+CPU_SAMPLE_EVERY = 16
+_cpu_seen: dict = {}     # span name -> spans entered (races only jitter)
 
 # ``factory(name)`` -> a context manager entered and exited around every
 # helper span.  ops/device.py installs jax.profiler.TraceAnnotation when
@@ -405,12 +428,15 @@ class span:
     ``mt:<name>`` when one is installed.  The full span dict is built
     only behind :func:`active`; ``detail`` is then called for its
     type-keyed payload.  An exception passing through is recorded as
-    the span's ``error`` and propagates.  ``dur_ns`` and ``error`` stay
-    readable after the block for callers that also count the interval.
-    A leg's wall includes the time its thread waited for the GIL."""
+    the span's ``error`` and propagates.  ``dur_ns``, ``cpu_ns`` and
+    ``error`` stay readable after the block for callers that also count
+    the interval.  A leg's wall includes the time its thread waited for
+    the GIL; ``cpu_ns`` (``time.thread_time_ns``; None unless this span
+    was one of the ``CPU_SAMPLE_EVERY`` that read the clock) does not,
+    so wall - CPU of a leg that makes no blocking call is that wait."""
 
     __slots__ = ("trace_type", "name", "nbytes", "detail", "start_ns",
-                 "dur_ns", "error", "_t0", "_ann")
+                 "dur_ns", "cpu_ns", "error", "_t0", "_c0", "_ann")
 
     def __init__(self, trace_type: str, name: str, nbytes: int = 0,
                  detail=None):
@@ -419,6 +445,7 @@ class span:
         self.nbytes = nbytes
         self.detail = detail
         self.start_ns = self.dur_ns = 0
+        self.cpu_ns = None
         self.error = ""
         self._ann = None
 
@@ -427,11 +454,16 @@ class span:
             self._ann = _ANNOTATOR("mt:" + self.name)
             self._ann.__enter__()
         self.start_ns = time.time_ns()
+        seen = _cpu_seen.get(self.name, 0)
+        _cpu_seen[self.name] = seen + 1
+        self._c0 = -1 if seen % CPU_SAMPLE_EVERY else time.thread_time_ns()
         self._t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, et, ev, tb):
         self.dur_ns = dur = time.monotonic_ns() - self._t0
+        if self._c0 >= 0:
+            self.cpu_ns = time.thread_time_ns() - self._c0
         if self._ann is not None:
             self._ann.__exit__(et, ev, tb)
             self._ann = None
@@ -440,8 +472,15 @@ class span:
         family = LEG_FAMILIES.get(self.trace_type)
         if family:
             op, _, leg = self.name.partition(".")
-            _metrics.observe(family, {"op": op, "leg": leg}, dur / 1e9,
+            labels = {"op": op, "leg": leg}
+            _metrics.observe(family, labels, dur / 1e9,
                              buckets=KERNEL_BUCKETS)
+            if self.cpu_ns is not None:
+                twin = _LEG_CPU_FAMILIES[self.trace_type]
+                _metrics.observe(twin, {**labels, "clock": "cpu"},
+                                 self.cpu_ns / 1e9, buckets=KERNEL_BUCKETS)
+                _metrics.observe(twin, {**labels, "clock": "wall"},
+                                 dur / 1e9, buckets=KERNEL_BUCKETS)
         rid = _REQUEST_ID.get()
         sid = ""
         if rid:

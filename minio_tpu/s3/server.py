@@ -23,6 +23,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from ..objectlayer import interface as ol
 from ..objectlayer.bucket_meta import BucketMetadataSys
+from ..obs import stages as _stages
 from . import errors as s3err
 from . import sigv4
 
@@ -58,19 +59,23 @@ class _BodyReader:
         n = min(n, self.remaining)
         if n <= 0:
             return b""
-        chunks = []
-        while n > 0:
-            c = self.raw.read(n)
-            if not c:
-                raise S3Error("IncompleteBody")
-            chunks.append(c)
-            n -= len(c)
-            self.remaining -= len(c)
-        data = chunks[0] if len(chunks) == 1 else b"".join(chunks)
-        if self._sha is not None:
-            self._sha.update(data)
-        if self._md5 is not None:
-            self._md5.update(data)
+        # the streaming PUT's body arrives here, under put_object_stream
+        # (or on its readahead thread: async detail then): socket read
+        # + digest updates are ``body_read``, as _body()'s buffered read
+        with _stages.stage("body_read"):
+            chunks = []
+            while n > 0:
+                c = self.raw.read(n)
+                if not c:
+                    raise S3Error("IncompleteBody")
+                chunks.append(c)
+                n -= len(c)
+                self.remaining -= len(c)
+            data = chunks[0] if len(chunks) == 1 else b"".join(chunks)
+            if self._sha is not None:
+                self._sha.update(data)
+            if self._md5 is not None:
+                self._md5.update(data)
         if self.remaining == 0:
             if self._sha is not None and \
                     self._sha.hexdigest() != self._want_sha:
@@ -1051,12 +1056,10 @@ def _make_handler(srv: S3Server):
                 raise S3Error("EntityTooLarge")
             if not n:
                 return b""
-            from ..obs import stages as _stages
             with _stages.stage("body_read"):
                 return self.rfile.read(n)
 
         def _auth(self, path, query, payload: bytes) -> bytes:
-            from ..obs import stages as _stages
             self._query_token = query.get("X-Amz-Security-Token", [""])[0]
             with _stages.stage("auth"):
                 out = self._auth_inner(path, query, payload)
@@ -1124,7 +1127,6 @@ def _make_handler(srv: S3Server):
             """Authorize the authenticated key for an S3 action: bucket
             policy first (explicit Deny wins, Allow grants even anonymous),
             then IAM (checkRequestAuthType -> IAMSys.IsAllowed)."""
-            from ..obs import stages as _stages
             with _stages.stage("policy"):
                 self._allow_inner(action, resource)
 
@@ -1195,7 +1197,6 @@ def _make_handler(srv: S3Server):
                 len(body) if content_length is None else content_length,
                 content_type, headers)
             if body and self.command != "HEAD":
-                from ..obs import stages as _stages
                 with _stages.stage("body_write"):
                     self.wfile.write(body)
 
@@ -1211,22 +1212,25 @@ def _make_handler(srv: S3Server):
             first = b""
             if self.command != "HEAD" and total:
                 try:
-                    first = next(it)
+                    with _stages.stage("stream_wait"):
+                        first = next(it)
                 except StopIteration:
                     first = b""
             self._send_prologue(status, total, total, content_type,
                                 headers)
-            from ..obs import stages as _stages
             try:
                 if first:
                     with _stages.stage("body_write"):
                         self.wfile.write(first)
-                # pull OUTSIDE the body_write stage: producing a chunk
-                # is drive_read/decode (attributed inside the
-                # generator), not socket time
+                # pull OUTSIDE the body_write stage, under stream_wait:
+                # with readahead it is this thread's wait for the
+                # producer (whose drive_read/decode are async detail);
+                # without, the producer's stages nest inside it and
+                # take their time out — either way not socket time
                 while True:
                     try:
-                        chunk = next(it)
+                        with _stages.stage("stream_wait"):
+                            chunk = next(it)
                     except StopIteration:
                         break
                     if chunk:
@@ -1342,7 +1346,6 @@ def _make_handler(srv: S3Server):
         def _dispatch(self):
             """Trace/audit wrapper around the real dispatcher
             (cmd/http-tracer.go httpTraceAll + cmd/logger/audit.go)."""
-            from ..obs import stages as _stages
             from ..obs import trace as _trace
             self._t0_ns = _trace.now_ns()
             # monotonic twin for durations fed into latency windows (a
@@ -1456,7 +1459,6 @@ def _make_handler(srv: S3Server):
                     srv._req_waiters -= 1
 
         def _record_request(self):
-            from ..obs import stages as _stages
             from ..obs import trace as _trace
             dur = _trace.now_ns() - self._t0_ns
             dur_mono = time.monotonic_ns() - self._t0m_ns

@@ -14,6 +14,8 @@ from typing import Iterable
 
 import msgpack
 
+from ..admin.metrics import GLOBAL as _metrics
+from ..admin.metrics import KERNEL_BUCKETS
 from ..obs import trace as _trace
 from ..parallel.rpc import (STREAM, RPCClient, RPCError, RPCServer,
                             StreamBody)
@@ -208,11 +210,11 @@ class RemoteStorage(StorageAPI):
     }
 
     def _call(self, method: str, **kwargs):
-        # client-observed storage span (drive latency incl. the wire);
-        # the owning node's XLStorage emits the drive-local twin.  The
+        # client-observed drive call (drive latency incl. the wire);
+        # the owning node's XLStorage times the drive-local twin.  The
         # last-minute window stays on the owning node — remote drives
         # must not be double-counted in disk latency stats.
-        t0 = time.monotonic_ns() if _trace.active() else 0
+        t0 = time.monotonic_ns()
         err = ""
         try:
             return self._c.call("storage", method, drive_id=self.drive_id,
@@ -222,11 +224,10 @@ class RemoteStorage(StorageAPI):
             err = f"{e.error_type}: {e.message}"
             raise self._map_err(e) from e
         finally:
-            if t0:
-                self._span(method, t0, err, kwargs)
+            self._span(method, t0, err, kwargs)
 
     def _raw(self, name: str, params: dict, body=b"") -> bytes:
-        t0 = time.monotonic_ns() if _trace.active() else 0
+        t0 = time.monotonic_ns()
         err = ""
         try:
             return self._c.raw_call(
@@ -236,11 +237,9 @@ class RemoteStorage(StorageAPI):
             err = f"{e.error_type}: {e.message}"
             raise self._map_err(e) from e
         finally:
-            if t0:
-                self._span(name, t0, err, params,
-                           nbytes=body.sent
-                           if isinstance(body, StreamBody)
-                           else len(body))
+            self._span(name, t0, err, params,
+                       nbytes=body.sent if isinstance(body, StreamBody)
+                       else len(body))
 
     def _stream_body(self, data, chunk: int,
                      trailer_fn=None) -> StreamBody | None:
@@ -266,7 +265,17 @@ class RemoteStorage(StorageAPI):
 
     def _span(self, method: str, t0: int, err: str, params: dict,
               nbytes: int = 0) -> None:
+        """The caller's wall of one RPC: always into
+        ``mt_drive_call_seconds{op,kind="remote"}`` (``op`` is the RPC's
+        name: a storage method, or ``storage-read`` / ``storage-write``
+        for the raw body calls); the ``storage`` span only for a
+        subscriber."""
         dt = time.monotonic_ns() - t0   # t0 is monotonic; wall clock
+        _metrics.observe("mt_drive_call_seconds",
+                         {"op": method, "kind": "remote"}, dt / 1e9,
+                         buckets=KERNEL_BUCKETS)
+        if not _trace.active():
+            return
         _trace.publish_span(_trace.make_span(  # only for the timestamp
             "storage", f"storage.{method}",
             start_ns=_trace.now_ns() - dt,

@@ -31,6 +31,7 @@ import uuid
 from typing import Iterable
 
 from ..admin.metrics import GLOBAL as _metrics
+from ..admin.metrics import KERNEL_BUCKETS
 from ..obs import lastminute as _lastminute
 from ..obs import trace as _trace
 from . import commit as _commit
@@ -1155,7 +1156,10 @@ class XLStorage(StorageAPI):
 # -- per-op instrumentation (deep tracing plane) ---------------------------
 # Every data-plane method records into the drive's last-minute latency
 # window (always on — slow-drive detection and mt_node_disk_latency_*
-# need it) and, only when a trace consumer is active, publishes a
+# need it) and, from the same ``dt``, into the cumulative family
+# ``mt_drive_call_seconds{op,kind="local"}`` (a scrape pair can
+# difference it; the window's gauges it cannot) and, only when a trace
+# consumer is active, publishes a
 # ``storage``-type span to the HTTP_TRACE hub (`mc admin trace -a`
 # storage calls, cmd/xl-storage-disk-id-check.go trace wrappers).  With
 # zero subscribers and an idle peer ring the per-op cost beyond the
@@ -1183,6 +1187,8 @@ _IN_TRACED_OP = threading.local()
 
 
 def _traced_op(op: str, fn, in_arg: int | None):
+    call_labels = {"op": op, "kind": "local"}
+
     def traced(self, *a, **kw):
         if getattr(_IN_TRACED_OP, "depth", 0):
             return fn(self, *a, **kw)
@@ -1224,6 +1230,8 @@ def _traced_op(op: str, fn, in_arg: int | None):
             elif isinstance(out, (bytes, bytearray)):
                 nbytes = len(out)
             self.latency.record(op, dt, nbytes)
+            _metrics.observe("mt_drive_call_seconds", call_labels,
+                             dt / 1e9, buckets=KERNEL_BUCKETS)
             if _trace.active():
                 vol = a[0] if a and isinstance(a[0], str) \
                     else kw.get("volume", "")

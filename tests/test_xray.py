@@ -159,6 +159,41 @@ def test_get_put_carry_complete_stage_timeline(served):
         assert sum(rec["stages"].values()) == rec["durationNs"], rec
 
 
+# what a read-side request's serial vector names since ISSUE 36: the
+# quorum metadata read, the hand-over of the body, a DELETE's fan-out
+@pytest.mark.parametrize("api,want", [
+    ("HeadObject", ("auth", "policy", "lock_wait", "meta_read")),
+    ("GetObject", ("auth", "policy", "lock_wait", "meta_read",
+                   "stream_wait", "body_write")),
+    ("DeleteObject", ("auth", "policy", "lock_wait", "drive_commit")),
+])
+def test_read_side_vectors_reconcile(served, api, want):
+    c = S3Client(served.endpoint, "xk", "xs")
+    c.make_bucket("rbkt")
+    c.put_object("rbkt", "obj", b"r" * 300_000)
+    if api == "HeadObject":
+        c.head_object("rbkt", "obj")
+    elif api == "GetObject":
+        c.get_object("rbkt", "obj")
+    else:
+        c.delete_object("rbkt", "obj")
+    _settle(served, 3)
+    rec = {r["api"]: r for r in _xray(c)["records"]}[api]
+    for name in want + ("other",):
+        assert name in rec["stages"], (name, rec["stages"])
+    names = set(rec["stages"]) | set(rec["asyncStages"])
+    assert names <= set(stages.STAGE_NAMES), names
+    # the serial stages + other are the request wall, to the ns: the
+    # new stages nest (meta_read inside cache, a buffered read's
+    # drive_read inside stream_wait) and take their time out of their
+    # parents; nothing is counted twice
+    assert sum(rec["stages"].values()) == rec["durationNs"], rec
+    if api != "DeleteObject":
+        # the metadata read is named: HEAD's remainder is no longer
+        # the whole request
+        assert rec["stages"]["meta_read"] > 0
+
+
 def test_stage_histogram_and_trace_detail(served):
     c = S3Client(served.endpoint, "xk", "xs")
     c.make_bucket("hbkt")
