@@ -44,6 +44,7 @@ from ..storage.writers import WriterPlane
 from ..utils import bufpool
 from ..storage.datatypes import (ChecksumInfo, ErasureInfo, FileInfo,
                                  ObjectPartInfo, now_ns)
+from ..storage import xl_storage as _xl
 from ..storage.xl_storage import SYS_DIR
 from . import metadata as meta
 from .interface import (BucketExists, BucketInfo, BucketNotEmpty,
@@ -297,7 +298,7 @@ class ErasureObjects(MultipartOps, ObjectLayer):
 
     # -- drive fan-out helpers --------------------------------------------
 
-    def _fanout_items(self, fn, items, ends=None, plane=None):
+    def _fanout_items(self, fn, items, ends=None, plane=None, inline=None):
         """Run fn(item) concurrently over arbitrary items; returns
         (results, errs) aligned with items (parallelWriter/Reader
         analog, cmd/erasure-encode.go:36).  On a single-core host the
@@ -316,7 +317,14 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         child (its wait for a pool thread and for the GIL to start),
         ``leg="gather"`` = caller resumed - last end.  One registry
         call per leg and fan-out; the drive call between a child's
-        start and end is in ``mt_drive_call_seconds``."""
+        start and end is in ``mt_drive_call_seconds``.
+
+        ``inline`` (optional, ``(positions, run)``): the items at
+        ``positions`` are not submitted; once the others are, the
+        CALLING thread runs ``run()``, which returns ``(result, error,
+        start_ns, end_ns)`` for each of them in order, and they fold
+        with the children (an inline item's ``queue`` is its start -
+        submit too)."""
 
         def run(x):
             try:
@@ -327,22 +335,39 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         starts = [0] * len(items) if plane is not None and items else None
         if starts is not None and ends is None:
             ends = [0] * len(items)
-        if ends is None:
+        if ends is None and inline is None:
             runner, seq = run, items
         else:
             def runner(pair):
                 if starts is not None:
                     starts[pair[0]] = time.monotonic_ns()
                 out = run(pair[1])
-                ends[pair[0]] = time.monotonic_ns()
+                if ends is not None:
+                    ends[pair[0]] = time.monotonic_ns()
                 return out
             seq = list(enumerate(items))
+            if inline is not None:
+                here = set(inline[0])
+                seq = [p for p in seq if p[0] not in here]
         submit = time.monotonic_ns()
-        if self._serial_fanout:
+        if self._serial_fanout or not seq:
             out = [runner(x) for x in seq]
         else:
-            out = list(self._pool.map(self._with_request_id(runner),
-                                      seq))
+            # submitted here; the inline items run while these do
+            out = self._pool.map(self._with_request_id(runner), seq)
+        if inline is not None:
+            got = [None] * len(items)
+            for i, (r, e, s0, e0) in zip(inline[0], inline[1]()):
+                got[i] = r, e
+                if starts is not None:
+                    starts[i] = s0
+                if ends is not None:
+                    ends[i] = e0
+            for (i, _), r in zip(seq, out):
+                got[i] = r
+            out = got
+        else:
+            out = list(out)
         if starts is not None:
             resumed = time.monotonic_ns()
             family = _trace.LEG_FAMILIES["read"]
@@ -377,10 +402,10 @@ class ErasureObjects(MultipartOps, ObjectLayer):
 
         return run_ctx
 
-    def _fanout(self, fn, disks=None, ends=None, plane=None):
+    def _fanout(self, fn, disks=None, ends=None, plane=None, inline=None):
         """fn(disk) on every drive concurrently; offline (None) drives
-        report DiskNotFound in the aligned error list.  ``ends`` and
-        ``plane`` as in :meth:`_fanout_items`."""
+        report DiskNotFound in the aligned error list.  ``ends``,
+        ``plane`` and ``inline`` as in :meth:`_fanout_items`."""
 
         def run(d):
             if d is None:
@@ -389,7 +414,7 @@ class ErasureObjects(MultipartOps, ObjectLayer):
 
         return self._fanout_items(run,
                                   self.disks if disks is None else disks,
-                                  ends=ends, plane=plane)
+                                  ends=ends, plane=plane, inline=inline)
 
     def _fanout_indexed(self, fn, shuffled_disks, ends=None):
         """fn((shard_idx, disk)) per drive, aligned errors; offline drives
@@ -1303,14 +1328,33 @@ class ErasureObjects(MultipartOps, ObjectLayer):
         updates) is timed here and nowhere else: stage ``meta_read``
         and leg ``meta.fanout`` are this whole interval on the caller's
         thread, ``meta.pick`` the interpreter's part after the gather;
-        the children are plane ``meta`` of :meth:`_fanout_items`."""
+        the children are plane ``meta`` of :meth:`_fanout_items`.
+
+        The drives a read wave can read (local, online, the native
+        library loaded, no group collector on this thread:
+        ``xl_storage.wave_positions``) are read by ONE native call on
+        this thread (``xl_storage.read_version_wave``), after the other
+        drives' children are submitted, so it overlaps them; every other
+        drive is a pool child as before.  ``mt_read_meta_drives_total
+        {route=wave|pool}`` counts the drives each route read."""
         with _stages.stage("meta_read"), \
                 _trace.span("read", "meta.fanout"):
             t0 = _critpath.now_ns()
             ends = [0] * len(self.disks)
+            here = _xl.wave_positions(self.disks)
             fis, errs = self._fanout(
                 lambda d: d.read_version(bucket, object_name, version_id),
-                ends=ends, plane="meta")
+                ends=ends, plane="meta",
+                inline=(here, lambda: _xl.read_version_wave(
+                    [self.disks[i] for i in here], bucket, object_name,
+                    version_id)) if here else None)
+            if here:
+                _metrics.inc("mt_read_meta_drives_total",
+                             {"route": "wave"}, len(here))
+            if len(here) < len(self.disks):
+                _metrics.inc("mt_read_meta_drives_total",
+                             {"route": "pool"},
+                             len(self.disks) - len(here))
             with _trace.span("read", "meta.pick"):
                 nf = sum(1 for e in errs
                          if isinstance(e, (serrors.FileNotFound,

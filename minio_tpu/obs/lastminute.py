@@ -8,8 +8,9 @@ one per S3 server (keyed by API name).  The windows drive:
   * the ``mt_node_disk_latency_*`` / ``mt_s3_api_last_minute_*`` gauge
     families computed at scrape time (admin/metrics.py);
   * slow-drive detection (storage/health.py slow_drives): a drive whose
-    p50 exceeds a configurable multiple of the set median is FLAGGED in
-    health/metrics, never ejected;
+    p50 of an op exceeds a configurable multiple of the set median of the
+    same op, for its typical op, is FLAGGED in health/metrics, never
+    ejected;
   * the admin ``top`` endpoint (hottest APIs, slowest drives).
 
 Recording is lock-free by design ("lock-cheap"): slot updates are plain

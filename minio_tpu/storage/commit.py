@@ -195,7 +195,8 @@ class _Landed(ctypes.Structure):
 @functools.cache
 def _wave_lib():
     """native/syncwave.c, built and loaded on first use; None when it
-    cannot be (utils/nativelib.status() says why)."""
+    cannot be (utils/nativelib.status() says why).  Its read wave
+    (``mt_read_files``) serves xl_storage.read_version_wave."""
     from ..utils import nativelib
     lib = nativelib.load(_WAVE_SRC, _WAVE_SO)
     if lib is not None:
@@ -212,6 +213,11 @@ def _wave_lib():
         lib.mt_land_part.argtypes = [ctypes.c_char_p] * 3 \
             + lib.mt_land_file.argtypes[1:]
         lib.mt_land_part.restype = ctypes.c_int
+        i64s = ctypes.POINTER(ctypes.c_longlong)
+        lib.mt_read_files.argtypes = [
+            ctypes.POINTER(ctypes.c_char_p), ctypes.c_int, ctypes.c_void_p,
+            ctypes.c_size_t, i64s, ints, i64s, i64s]
+        lib.mt_read_files.restype = None
     return lib
 
 

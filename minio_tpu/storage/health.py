@@ -29,7 +29,7 @@ from typing import Callable, Optional
 
 from . import errors
 from .format import FORMAT_FILE, FormatErasure
-from .xl_storage import SYS_DIR
+from .xl_storage import SYS_DIR, XLStorage
 from ..utils.locktrace import mtlock
 
 # data-plane methods gated by the circuit breaker; identity/health
@@ -67,11 +67,19 @@ def slow_drive_knobs(config=None) -> tuple[float, int]:
 def slow_drives(disks, multiple: float = 4.0, min_samples: int = 10
                 ) -> dict[str, dict]:
     """Slow-drive detection over ONE erasure set's last-minute latency
-    windows: a drive whose p50 exceeds ``multiple`` x the median p50 of
-    the OTHER drives in the set is flagged (tail-at-scale hedging
-    signal, Dean & Barroso 2013) — flagged in health/metrics output,
-    never ejected; ejection stays the circuit breaker's job and needs
-    hard failures, not latency.
+    windows, op by op: each op a drive ran is compared with the median
+    p50 of the same op on the OTHER drives of the set, and a drive whose
+    typical op (the sample-weighted median of those ratios) is over
+    ``multiple`` is flagged (tail-at-scale hedging signal, Dean &
+    Barroso 2013) — flagged in health/metrics output, never ejected;
+    ejection stays the circuit breaker's job and needs hard failures,
+    not latency.
+
+    Op by op because a drive's mix is its own: a data drive serves shard
+    reads a parity drive does not, and a quorum metadata read that the
+    native wave makes (xl_storage.read_version_wave) costs tens of us
+    where a write costs ms; one median over all of a drive's ops sits
+    wherever its mix puts it.
 
     Leave-one-out median: comparing a drive against a median that
     includes itself lets a single outlier in a small set DRAG the
@@ -80,26 +88,49 @@ def slow_drives(disks, multiple: float = 4.0, min_samples: int = 10
     (slow_drives_for_layer) so an HDD pool never masks a failing NVMe.
 
     Returns {endpoint: {"p50_ns", "samples", "median_ns", "slow"}} for
-    drives with any last-minute traffic."""
+    drives with any last-minute traffic: ``p50_ns`` the drive's median
+    over all its ops, ``median_ns`` what its peers' pace gives for the
+    same mix (``p50_ns`` over the typical ratio; 0 with no op in
+    common)."""
     from ..obs.lastminute import drive_windows
     wins = drive_windows(disks)
-    stats = {}
+    stats, ops = {}, {}
     for endpoint, w in wins.items():
         samples = sum(c for c, _, _ in w.totals().values())
         if not samples:
             continue
         stats[endpoint] = {"p50_ns": w.p50_all(), "samples": samples}
-    if not stats:
-        return {}
+        mine = ops[endpoint] = {}       # op -> (p50, live samples)
+        for op, win in list(w.windows.items()):
+            live = sorted(win.live_samples())
+            if live:
+                mine[op] = (live[len(live) // 2], len(live))
     for endpoint, v in stats.items():
-        others = sorted(o["p50_ns"] for e, o in stats.items()
-                        if e != endpoint)
-        median = others[len(others) // 2] if others else 0
-        v["median_ns"] = median
-        v["slow"] = bool(
-            median > 0 and v["samples"] >= min_samples
-            and v["p50_ns"] > multiple * median)
+        ratios = []
+        for op, (p50, n) in ops[endpoint].items():
+            others = sorted(o[op][0] for e, o in ops.items()
+                            if e != endpoint and op in o)
+            median = others[len(others) // 2] if others else 0
+            if median > 0:
+                ratios.append((p50 / median, n))
+        ratio = _weighted_median(ratios)
+        v["median_ns"] = int(v["p50_ns"] / ratio) if ratio else 0
+        v["slow"] = bool(ratio and v["samples"] >= min_samples
+                         and ratio > multiple)
     return stats
+
+
+def _weighted_median(pairs: list[tuple[float, int]]) -> float:
+    """The median of ``(value, weight)`` pairs, each value counted
+    ``weight`` times (the upper one of an even count, as a window's p50
+    takes it); 0 for none."""
+    half = sum(w for _, w in pairs) // 2
+    seen = 0
+    for value, w in sorted(pairs):
+        seen += w
+        if seen > half:
+            return value
+    return 0.0
 
 
 def disks_by_set(layer) -> list[list]:
@@ -256,6 +287,24 @@ class HealthDisk:
             if not healthy:
                 self._mark_offline()
             raise
+
+    def guarded(self, fn, *args):
+        """``fn(*args)`` as a call of this drive, under :meth:`_guard`'s
+        rules: the entry a read wave (xl_storage.read_version_wave)
+        takes for the part of a drive's read it does in Python, so the
+        breaker's rules live in ``_guard`` alone."""
+        return self._guard(fn, *args)
+
+    def wave_storage(self) -> XLStorage | None:
+        """The local drive under this one when a quorum metadata read may
+        read its ``xl.meta`` in the native wave
+        (xl_storage.wave_target): a plain ``XLStorage`` while the drive
+        is online.  None for an offline drive: it stays a pool child,
+        whose call :meth:`_guard` refuses inside its cooldown or lets
+        :meth:`probe` decide after, so the wave never reads it."""
+        inner = self.inner
+        return inner if not self._offline and type(inner) is XLStorage \
+            else None
 
     def __getattr__(self, name):
         attr = getattr(self.inner, name)

@@ -20,6 +20,8 @@ page-cache writeback, not alignment, is the governing factor).  Set
 
 from __future__ import annotations
 
+import ctypes
+import errno
 import functools
 import itertools
 import os
@@ -176,6 +178,21 @@ def _fsync_dir(path: str) -> None:
         pass
     finally:
         os.close(fd)
+
+
+def _read_file(full: str, path: str) -> bytes:
+    """The whole file ``full``; a missing file or a directory is
+    ``FileNotFound(path)``, a refused one ``FileAccessDenied(path)``, any
+    other error the ``OSError`` the open or read raised."""
+    try:
+        with open(full, "rb") as f:
+            return f.read()
+    except FileNotFoundError:
+        raise errors.FileNotFound(path) from None
+    except IsADirectoryError:
+        raise errors.FileNotFound(path) from None
+    except PermissionError as e:
+        raise errors.FileAccessDenied(path) from e
 
 
 def _is_valid_volname(volume: str) -> bool:
@@ -341,15 +358,7 @@ class XLStorage(StorageAPI):
     def read_all(self, volume: str, path: str) -> bytes:
         full = self._file_path(volume, path)
         self._check_vol(volume)
-        try:
-            with open(full, "rb") as f:
-                return f.read()
-        except FileNotFoundError:
-            raise errors.FileNotFound(path) from None
-        except IsADirectoryError:
-            raise errors.FileNotFound(path) from None
-        except PermissionError as e:
-            raise errors.FileAccessDenied(path) from e
+        return _read_file(full, path)
 
     def _open_create(self, volume: str, full: str):
         """Open for write, creating parents on the rare miss — but a
@@ -1186,6 +1195,31 @@ _OP_IN_ARG = {"write_all": 2, "create_file": 2, "append_file": 2,
 _IN_TRACED_OP = threading.local()
 
 
+def _publish_call(drive: XLStorage, op: str, start_ns: int, dt: int,
+                  err: str, where, in_bytes: int = 0,
+                  out_bytes: int = 0) -> None:
+    """One drive call's ``storage.<op>`` span: the full span to the trace
+    hub when a consumer is active (``where()`` gives its volume and
+    path), else, with a request in context, one compact tuple in the
+    idle causal ring (make_span rings on the active branch): requests
+    keep their drive-op children for trace-tree assembly with zero
+    subscribers, no dict built (the idle contract)."""
+    if _trace.active():
+        vol, path = where()
+        _trace.publish_span(_trace.make_span(
+            "storage", f"storage.{op}", start_ns=start_ns, duration_ns=dt,
+            input_bytes=in_bytes, output_bytes=out_bytes, error=err,
+            detail={"drive": drive._endpoint, "volume": vol,
+                    "path": path}))
+        return
+    rid = _trace.get_request_id()
+    if rid:
+        _trace.ring_append(rid, _trace.new_span_id(),
+                           _trace.get_span_parent(), "storage",
+                           f"storage.{op}", start_ns, dt, err,
+                           drive._endpoint)
+
+
 def _traced_op(op: str, fn, in_arg: int | None):
     call_labels = {"op": op, "kind": "local"}
 
@@ -1232,31 +1266,14 @@ def _traced_op(op: str, fn, in_arg: int | None):
             self.latency.record(op, dt, nbytes)
             _metrics.observe("mt_drive_call_seconds", call_labels,
                              dt / 1e9, buckets=KERNEL_BUCKETS)
-            if _trace.active():
-                vol = a[0] if a and isinstance(a[0], str) \
-                    else kw.get("volume", "")
-                path = a[1] if len(a) > 1 and isinstance(a[1], str) \
-                    else kw.get("path", "")
-                _trace.publish_span(_trace.make_span(
-                    "storage", f"storage.{op}",
-                    start_ns=time.time_ns() - dt, duration_ns=dt,
-                    input_bytes=nbytes if in_arg is not None else 0,
-                    output_bytes=0 if in_arg is not None else nbytes,
-                    error=err,
-                    detail={"drive": self._endpoint, "volume": vol,
-                            "path": path}))
-            else:
-                # idle causal ring (make_span rings on the active
-                # branch above): requests keep their drive-op children
-                # for trace-tree assembly with zero subscribers — one
-                # compact tuple, no dict (the PR-2 idle contract)
-                rid = _trace.get_request_id()
-                if rid:
-                    _trace.ring_append(
-                        rid, _trace.new_span_id(),
-                        _trace.get_span_parent(), "storage",
-                        f"storage.{op}", time.time_ns() - dt, dt, err,
-                        self._endpoint)
+            _publish_call(
+                self, op, time.time_ns() - dt, dt, err,
+                lambda: (a[0] if a and isinstance(a[0], str)
+                         else kw.get("volume", ""),
+                         a[1] if len(a) > 1 and isinstance(a[1], str)
+                         else kw.get("path", "")),
+                nbytes if in_arg is not None else 0,
+                0 if in_arg is not None else nbytes)
     traced.__name__ = op
     traced.__qualname__ = f"XLStorage.{op}"
     traced.__wrapped__ = fn
@@ -1267,3 +1284,183 @@ for _op in _TRACED_OPS:
     setattr(XLStorage, _op,
             _traced_op(_op, getattr(XLStorage, _op),
                        _OP_IN_ARG.get(_op)))
+
+
+# -- a quorum metadata read's local drives, in one native wave --------------
+# read_version on 16 drives was 16 pool children: each waited for a pool
+# thread and the interpreter to start, then for the interpreter again
+# after each of its open / read / close (PERF.md, meta_queue_ms).  The
+# local drives' xl.meta files are read instead by ONE call on the
+# calling thread that never holds the interpreter lock
+# (native/syncwave.c mt_read_files); the decode and every per-drive rule
+# of read_version stay here.
+
+# syncwave.c MT_READ_TOOBIG: the file is larger than its slot
+_READ_TOOBIG = -1
+# bytes per drive in a thread's arena: from _SLOT_MIN, doubled where the
+# thread meets a larger xl.meta (an inline object's shard lives in it),
+# up to _SLOT_MAX; a file over that is read again by the plain path
+_SLOT_MIN, _SLOT_MAX = 8 << 10, 256 << 10
+_WAVE_TLS = threading.local()
+_CALL_LABELS = {"op": "read_version", "kind": "local"}
+
+
+def wave_target(disk) -> XLStorage | None:
+    """The local drive a read wave reads for ``disk``: a plain
+    ``XLStorage``, or the one under an online ``HealthDisk``
+    (``wave_storage``); None for every other drive (remote, offline,
+    wrapped otherwise), which is called as always."""
+    if type(disk) is XLStorage:
+        return disk
+    under = getattr(type(disk), "wave_storage", None)
+    return under(disk) if under is not None else None
+
+
+def wave_positions(disks) -> list[int]:
+    """Positions of the drives of ``disks`` that :func:`read_version_wave`
+    reads in its native wave: none where the library cannot be loaded or
+    a group collector is armed on this thread (a read must see its
+    ``pending_get``)."""
+    if _commit._wave_lib() is None or _commit.collector() is not None:
+        return []
+    return [i for i, d in enumerate(disks) if wave_target(d) is not None]
+
+
+def _slot_arena(n: int):
+    """This thread's arena for ``n`` slots, and the slot size."""
+    cap = getattr(_WAVE_TLS, "cap", _SLOT_MIN)
+    arena = getattr(_WAVE_TLS, "arena", None)
+    if arena is None or len(arena) < n * cap:
+        arena = _WAVE_TLS.arena = ctypes.create_string_buffer(n * cap)
+    return arena, cap
+
+
+def _grow_slot(size: int) -> None:
+    cap = getattr(_WAVE_TLS, "cap", _SLOT_MIN)
+    while cap < size and cap < _SLOT_MAX:
+        cap *= 2
+    _WAVE_TLS.cap = cap
+
+
+def _read_error(err: int, full: str, path: str) -> Exception:
+    """What :func:`_read_file` raises for the errno ``err``."""
+    if err in (errno.ENOENT, errno.EISDIR):
+        return errors.FileNotFound(path)
+    e = OSError(err, os.strerror(err), full)
+    if isinstance(e, PermissionError):
+        return errors.FileAccessDenied(path)
+    return e
+
+
+def read_version_wave(disks, volume: str, path: str,
+                      version_id: str | None = None) -> list[tuple]:
+    """``read_version`` on every drive of ``disks``, each one that
+    :func:`wave_target` reads (the caller picks them with
+    :func:`wave_positions`), from the calling thread: their ``xl.meta``
+    files are read by ONE native call (at most 8 threads, joined before
+    it returns) that never holds the interpreter lock.
+    Per drive, in order: ``(FileInfo | None, error | None, start_ns,
+    end_ns)`` on the monotonic clock.
+
+    Each drive keeps ``read_version``'s semantics: the path passes the
+    traversal guard (``FileAccessDenied``) and the volume check
+    (``VolumeNotFound``); a missing file or a directory is
+    ``FileNotFound``, a refused one ``FileAccessDenied``, any other errno
+    the ``OSError`` a read would raise; a bad file ``FileCorrupt``; a
+    missing version ``FileVersionNotFound``.  What follows the native
+    read (the error, the decode) runs as the drive's call under its
+    breaker (``HealthDisk.guarded``), so a failure is judged as any call
+    of the drive is.  A drive is observed as ``_traced_op`` observes the
+    call: its last-minute window, ``mt_drive_call_seconds{op=
+    read_version,kind=local}`` (the native read's own time) and its
+    ``storage.read_version`` span."""
+    rel = os.path.join(path, META_FILE)
+    xls = [wave_target(d) for d in disks]
+    got: list = []      # per drive: its file, or what refused its path
+    t0s, t1s = [], []
+    wave = []           # positions whose file the native call reads
+    for i, xl in enumerate(xls):
+        t0s.append(time.monotonic_ns())
+        try:
+            full = xl._file_path(volume, rel)
+            xl._check_vol(volume)
+        except Exception as e:  # noqa: BLE001 — raised as the drive's call
+            full = e
+        else:
+            wave.append(i)
+        got.append(full)
+        t1s.append(time.monotonic_ns())
+    if wave:
+        _read_files(wave, got, t0s, t1s)
+    out, dts = [], []
+    for i, d in enumerate(disks):
+        try:
+            fi, err = _as_call(d, _decode, got[i], volume, path, rel,
+                               version_id), None
+        except Exception as e:  # noqa: BLE001 — per-drive isolation
+            fi, err = None, e
+        if isinstance(got[i], tuple) and got[i][1] == _READ_TOOBIG:
+            t1s[i] = time.monotonic_ns()     # read again by the plain path
+        out.append((fi, err, t0s[i], t1s[i]))
+        dts.append(_observe_wave(xls[i], volume, path, err, t0s[i], t1s[i]))
+    _metrics.observe_many("mt_drive_call_seconds", _CALL_LABELS,
+                          [dt / 1e9 for dt in dts], buckets=KERNEL_BUCKETS)
+    return out
+
+
+def _read_files(wave: list, got: list, t0s: list, t1s: list) -> None:
+    """``mt_read_files`` over the files ``got`` names at the positions
+    ``wave``: each becomes ``(file, 0 | errno | _READ_TOOBIG, its bytes
+    or None)``, its start and end the native read's own."""
+    n = len(wave)
+    arena, cap = _slot_arena(n)
+    lens, s0, s1 = ((ctypes.c_longlong * n)() for _ in range(3))
+    errs = (ctypes.c_int * n)()
+    _commit._wave_lib().mt_read_files(
+        (ctypes.c_char_p * n)(*(os.fsencode(got[i]) for i in wave)), n,
+        arena, cap, lens, errs, s0, s1)
+    base = ctypes.addressof(arena)
+    for j, i in enumerate(wave):
+        err = errs[j]
+        if err == _READ_TOOBIG:
+            _grow_slot(lens[j])
+        got[i] = (got[i], err,
+                  None if err else ctypes.string_at(base + j * cap, lens[j]))
+        t0s[i], t1s[i] = s0[j], s1[j]
+
+
+def _as_call(disk, fn, *args):
+    """``fn(*args)`` as a call of ``disk``: under its breaker
+    (``HealthDisk.guarded``), or plainly on a bare ``XLStorage``."""
+    guarded = getattr(type(disk), "guarded", None)
+    return guarded(disk, fn, *args) if guarded is not None else fn(*args)
+
+
+def _decode(got, volume: str, path: str, rel: str, version_id) -> FileInfo:
+    """The rest of one drive's ``read_version`` after the wave: what
+    refused its path before the read, the errno the read met, or its
+    file decoded; a file over its slot is read again whole."""
+    if isinstance(got, Exception):
+        raise got
+    full, err, buf = got
+    try:
+        if err == _READ_TOOBIG:
+            buf = _read_file(full, rel)
+        elif err:
+            raise _read_error(err, full, rel)
+    except errors.FileNotFound:
+        raise errors.FileNotFound(f"{volume}/{path}") from None
+    return XLMeta.load(buf).to_fileinfo(volume, path, version_id)
+
+
+def _observe_wave(xl: XLStorage, volume: str, path: str, e, t0: int,
+                  t1: int) -> int:
+    """One drive read here, observed as ``_traced_op`` observes a call:
+    its window and its span; the caller folds the returned ns into
+    ``mt_drive_call_seconds``."""
+    dt = t1 - t0
+    err = f"{type(e).__name__}: {e}" if e is not None else ""
+    xl.latency.record("read_version", dt, 0)
+    _publish_call(xl, "read_version", t0 + time.time_ns()
+                  - time.monotonic_ns(), dt, err, lambda: (volume, path))
+    return dt

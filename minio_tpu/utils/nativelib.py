@@ -4,8 +4,9 @@ Six subsystems carry native kernels — snappy compression
 (native/snappy.cc), HighwayHash (hashing/native/highwayhash.c), the
 GF(2^8) erasure matmul (native/gf8.cc), multi-buffer md5
 (native/md5mb.cc), the NDJSON scanner (native/jsonscan.cc) and the
-drive writer threads' syscalls (native/syncwave.c: the group commit's
-fsync waves and an op body's file landings) — the
+drive syscalls issued below the interpreter (native/syncwave.c: the
+group commit's fsync waves, an op body's file landings and a quorum
+metadata read's xl.meta read wave) — the
 roles the reference fills with assembly-accelerated Go modules
 (SURVEY.md §2.4).  They all share one loading discipline, implemented
 once here:

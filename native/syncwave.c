@@ -1,25 +1,32 @@
-// The syscalls of a drive's writer thread (storage/commit.py), each
-// group of them ONE call that never holds the interpreter lock:
+// The syscalls of a drive's writer thread (storage/commit.py) and of a
+// quorum metadata read (storage/xl_storage.py), each group of them ONE
+// call that never holds the interpreter lock:
 //
 //   * a wave of a group-commit flush (GroupCollector.flush): the fsyncs
 //     of a round's files, or of its directories, issued together;
-//   * the landing of a drive op's body (land_part / land_file, at the
-//     end of this file): a file created, written, dup'd or fsynced, and
-//     closed, for the part file behind its two mkdirs.
+//   * the landing of a drive op's body (land_part / land_file, further
+//     down): a file created, written, dup'd or fsynced, and closed, for
+//     the part file behind its two mkdirs;
+//   * a read wave (mt_read_files, at the end of this file): the xl.meta
+//     file of every local drive of a set read into a slot each, for one
+//     quorum metadata read (read_version_wave).
 //
 // Why native: under a loaded interpreter every blocking call a Python
 // thread makes ends with a wait for the GIL, so a drive's writer thread
 // that fsyncs a batch's ~40 files and directories one os.* call at a
 // time spends its wall waiting for the interpreter, not for the drive
-// (PERF.md section 6, PR 30).  Here the whole wave costs the calling
-// thread one release and one re-acquisition.
+// (PERF.md, commit_flush_ms).  A metadata read that hands each drive's
+// open / read / close to a pool thread pays a hand-over to start each
+// child and one more per syscall (PERF.md, meta_queue_ms).  Here the whole
+// wave costs the calling thread one release and one re-acquisition.
 //
-// The calls are the ones the Python loop made, for the same objects:
+// The calls are the ones the Python code made, for the same objects:
 //   files:  fsync(fd); close(fd)              errs[i] = errno of the fsync
 //   dirs:   open(O_RDONLY|O_DIRECTORY); fsync; close     errors tolerated
+//   reads:  open(O_RDONLY); fstat; read until EOF; close
 // A wave is cut into at most MT_SYNC_SLICES slices, each on a thread of
 // its own that is joined before the call returns: nothing of the wave is
-// in flight when the caller goes on to the round's continuations.
+// in flight when the caller goes on.
 
 #define _GNU_SOURCE
 #include <errno.h>
@@ -28,18 +35,28 @@
 #include <stddef.h>
 #include <sys/stat.h>
 #include <sys/types.h>
+#include <time.h>
 #include <unistd.h>
 
 #define MT_SYNC_SLICES 8
 
+typedef struct read_s read_t;    // a read wave's buffers (below)
+
 typedef struct {
     const int *fds;          // files wave (or NULL)
     const char *const *dirs; // directories wave (or NULL)
-    int *errs;               // files wave: per fd 0 or its fsync's errno
+    const read_t *rd;        // read wave (or NULL)
+    int *errs;               // files and read waves: per item 0 or errno
     int n, first, step;
 } slice_t;
 
+static void read_item(const read_t *r, int *errs, int i);
+
 static void sync_item(const slice_t *s, int i) {
+    if (s->rd) {
+        read_item(s->rd, s->errs, i);
+        return;
+    }
     if (s->fds) {
         int fd = s->fds[i];
         s->errs[i] = fsync(fd) == 0 ? 0 : errno;
@@ -58,14 +75,15 @@ static void *run_slice(void *arg) {
     return 0;
 }
 
-static void wave(const int *fds, const char *const *dirs, int n, int *errs) {
+static void wave(const int *fds, const char *const *dirs, const read_t *rd,
+                 int n, int *errs) {
     if (n <= 0) return;
     int k = n < MT_SYNC_SLICES ? n : MT_SYNC_SLICES;
     slice_t sl[MT_SYNC_SLICES];
     pthread_t th[MT_SYNC_SLICES];
     int started[MT_SYNC_SLICES];
     for (int j = 0; j < k; j++) {
-        sl[j] = (slice_t){fds, dirs, errs, n, j, k};
+        sl[j] = (slice_t){fds, dirs, rd, errs, n, j, k};
         // slice 0 runs here; a thread that cannot start runs here too
         started[j] = j > 0
             && pthread_create(&th[j], 0, run_slice, &sl[j]) == 0;
@@ -78,13 +96,13 @@ static void wave(const int *fds, const char *const *dirs, int n, int *errs) {
 
 // fsync + close every fd; errs[i] is 0 or the errno of fds[i]'s fsync.
 void mt_sync_files(const int *fds, int n, int *errs) {
-    wave(fds, 0, n, errs);
+    wave(fds, 0, 0, n, errs);
 }
 
 // open + fsync + close every directory; errors are tolerated, as
 // _fsync_dir tolerates them.
 void mt_sync_dirs(const char *const *dirs, int n) {
-    wave(0, dirs, n, 0);
+    wave(0, dirs, 0, n, 0);
 }
 
 // -- a drive op's body: one file landed per call ---------------------------
@@ -179,4 +197,88 @@ int mt_land_part(const char *obj, const char *ddir, const char *part,
     if (mkdir(ddir, 0777) < 0)
         return land_fail(out, MT_LAND_MKDIR_DDIR, errno);
     return mt_land_file(part, buf, len, sync, out);
+}
+
+// -- a quorum metadata read: one xl.meta per local drive, read together -----
+//
+// read_version on every drive of a set was 16 pool children, each of
+// which waited for a thread and the interpreter to start, then paid it
+// again after open, read and close (PERF.md, meta_queue_ms).  Here the
+// local drives' files are read by one call: item i lands in its own slot
+// of the caller's arena, arena + i * cap, and comes back with its length,
+// 0 or the errno of the step that failed, and CLOCK_MONOTONIC stamps
+// around it (the clock Python's time.monotonic_ns() reads).
+
+// errs[i] of a file larger than its slot: lens[i] is the size seen, and
+// the caller reads that one file again without a limit
+#define MT_READ_TOOBIG (-1)
+
+struct read_s {
+    const char *const *paths;
+    char *arena;
+    size_t cap;              // bytes per slot
+    long long *lens, *t0, *t1;
+};
+
+static long long mono_ns(void) {
+    struct timespec ts;
+    clock_gettime(CLOCK_MONOTONIC, &ts);
+    return (long long)ts.tv_sec * 1000000000LL + ts.tv_nsec;
+}
+
+// open, fstat, read until EOF, close: what Python's
+// open(path, "rb").read() issues, with the directory check its fstat
+// makes (EISDIR).  Returns 0, an errno or MT_READ_TOOBIG.
+static int read_file(const char *path, char *buf, size_t cap,
+                     long long *len) {
+    int fd, err = 0;
+    size_t got = 0;
+    do {
+        fd = open(path, O_RDONLY | O_CLOEXEC);
+    } while (fd < 0 && errno == EINTR);
+    if (fd < 0) return errno;
+    struct stat st;
+    if (fstat(fd, &st) < 0) {
+        err = errno;
+    } else if (S_ISDIR(st.st_mode)) {
+        err = EISDIR;
+    } else if ((unsigned long long)st.st_size > cap) {
+        got = (size_t)st.st_size;
+        err = MT_READ_TOOBIG;
+    } else {
+        for (;;) {
+            // past a full slot, one byte more tells EOF from a file that
+            // grew since its fstat
+            char probe;
+            ssize_t k = got < cap ? read(fd, buf + got, cap - got)
+                                  : read(fd, &probe, 1);
+            if (k < 0) {
+                if (errno == EINTR) continue;
+                err = errno;
+                break;
+            }
+            if (k == 0) break;
+            if (got >= cap) err = MT_READ_TOOBIG;
+            got += (size_t)k;
+            if (err) break;
+        }
+    }
+    close(fd);
+    *len = (long long)got;
+    return err;
+}
+
+static void read_item(const read_t *r, int *errs, int i) {
+    r->t0[i] = mono_ns();
+    errs[i] = read_file(r->paths[i], r->arena + (size_t)i * r->cap, r->cap,
+                        &r->lens[i]);
+    r->t1[i] = mono_ns();
+}
+
+// Read every path into its slot; per item lens[i], errs[i] and
+// t0[i] / t1[i].
+void mt_read_files(const char *const *paths, int n, char *arena, size_t cap,
+                   long long *lens, int *errs, long long *t0, long long *t1) {
+    read_t rd = {paths, arena, cap, lens, t0, t1};
+    wave(0, 0, &rd, n, errs);
 }

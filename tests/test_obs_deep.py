@@ -137,6 +137,32 @@ def test_slow_drive_flagged_not_ejected():
     assert mult == 4.0 and min_s == 10
 
 
+@pytest.mark.parametrize("fast_share", [0.2, 0.5, 0.8])
+def test_slow_drives_compare_op_by_op(fast_share):
+    """A drive is judged against the same op on its peers: one whose
+    reads of tens of us and writes of ms mix in another share than its
+    peers' has a median over all ops far from theirs and is not slow; a
+    drive 10x slower at every op is, whatever its mix."""
+    class FakeDisk:
+        def __init__(self, label, reads, writes, scale=1):
+            self.latency = lastminute.OpWindows(label)
+            for _ in range(reads):
+                self.latency.record("read_version", 30_000 * scale, 0)
+            for _ in range(writes):
+                self.latency.record("write_data_commit", 2_000_000 * scale,
+                                    0)
+
+    fast = round(20 * fast_share)
+    disks = [FakeDisk(f"d{i}", 12, 8) for i in range(3)]
+    disks += [FakeDisk("mix", fast, 20 - fast),
+              FakeDisk("slow", fast, 20 - fast, scale=10)]
+    out = health.slow_drives(disks, multiple=4.0, min_samples=10)
+    assert out["slow"]["slow"] is True
+    assert out["slow"]["median_ns"] == out["slow"]["p50_ns"] // 10
+    assert not any(out[d]["slow"] for d in ("d0", "d1", "d2", "mix"))
+    assert out["mix"]["median_ns"] == out["mix"]["p50_ns"]
+
+
 def test_slow_drives_grouped_per_set(tmp_path):
     """Detection compares a drive against its SET peers: a slow pool
     must not mask a relatively-failing drive in a fast pool."""

@@ -155,6 +155,31 @@ WORKLOAD = textwrap.dedent("""
                 with open(part, "rb") as f:
                     assert f.read() == body.tobytes()
 
+    def readwave_work():
+        # a quorum metadata read's wave (mt_read_files): files that fit
+        # their slot, fill it exactly, outgrow it; a directory; a gap
+        import tempfile
+        from minio_tpu.storage import commit, xl_storage
+        from minio_tpu.storage.xl_meta import XLMeta
+        assert commit._wave_lib() is not None, "syncwave build failed"
+        with tempfile.TemporaryDirectory() as d:
+            disks, want = [], []
+            for i in range(16):
+                root = os.path.join(d, f"d{i}")
+                os.makedirs(os.path.join(root, "bkt", "obj"))
+                disks.append(xl_storage.XLStorage(root))
+                meta = os.path.join(root, "bkt", "obj", "xl.meta")
+                if i == 14:
+                    os.mkdir(meta)
+                elif i < 14:
+                    blob = XLMeta([{"vid": "", "mt": i, "type": "object",
+                                    "data": os.urandom(i * 2000)}]).dump()
+                    with open(meta, "wb") as f:
+                        f.write(blob)
+                want.append(i < 14)
+            got = xl_storage.read_version_wave(disks, "bkt", "obj")
+            assert [fi is not None for fi, *_ in got] == want, got
+
     def run(fn):
         try:
             for _ in range(5):
@@ -164,7 +189,7 @@ WORKLOAD = textwrap.dedent("""
 
     threads = [threading.Thread(target=run, args=(f,))
                for f in (gf8_work, snappy_work, hh_work, jsonscan_work,
-                         syncwave_work)
+                         syncwave_work, readwave_work)
                for _ in range(3)]
     # one writer thread per drive of a 16-drive set, landing at once
     threads += [threading.Thread(target=run, args=(landing_work,))
