@@ -24,10 +24,11 @@ bitrot-framed shard rows; which route encodes and frames is decided
 there) and ``Erasure.reconstruct_files`` (k surviving shard files ->
 the wanted ones, degraded GET and heal).  On a device backend a PUT's
 full blocks cross the link once: the stripes go up, parity and the k+m
-bitrot digests come down from ONE fused program per stripe that rides
-the combiner (ops/rs_fused.py on one chip, ops/rs_mesh.py over a
-mesh), and the rows are framed on the host through views.  On one chip
-a tail block (every object under a block) keeps the two-dispatch route:
+bitrot digests come down from fused programs that ride the combiner
+(ops/rs_fused.py on one chip: one per stripe, or one per stripe group
+for a body of several blocks; ops/rs_mesh.py over a mesh), and the rows
+are framed on the host through views.  On one chip a tail block
+(every object under a block) keeps the two-dispatch route:
 parity through ``rs_kernels``, then the device bitrot leg
 (``_streaming_encode_batch_device``, here next to the kernels it
 drives); ``Erasure.encode_framed`` says why.
@@ -513,9 +514,10 @@ class Erasure:
           * ``tpu`` + HighwayHash256S, a body of a block or more
             (:meth:`_encode_framed_chip`): the same contract on one
             chip for the full blocks — the stripes up, parity and the
-            k+m digests down from ONE fused program per stripe
-            (ops/rs_fused.py), framed on the host through views — and
-            the route below for a tail block; counted and timed as ONE
+            k+m digests down from fused programs (ops/rs_fused.py): ONE
+            per stripe for a body of one block, ONE per stripe group of
+            G for a body of several — framed on the host through views —
+            and the route below for a tail block; counted and timed as ONE
             ``encode`` dispatch of the body's bytes too;
           * ``numpy`` with both native libraries: shard bytes and parity
             land once in the framed layout, digests filled in place by
@@ -569,35 +571,44 @@ class Erasure:
             return _streaming_encode_batch_device(shards, ss)
 
     def _encode_bitrot(self, staged: np.ndarray):
-        """(parity per stripe, digests) of staged full-block stripes
-        (``rs_fused.launch_encode_bitrot``'s contract), shared with
-        concurrent PUTs through the combiner's ``encode-bitrot`` bucket
-        when the batcher is on (stripes are batch-axis independent, so
-        each caller's slice is what it would get alone)."""
+        """(parity per stripe, digests) of one body's staged full-block
+        stripes (``rs_fused.launch_encode_bitrot``'s contract), shared
+        with concurrent PUTs through the combiner when the batcher is on
+        (stripes are batch-axis independent, so each caller's slice is
+        what it would get alone).  One block goes out one stripe per
+        program, from the ``encode-bitrot`` bucket; several go out in
+        stripe groups (``rs_fused.launch_encode_bitrot_groups``), from
+        the ``encode-bitrot-group`` bucket, where the stripes of the
+        bodies that meet fill the groups together."""
         from . import rs_fused
-        rows = np.asarray(self.matrix)[self.data_blocks:]
+        k, m = self.data_blocks, self.parity_blocks
+        rows = np.asarray(self.matrix)[k:]
         n = self.shard_size()
+        op, launch = "encode-bitrot", rs_fused.launch_encode_bitrot
+        if staged.shape[0] > 1 and rs_fused.group_plan(k, m, n)["bs"] > 1:
+            op, launch = ("encode-bitrot-group",
+                          rs_fused.launch_encode_bitrot_groups)
         b = _batcher(self)
         if b is None:
-            return rs_fused.launch_encode_bitrot(rows, staged, n)()
-        return b.submit(
-            self, "encode-bitrot", rows, staged,
-            fn=lambda rows, cat: rs_fused.launch_encode_bitrot(
-                rows, cat, n)())
+            return launch(rows, staged, n)()
+        return b.submit(self, op, rows, staged,
+                        fn=lambda rows, cat: launch(rows, cat, n)())
 
     def _encode_framed_chip(self, data, digest: int = 32) -> np.ndarray:
         """The one-chip device route of :meth:`encode_framed` for a body
         of a block or more: (k+m, framed_len) uint8, bit-identical to
         the host streaming-bitrot layout.  All full blocks go in one
-        fused submission.  ``encode.prep`` is the one copy of their
-        bytes into staged stripes (row i of a stripe holds bytes
-        [i*width, (i+1)*width) of its block, zeros after the block's
-        end and up to the kernel's lane tile: no program pads); the
-        dispatch's own legs are rs_fused's; ``hash.frame`` lands
-        payloads and digests in the on-disk layout through views, one
-        copy each.  A tail block takes the two-dispatch route
-        (``encode_data``, then the device bitrot leg) and lands behind
-        them."""
+        fused submission (:meth:`_encode_bitrot`: one block as one
+        stripe, several as stripe groups).  ``encode.prep`` is the one
+        copy of their bytes into staged stripes (row i of a stripe holds
+        bytes [i*width, (i+1)*width) of its block, zeros after the
+        block's end and up to the kernel's lane tile: no program pads);
+        the dispatch's own legs are rs_fused's; ``hash.frame`` lands
+        payloads, each stripe's parity (a view of its group's when the
+        stripes went as groups) and the digests in the on-disk layout
+        through views, one copy each.  A tail block takes the
+        two-dispatch route (``encode_data``, then the device bitrot
+        leg) and lands behind them."""
         from . import rs_fused
         k, m = self.data_blocks, self.parity_blocks
         bs, width = self.block_size, self.shard_size()

@@ -34,14 +34,18 @@ magic key (tests/test_fused_kernel.py pins ragged geometries, tails
 and the k/m matrix from the BASELINE configs).
 
 Two callers.  On one chip this is the route of a PUT's full blocks
-(``launch_encode_bitrot``, called by ``Erasure.encode_framed`` on
-``--backend tpu``): the kernel, the plane reassembly, the remainder
-packet and finalization are ONE compiled program per block width
-(``jit_mt_encode_bitrot``), the host stages the stripes at the plan's
-lane tile so nothing is padded on the device, and a batch goes out one
-stripe per dispatch.  Over a mesh ops/rs_mesh.py calls the kernel
-inside its sharded program.  Off a TPU the same contract runs in the
-XLA forms (``_encode_bitrot_xla``), so tier-1 drives the route.
+(called by ``Erasure.encode_framed`` on ``--backend tpu``): the kernel,
+the plane reassembly, the remainder packet and finalization are ONE
+compiled program per operand shape (``jit_mt_encode_bitrot``), and the
+host stages the stripes at the plan's lane tile so nothing is padded on
+the device.  A body of one full block goes out one stripe per dispatch
+(``launch_encode_bitrot``); a body of several goes out in stripe groups
+(``launch_encode_bitrot_groups``): G stripes per dispatch, G fixed per
+geometry and width by ``group_plan`` (as many stripes as lay their
+shards into one 128-lane hash row: 10 at 8+4), so the hash chain runs
+once for G stripes.  Over a mesh ops/rs_mesh.py calls the kernel inside
+its sharded program.  Off a TPU the same contract runs in the XLA forms
+(``_encode_bitrot_xla``), so tier-1 drives the route.
 """
 
 from __future__ import annotations
@@ -89,6 +93,19 @@ def plan(B: int, k: int, ro: int, n: int,
     n_pad = -(-n // tn) * tn
     return {"R": R, "gs": gs, "bs": bs, "B_pad": B_pad, "S": S,
             "tn": tn, "n_pad": n_pad, "pc": tn // 32}
+
+
+def group_plan(k: int, ro: int, n: int) -> dict:
+    """The plan of one stripe-group program at shard width n: G = bs
+    stripes in ONE row-block, as many as lay their k+ro hash lanes into
+    one 128-lane row (S = 1, the hash chain of a single stripe), with
+    the largest block-diagonal sub-group (4, 2 or 1 stripes) that
+    divides G.  G is 1 when one stripe fills the row."""
+    G = max(1, 128 // (k + ro))
+    p = plan(1, k, ro, n)
+    p.update(gs=max(g for g in (1, 2, rs_pallas._GS) if G % g == 0),
+             bs=G, B_pad=G)
+    return p
 
 
 def hashed_lanes(p: dict) -> int:
@@ -271,7 +288,9 @@ def _encode_bitrot(mat_bd, shards, *, gs: int, n_real: int):
     (my chip runs, PR 34: (2, 2, 5 MiB) in 57 ms, flat in 6.3)."""
     B, k, _ = shards.shape
     ro = mat_bd.shape[0] // (8 * gs)
-    p = plan(B, k, ro, n_real)
+    # one stripe, or one stripe group: all B stripes in one row-block
+    p = plan(1, k, ro, n_real) if B == 1 else group_plan(k, ro, n_real)
+    assert p["B_pad"] == B and p["gs"] == gs, (B, gs, p)
     parity, planes = _fused_call(
         mat_bd, shards, k=k, ro=ro, gs=gs, bs=p["bs"], S=p["S"],
         pc=p["pc"], n_packets=n_real // 32, hash_parity=True)
@@ -301,14 +320,42 @@ def staged_width(k: int, ro: int, n: int) -> int:
     return plan(1, k, ro, n)["n_pad"] if device.use_pallas() else n
 
 
+def _program(M: np.ndarray, p: dict, n: int):
+    """(the call of the one-chip program under plan ``p`` with the
+    matrix bound, the hash lanes one call runs) in the form in force."""
+    ro, k = M.shape
+    if device.use_pallas():
+        return functools.partial(
+            _encode_bitrot,
+            rs_pallas._device_matrix_bd(M.tobytes(), ro, k, p["gs"]),
+            gs=p["gs"], n_real=n), hashed_lanes(p)
+    return (functools.partial(_encode_bitrot_xla, rs_kernels._put_matrix(M)),
+            hk.hashed_rows(p["B_pad"] * (k + ro), n))
+
+
+def _count(form: str, programs: int, stripes: int, rows: int,
+           hashed: int) -> None:
+    """What one launch call ran: programs and the stripes they carry
+    (``form`` ``stripe`` or ``group``), and the hash lanes asked for
+    (``rows`` digests per stripe) against those the programs run."""
+    _metrics.inc("mt_tpu_fused_programs_total", {"form": form},
+                 float(programs))
+    _metrics.inc("mt_tpu_fused_stripes_total", {"form": form},
+                 float(stripes))
+    _metrics.inc("mt_tpu_hash_rows_total", {"kind": "real"},
+                 float(stripes * rows))
+    _metrics.inc("mt_tpu_hash_rows_total", {"kind": "hashed"},
+                 float(programs * hashed))
+
+
 def launch_encode_bitrot(M: np.ndarray, staged: np.ndarray, n: int):
-    """The one-chip PUT dispatch: (B, k, staged_width) stripes of real
-    width ``n`` go up (``encode.upload``), ONE program per stripe is
-    launched (``encode.launch``, until its handles are held), and the
-    hash lanes it runs are counted against the k+ro digests per stripe
-    it is asked for.  Returns the call that lands the results
-    (``encode.fetch``): (parity, one (ro, staged width) array per
-    stripe; digests (B, k+ro, 32)) on the host.
+    """The one-chip PUT dispatch of one-block bodies: (B, k,
+    staged_width) stripes of real width ``n`` go up (``encode.upload``),
+    ONE program per stripe is launched (``encode.launch``, until its
+    handles are held), and the hash lanes it runs are counted against
+    the k+ro digests per stripe it is asked for.  Returns the call that
+    lands the results (``encode.fetch``): (parity, one (ro, staged
+    width) array per stripe; digests (B, k+ro, 32)) on the host.
 
     One stripe per dispatch whatever batch the combiner formed: a
     program's row-block is static, so every batch size would be a
@@ -319,26 +366,13 @@ def launch_encode_bitrot(M: np.ndarray, staged: np.ndarray, n: int):
     M = np.ascontiguousarray(M, dtype=np.uint8)
     ro, k = M.shape
     B, _, width = staged.shape
-    if device.use_pallas():
-        p = plan(1, k, ro, n)
-        call = functools.partial(
-            _encode_bitrot,
-            rs_pallas._device_matrix_bd(M.tobytes(), ro, k, p["gs"]),
-            gs=p["gs"], n_real=n)
-        hashed = hashed_lanes(p)
-    else:
-        call = functools.partial(_encode_bitrot_xla,
-                                 rs_kernels._put_matrix(M))
-        hashed = hk.hashed_rows(k + ro, n)
+    call, hashed = _program(M, plan(1, k, ro, n), n)
     handles = []
     for b in range(B):
         dev = device.upload("encode", staged[b:b + 1])
         with _trace.span("tpu", "encode.launch", nbytes=dev.nbytes):
             handles.append(call(dev))
-    _metrics.inc("mt_tpu_hash_rows_total", {"kind": "real"},
-                 float(B * (k + ro)))
-    _metrics.inc("mt_tpu_hash_rows_total", {"kind": "hashed"},
-                 float(B * hashed))
+    _count("stripe", B, B, k + ro, hashed)
 
     def land():
         parity = [device.fetch("encode", par).reshape(ro, width)
@@ -346,6 +380,72 @@ def launch_encode_bitrot(M: np.ndarray, staged: np.ndarray, n: int):
         digests = np.stack([device.fetch("encode", dig)
                             for _, dig in handles])
         return parity, digests.reshape(B, k + ro, 32)
+
+    return land
+
+
+@functools.lru_cache(maxsize=16)
+def _zero_stripe(k: int, width: int) -> jax.Array:
+    """One all-zero stripe made on the device: what completes a partial
+    group there, so its padding never crosses the link."""
+    return jnp.zeros((1, k, width), jnp.uint8)
+
+
+@device.named_jit("mt_group_stage")
+def _group_stage(*stripes):
+    """G single-stripe arrays -> the (G, k, width) group operand."""
+    return jnp.concatenate(stripes, axis=0)
+
+
+@device.named_jit("mt_group_split", static_argnames=("G",))
+def _group_split(parity, digests, *, G: int):
+    """A group program's flat results -> G per-stripe pieces of each."""
+    return jnp.split(parity, G), jnp.split(digests, G)
+
+
+def launch_encode_bitrot_groups(M: np.ndarray, staged: np.ndarray,
+                                n: int):
+    """:func:`launch_encode_bitrot`'s contract for the stripes of bodies
+    of several full blocks: the stripes go out in groups of G
+    (``group_plan``), ONE program per group and all of them at one
+    shape per width, whatever the batch or the bodies.  A whole group
+    goes up as one array and its parity and digests come down in two
+    fetches.  A last partial group of r < G goes up as its r stripes,
+    is completed with zero stripes on the device (``mt_group_stage``)
+    and its results are split per stripe there (``mt_group_split``), so
+    the link carries the r stripes both ways and no padding."""
+    M = np.ascontiguousarray(M, dtype=np.uint8)
+    ro, k = M.shape
+    B, _, width = staged.shape
+    p = group_plan(k, ro, n)
+    G = p["bs"]
+    call, hashed = _program(M, p, n)
+    whole, r = divmod(B, G)
+    handles = []
+    for g in range(whole):
+        dev = device.upload("encode", staged[g * G:(g + 1) * G])
+        with _trace.span("tpu", "encode.launch", nbytes=dev.nbytes):
+            handles.append(call(dev))
+    if r:
+        pieces = [device.upload("encode", staged[b:b + 1])
+                  for b in range(whole * G, B)]
+        with _trace.span("tpu", "encode.launch",
+                         nbytes=r * pieces[0].nbytes):
+            pieces += [_zero_stripe(k, width)] * (G - r)
+            handles.append(_group_split(*call(_group_stage(*pieces)), G=G))
+    _count("group", len(handles), B, k + ro, hashed)
+
+    def land():
+        parity, digests = [], []
+        for par, dig in handles[:whole]:
+            parity.extend(device.fetch("encode", par).reshape(G, ro, width))
+            digests.append(device.fetch("encode", dig))
+        if r:
+            par, dig = handles[-1]
+            parity.extend(device.fetch("encode", x).reshape(ro, width)
+                          for x in par[:r])
+            digests.extend(device.fetch("encode", x) for x in dig[:r])
+        return parity, np.concatenate(digests).reshape(B, k + ro, 32)
 
     return land
 

@@ -1,15 +1,18 @@
 """The one-chip PUT route (ISSUE 35): on ``backend="tpu"`` the full
 blocks of ``Erasure.encode_framed``'s body cross the link once — data
-stripes up, parity and the k+m digests down from ONE fused program per
-stripe (``rs_fused.launch_encode_bitrot``, program ``mt_encode_bitrot``)
-— through the combiner's ``encode-bitrot`` bucket (ops/codec.py); a tail
-block keeps the two-dispatch route (ISSUE 35's fallback, taken by the
-rule it gives: the shape decides).  Here on
+stripes up, parity and the k+m digests down from fused programs
+(program ``mt_encode_bitrot``): ONE per stripe for a body of one block
+(``rs_fused.launch_encode_bitrot``, the combiner's ``encode-bitrot``
+bucket), ONE per stripe group for a body of several
+(``rs_fused.launch_encode_bitrot_groups``, the ``encode-bitrot-group``
+bucket; ops/codec.py); a tail block keeps the two-dispatch route (the
+shape decides; ``Erasure.encode_framed`` says why).  Here on
 XLA:CPU the route runs in its XLA forms (tests/conftest.py); the Pallas
 program is run interpreted where a case says so.  Pinned: the rows on
 disk against the host one-copy route and the plain reference, who
-shares a dispatch with whom, that a batch goes out one stripe per
-dispatch, and what the dispatch counts.
+shares a dispatch with whom, that a batch of one-block bodies goes out
+one stripe per dispatch, how a body of several goes out in groups, and
+what the dispatches count.
 """
 
 import threading
@@ -162,6 +165,10 @@ def _counters() -> dict:
             ("mt_tpu_ops_total", (("backend", "tpu"), ("op", op))), 0)
     out["bytes"] = snap.get(
         ("mt_tpu_bytes_total", (("backend", "tpu"), ("op", "encode"))), 0)
+    for form in ("stripe", "group"):
+        for what in ("programs", "stripes"):
+            out[f"{what}.{form}"] = snap.get(
+                (f"mt_tpu_fused_{what}_total", (("form", form),)), 0)
     return out
 
 
@@ -173,8 +180,10 @@ def test_a_put_is_one_encode_dispatch_that_counts_what_it_moved(
     the link carries the staged (padded) data up and parity + digests
     down, once each, with no ``hash`` dispatch; the hash lanes come from
     the plan of the form in force; the legs are the ones the benchmark
-    reads.  A tail block adds its own RS upload and ONE ``hash``
-    dispatch (the two-dispatch route it keeps)."""
+    reads.  Two full blocks are one partial stripe group: one program,
+    its zero stripes made on the device and never on the link.  A tail
+    block adds its own RS upload and ONE ``hash`` dispatch (the
+    two-dispatch route it keeps)."""
     monkeypatch.setattr(device, "use_pallas", lambda: pallas)
     k, m, bs, ss = 2, 2, 2 * 96, 96
     codec = Erasure(k, m, bs, backend="tpu")
@@ -186,13 +195,16 @@ def test_a_put_is_one_encode_dispatch_that_counts_what_it_moved(
     assert [bytes(r) for r in rows] == \
         shard_files.reference_framed(data, bs, k, m)
     moved = {key: v - before[key] for key, v in _counters().items()}
-    # ONE fused submission of the two full blocks, one stripe each
+    # ONE fused submission of the two full blocks: one group program of
+    # G = 32 stripes at 2+2, two of them real
     w_full = rs_fused.staged_width(k, m, ss)
+    p = rs_fused.group_plan(k, m, ss)
+    assert p["bs"] == 32
     if pallas:
-        lanes = 2 * rs_fused.hashed_lanes(rs_fused.plan(1, k, m, ss))
-        assert (w_full, lanes) == (256, 2 * 128)
+        lanes = rs_fused.hashed_lanes(p)
+        assert (w_full, lanes) == (256, 128)
     else:
-        lanes = 2 * (k + m)
+        lanes = 32 * (k + m)
         assert w_full == ss
     up = 2 * k * w_full
     down = 2 * m * w_full + 2 * (k + m) * 32
@@ -204,14 +216,147 @@ def test_a_put_is_one_encode_dispatch_that_counts_what_it_moved(
     assert moved == {
         "h2d": up, "d2h": down,
         "real": (2 + bool(tail)) * (k + m), "hashed": lanes,
-        "ops.encode": 1, "ops.hash": int(bool(tail)), "bytes": len(data)}
+        "ops.encode": 1, "ops.hash": int(bool(tail)), "bytes": len(data),
+        "programs.stripe": 0, "stripes.stripe": 0,
+        "programs.group": 1, "stripes.group": 2}
     legs = [s.get("funcName") or "" for s in spans]
     for leg in ("encode.prep", "encode.upload", "encode.launch",
                 "encode.fetch", "hash.frame", "encode.dispatch",
-                "encode-bitrot.batch"):
+                "encode-bitrot-group.batch"):
         assert leg in legs, (leg, legs)
     assert bool([leg for leg in legs if leg.startswith("hash.")
                  and leg != "hash.frame"]) == bool(tail)
+
+
+def _spy(monkeypatch) -> list:
+    """Record every upload (its stripes), fetch and program call of the
+    one-chip route, in order."""
+    events: list = []
+    upload, fetch = device.upload, device.fetch
+
+    def up(op, x):
+        events.append(("up", x.shape[0]))
+        return upload(op, x)
+
+    def down(op, x, rows=None):
+        events.append(("down", None))
+        return fetch(op, x, rows)
+
+    monkeypatch.setattr(device, "upload", up)
+    monkeypatch.setattr(device, "fetch", down)
+    for name in ("_encode_bitrot", "_encode_bitrot_xla", "_group_stage",
+                 "_group_split"):
+        def call(*a, _fn=getattr(rs_fused, name), _name=name, **kw):
+            events.append((_name, a[-1].shape[0]
+                           if _name.startswith("_encode") else None))
+            return _fn(*a, **kw)
+        monkeypatch.setattr(rs_fused, name, call)
+    return events
+
+
+@pytest.mark.parametrize("pallas", [False, True], ids=["xla", "pallas"])
+def test_one_block_takes_the_one_stripe_program_as_before(
+        pallas, monkeypatch):
+    """A body of ONE full block (every body of the 10 MiB cells) makes
+    what it made before stripe groups existed: its stripe up as one
+    array, ONE call of the one-stripe program, parity then digests down,
+    the ``encode-bitrot`` bucket, and the stripe form's counters; no
+    group program, stage or split runs."""
+    monkeypatch.setattr(device, "use_pallas", lambda: pallas)
+    k, m, ss = 8, 4, 96
+    codec = Erasure(k, m, k * ss, backend="tpu")
+    data = _body(k * ss, 35)
+    before = _counters()
+    events = _spy(monkeypatch)
+    with trace.HTTP_TRACE.subscribe() as sub:
+        rows = codec.encode_framed(data, bitrot.HIGHWAYHASH256S)
+        spans = list(sub.drain(80, timeout=2.0))
+    assert [bytes(r) for r in rows] == \
+        shard_files.reference_framed(data, k * ss, k, m)
+    program = "_encode_bitrot" if pallas else "_encode_bitrot_xla"
+    assert events == [("up", 1), (program, 1), ("down", None),
+                      ("down", None)]
+    w = rs_fused.staged_width(k, m, ss)
+    moved = {key: v - before[key] for key, v in _counters().items()}
+    assert moved == {
+        "h2d": k * w, "d2h": m * w + (k + m) * 32,
+        "real": k + m, "hashed": 128 if pallas else k + m,
+        "ops.encode": 1, "ops.hash": 0, "bytes": len(data),
+        "programs.stripe": 1, "stripes.stripe": 1,
+        "programs.group": 0, "stripes.group": 0}
+    legs = [s.get("funcName") or "" for s in spans]
+    assert legs.count("encode.launch") == 1
+    assert "encode-bitrot.batch" in legs
+    assert not [leg for leg in legs if "group" in leg]
+
+
+# stripe groups: k+m = 12 shards lay 10 stripes into one 128-lane row
+GK, GM, GSS = 8, 4, 96
+G = 10
+
+
+def _group_body(nfull: int, seed: int) -> bytes:
+    return _body(nfull * GK * GSS, seed)
+
+
+@pytest.mark.parametrize("nfull", [2, G - 1, G, G + 1, 2 * G + 3])
+@pytest.mark.parametrize("pallas", [False, True], ids=["xla", "pallas"])
+def test_a_body_of_several_blocks_goes_out_in_stripe_groups(
+        pallas, nfull, monkeypatch):
+    """nfull full blocks are ceil(nfull / G) programs of the ONE group
+    shape: a whole group up as one array and down in two fetches; a
+    last partial group of r up as its r stripes, completed and split on
+    the device, its r stripes' results down, so the link carries no
+    padding either way; the rows are the plain reference's."""
+    monkeypatch.setattr(device, "use_pallas", lambda: pallas)
+    codec = Erasure(GK, GM, GK * GSS, backend="tpu")
+    p = rs_fused.group_plan(GK, GM, GSS)
+    assert (p["bs"], p["gs"], p["S"]) == (G, 2, 1)
+    data = _group_body(nfull, nfull)
+    before = _counters()
+    events = _spy(monkeypatch)
+    rows = codec.encode_framed(data, bitrot.HIGHWAYHASH256S)
+    assert [bytes(r) for r in rows] == \
+        shard_files.reference_framed(data, GK * GSS, GK, GM)
+    whole, r = divmod(nfull, G)
+    program = "_encode_bitrot" if pallas else "_encode_bitrot_xla"
+    want = [("up", G), (program, G)] * whole
+    if r:
+        want += [("up", 1)] * r + [("_group_stage", None), (program, G),
+                                   ("_group_split", None)]
+    want += [("down", None)] * (2 * whole + 2 * r)
+    assert events == want
+    w = rs_fused.staged_width(GK, GM, GSS)
+    programs = whole + bool(r)
+    moved = {key: v - before[key] for key, v in _counters().items()}
+    assert moved == {
+        "h2d": nfull * GK * w, "d2h": nfull * (GM * w + (GK + GM) * 32),
+        "real": nfull * (GK + GM),
+        "hashed": programs * (128 if pallas else G * (GK + GM)),
+        "ops.encode": 1, "ops.hash": 0, "bytes": len(data),
+        "programs.stripe": 0, "stripes.stripe": 0,
+        "programs.group": programs, "stripes.group": nfull}
+
+
+def test_bodies_that_meet_fill_stripe_groups_together(combining):
+    """Three callers with bodies of four blocks each meet in the
+    ``encode-bitrot-group`` bucket: their twelve stripes fill groups
+    together and each caller gets the rows of its own body."""
+    codec = batcher.codec_for(GK, GM, GK * GSS, "tpu")
+    bodies = [_group_body(4, 40 + i) for i in range(3)]
+    codec.encode_framed(bodies[0], bitrot.HIGHWAYHASH256S)   # built
+    before, c0 = batcher.GLOBAL.snapshot(), _counters()
+    got = _together(3, lambda i: codec.encode_framed(
+        bodies[i], bitrot.HIGHWAYHASH256S))
+    after, c1 = batcher.GLOBAL.snapshot(), _counters()
+    assert after["requests"] - before["requests"] == 3
+    assert after["dispatches"] - before["dispatches"] < 3
+    assert c1["stripes.group"] - c0["stripes.group"] == 12
+    assert c1["programs.group"] - c0["programs.group"] < 3
+    assert c1["programs.stripe"] == c0["programs.stripe"]
+    for i, body in enumerate(bodies):
+        assert [bytes(r) for r in got[i]] == shard_files.reference_framed(
+            body, GK * GSS, GK, GM), f"caller {i}"
 
 
 def test_a_new_block_width_is_one_compiled_program():
@@ -235,5 +380,19 @@ def test_a_new_block_width_is_one_compiled_program():
                   v[1] - before.get(name, (0, 0))[1])
            for name, v in after.items() if v != before.get(name, (0, 0))}
     assert new == {"mt_encode_bitrot": (1, 1)}
-    codec.encode_framed(_body(2 * 5332, 3), bitrot.HIGHWAYHASH256S)
+    codec.encode_framed(_body(5332, 3), bitrot.HIGHWAYHASH256S)
     assert rows() == after
+    # bodies of several blocks: ONE group program of the width, its
+    # stage and split, built by the first; none for other block counts
+    codec.encode_framed(_body(2 * 5332, 4), bitrot.HIGHWAYHASH256S)
+    grouped = rows()
+    assert {name: (v[0] - after.get(name, (0, 0))[0],
+                   v[1] - after.get(name, (0, 0))[1])
+            for name, v in grouped.items()
+            if v != after.get(name, (0, 0)) and v[1]} == {
+        "mt_encode_bitrot": (1, 1), "mt_group_stage": (1, 1),
+        "mt_group_split": (1, 1)}
+    for nfull in (3, 21, 45):
+        codec.encode_framed(_body(nfull * 5332, nfull),
+                            bitrot.HIGHWAYHASH256S)
+    assert rows() == grouped

@@ -96,6 +96,34 @@ def test_single_chip_kernels_compile(topo, bs):
             gs=p["gs"], n_real=n).compile()
 
 
+@pytest.mark.parametrize("k,m,bs", [(8, 4, 1 << 20), (12, 4, 10 << 20),
+                                    (2, 2, 10 << 20)],
+                         ids=["8+4-1MiB", "12+4-10MiB", "2+2-10MiB"])
+def test_stripe_group_program_compiles(topo, k, m, bs):
+    """A body of several full blocks: ONE program per stripe group
+    (``rs_fused.group_plan``: 10 / 8 / 32 stripes in one 128-lane row),
+    and the two small programs that complete a partial group on the
+    device and split its results per stripe."""
+    sh = SingleDeviceSharding(topo.devices[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    n = gf8.shard_size(bs, k)
+    p = rs_fused.group_plan(k, m, n)
+    G = p["bs"]
+    assert p["S"] == 1 and G * (k + m) <= 128
+    rs_fused._encode_bitrot.lower(
+        spec((p["gs"] * 8 * m, p["gs"] * 8 * k), jnp.int8),
+        spec((G, k, p["n_pad"]), jnp.uint8),
+        gs=p["gs"], n_real=n).compile()
+    rs_fused._group_stage.lower(
+        *[spec((1, k, p["n_pad"]), jnp.uint8)] * G).compile()
+    rs_fused._group_split.lower(
+        spec((G * m * p["n_pad"],), jnp.uint8),
+        spec((G * (k + m) * 32,), jnp.uint8), G=G).compile()
+
+
 @pytest.mark.parametrize("bs", BLOCK_SIZES)
 def test_mesh_1x4_forms_compile(topo, bs):
     """rs_mesh's shard_map forms on a 1x4 (stripe x shard) mesh: k=12

@@ -560,8 +560,10 @@ def test_device_put_counts_legs_and_link_bytes(tmp_path):
     mt_tpu_leg_seconds and mt_tpu_link_bytes_total by exactly what the
     code dispatches: 2+2, 64 KiB blocks, 200,000 B = 3 full blocks and
     a 3,392 B tail.  The full blocks cross the link once: ONE fused
-    encode+bitrot submission that goes out as three single-stripe
-    dispatches.  The tail block keeps the two-dispatch route: its RS
+    encode+bitrot submission that goes out as ONE stripe-group program
+    (3 of G = 32 stripes at 2+2: each real stripe up as one array, the
+    zero stripes made on the device, each real stripe's results down).
+    The tail block keeps the two-dispatch route: its RS
     dispatch, then the device bitrot leg.  Link bytes are array nbytes,
     padding included (the XLA forms stage a fused stripe at its exact
     width)."""
@@ -578,15 +580,16 @@ def test_device_put_counts_legs_and_link_bytes(tmp_path):
     legs = {k: v - legs0.get(k, 0) for k, v in _leg_counts().items()}
     ctr = {k: v - ctr0.get(k, 0) for k, v in _tpu_counters().items()}
 
-    # fused: per stripe one upload, one launch, two fetches (parity,
-    # digests); the tail's RS dispatch: one of each
+    # fused: per real stripe one upload and two fetches (parity,
+    # digests), one launch for the group; the tail's RS dispatch: one
+    # of each
     assert legs[("encode", "upload")] == 3 + 1, legs
-    assert legs[("encode", "launch")] == 3 + 1, legs
+    assert legs[("encode", "launch")] == 1 + 1, legs
     assert legs[("encode", "fetch")] == 6 + 1, legs
     assert legs[("encode", "dispatch")] == 1, legs
     # the staged full blocks; the tail's split and its lane pad
     assert legs[("encode", "prep")] == 3, legs
-    assert legs[("encode-bitrot", "batch")] == 1, legs
+    assert legs[("encode-bitrot-group", "batch")] == 1, legs
     assert legs[("encode", "batch")] == 1, legs
     # the tail's device bitrot leg, and the two framings
     for leg in ("dispatch", "prep", "upload", "launch", "fetch"):
@@ -611,10 +614,10 @@ def test_device_put_counts_legs_and_link_bytes(tmp_path):
         (k + m) * tail_shard
     assert ctr[("mt_tpu_ops_total", "encode", "tpu")] == 1
     assert ctr[("mt_tpu_bytes_total", "encode", "tpu")] == 200_000
-    # rows: 4 shards per fused stripe, then the 4 tail rows; the XLA
-    # forms hash what they are handed
-    for kind in ("real", "hashed"):
-        assert ctr[("mt_tpu_hash_rows_total", None, kind)] == 16
+    # rows: 4 shards per real fused stripe, then the 4 tail rows; the
+    # XLA forms hash what they are handed: the group's 32 stripes
+    assert ctr[("mt_tpu_hash_rows_total", None, "real")] == 16
+    assert ctr[("mt_tpu_hash_rows_total", None, "hashed")] == 32 * 4 + 4
     # and what it wrote reads back
     assert bytes(layer.get_object("linkb", "obj")[1]) == b"k" * 200_000
 

@@ -413,7 +413,7 @@ def test_device_put_leaves_leg_spans_under_its_root(served_device):
     want = {"encode.dispatch", "encode.prep", "encode.upload",
             "encode.launch", "encode.fetch", "hash.dispatch",
             "hash.prep", "hash.upload", "hash.launch", "hash.fetch",
-            "hash.frame", "encode-bitrot.batch"}
+            "hash.frame", "encode-bitrot-group.batch"}
     assert want <= names, sorted(want - names)
     for ch in kids:
         assert ch["parentID"] == rid and ch["requestID"] == rid, ch

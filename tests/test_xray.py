@@ -390,13 +390,13 @@ def test_legs_leave_the_serial_vector_alone(device_layer, size):
     # both whole dispatches ran inside stage encode, on this thread: one
     # after the other for a body under a block (256 KiB here), the
     # tail's bitrot leg INSIDE the encode dispatch for a longer one
-    # (its full blocks take the fused route)
+    # (its four full blocks take the fused route, as one stripe group)
     whole = by_name["encode.dispatch"]
     if size < 256 * 1024:
         whole += by_name["hash.dispatch"]
     else:
         assert by_name["hash.dispatch"] <= by_name["encode.dispatch"]
-        assert "encode-bitrot.batch" in by_name, by_name
+        assert "encode-bitrot-group.batch" in by_name, by_name
     assert whole <= serial["encode"] + serial.get("batch_wait", 0)
 
 
