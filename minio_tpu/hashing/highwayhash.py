@@ -285,6 +285,17 @@ def hh256_verify_framed(framed, block_size: int,
         key, arr.ctypes.data_as(ctypes.c_void_p), arr.size, block_size))
 
 
+def verify_framed_address() -> int | None:
+    """The address of the native ``mt_hh256_verify_framed`` (what
+    :func:`hh256_verify_framed` calls), for a native caller that checks
+    frames below the interpreter (storage/xl_storage.py
+    read_shard_wave); None without the native library."""
+    lib = _get_lib()
+    if lib is None:
+        return None
+    return ctypes.cast(lib.mt_hh256_verify_framed, ctypes.c_void_p).value
+
+
 def hh256_frame(data, block_size: int, key: bytes = MAGIC_KEY) -> bytes:
     """Frame a shard file (hash || block per block) in ONE native pass.
 

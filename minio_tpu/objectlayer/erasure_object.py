@@ -1636,31 +1636,49 @@ class ErasureObjects(MultipartOps, ObjectLayer):
                              algo: str) -> list:
         """Read one block-batch's byte range from k healthy shards,
         extending into parity on failure; returns a length-n list with
-        np arrays at the indices read."""
+        np arrays at the indices read.
+
+        Each round of k candidates is one fan-out.  Its local drives
+        (``xl_storage.shard_wave_positions``: local and online, the
+        native library loaded, no group collector on this thread, no
+        O_DIRECT reads) are read on THIS thread, after the other drives'
+        children are submitted, so it overlaps them: their part files
+        and packed extents by ONE native call that reads, verifies and
+        gathers every window (``xl_storage.read_shard_wave``), their
+        inline shards here in Python.  ``mt_read_get_drives_total
+        {route=wave|pool}`` counts the drives each route read."""
         k, m = fi.erasure.data_blocks, fi.erasure.parity_blocks
         nsh = k + m
+        part_path = f"{object_name}/{fi.data_dir}/part.{part.number}"
 
-        def read_one(j):
-            disk = shuffled[j]
+        def source(j):
+            """Where shard j's window is: None for inline data, else the
+            drive call that reads it (xl_storage.read_shard_wave's item)."""
             dfi = sfis[j]
-            if disk is None:
-                raise serrors.DiskNotFound("offline")
             if dfi is not None and dfi.inline_data is not None:
-                framed = dfi.inline_data[framed_off:framed_off + framed_len]
-                if len(framed) < framed_len:
-                    raise serrors.FileCorrupt("short inline data")
-            elif dfi is not None and getattr(dfi, "seg", None):
+                return None
+            if dfi is not None and getattr(dfi, "seg", None):
                 # packed object: the framed shard lives at an extent
                 # inside the drive's segment file (storage/commit.py);
                 # same window arithmetic, different backing file
-                framed = disk.read_segment(
-                    dfi.seg["sid"], dfi.seg["off"] + framed_off,
-                    framed_len)
+                return ("read_segment", dfi.seg["sid"],
+                        dfi.seg["off"] + framed_off)
+            return ("read_file_stream", bucket, part_path, framed_off)
+
+        def read_one(j):
+            disk = shuffled[j]
+            if disk is None:
+                raise serrors.DiskNotFound("offline")
+            src = source(j)
+            if src is None:
+                framed = sfis[j].inline_data[
+                    framed_off:framed_off + framed_len]
+                if len(framed) < framed_len:
+                    raise serrors.FileCorrupt("short inline data")
+            elif src[0] == "read_segment":
+                framed = disk.read_segment(src[1], src[2], framed_len)
             else:
-                framed = disk.read_file_stream(
-                    bucket,
-                    f"{object_name}/{fi.data_dir}/part.{part.number}",
-                    framed_off, framed_len)
+                framed = disk.read_file_stream(*src[1:], framed_len)
             # the drive call above is timed where it runs
             # (mt_drive_call_seconds); the verify is the child's other half
             try:
@@ -1676,16 +1694,49 @@ class ErasureObjects(MultipartOps, ObjectLayer):
             except bitrot.BitrotError as e:
                 raise serrors.FileCorrupt(str(e)) from e
 
+        def wave(js):
+            """The round's local shards j in ``js``, on this thread:
+            ``(result, error, start_ns, end_ns)`` each, in order."""
+            got, files = {}, []
+            for j in js:
+                if source(j) is None:       # no I/O: read_one, here
+                    t0 = time.monotonic_ns()
+                    try:
+                        r, e = read_one(j), None
+                    except Exception as x:  # noqa: BLE001 — per-item
+                        r, e = None, x
+                    got[j] = r, e, t0, time.monotonic_ns()
+                else:
+                    files.append(j)
+            if files:
+                for j, r in zip(files, _xl.read_shard_wave(
+                        [shuffled[j] for j in files],
+                        [source(j) for j in files], framed_len, seg_len,
+                        ssize)):
+                    got[j] = r
+            return [got[j] for j in js]
+
         shards: list[np.ndarray | None] = [None] * nsh
         got = 0
         t0 = _critpath.now_ns()
         ends_all = [0] * nsh
         candidates = [j for j in range(nsh) if j not in dead]
+        waves = algo == bitrot.HIGHWAYHASH256S
         while got < k and candidates:
             batch, candidates = candidates[:k - got], candidates[k - got:]
             bends = [0] * len(batch)
-            res, errs = self._fanout_items(read_one, batch, ends=bends,
-                                           plane="get")
+            here = _xl.shard_wave_positions(
+                [shuffled[j] for j in batch]) if waves else []
+            res, errs = self._fanout_items(
+                read_one, batch, ends=bends, plane="get",
+                inline=(here, lambda: wave([batch[p] for p in here]))
+                if here else None)
+            if here:
+                _metrics.inc("mt_read_get_drives_total", {"route": "wave"},
+                             len(here))
+            if len(here) < len(batch):
+                _metrics.inc("mt_read_get_drives_total", {"route": "pool"},
+                             len(batch) - len(here))
             for pos, (j, r, e) in enumerate(zip(batch, res, errs)):
                 ends_all[j] = bends[pos]
                 if e is None:

@@ -454,9 +454,7 @@ class span:
             self._ann = _ANNOTATOR("mt:" + self.name)
             self._ann.__enter__()
         self.start_ns = time.time_ns()
-        seen = _cpu_seen.get(self.name, 0)
-        _cpu_seen[self.name] = seen + 1
-        self._c0 = -1 if seen % CPU_SAMPLE_EVERY else time.thread_time_ns()
+        self._c0 = time.thread_time_ns() if cpu_sampled(self.name) else -1
         self._t0 = time.monotonic_ns()
         return self
 
@@ -469,31 +467,50 @@ class span:
             self._ann = None
         if et is not None:
             self.error = f"{et.__name__}: {ev}"
-        family = LEG_FAMILIES.get(self.trace_type)
-        if family:
-            op, _, leg = self.name.partition(".")
-            labels = {"op": op, "leg": leg}
-            _metrics.observe(family, labels, dur / 1e9,
-                             buckets=KERNEL_BUCKETS)
-            if self.cpu_ns is not None:
-                twin = _LEG_CPU_FAMILIES[self.trace_type]
-                _metrics.observe(twin, {**labels, "clock": "cpu"},
-                                 self.cpu_ns / 1e9, buckets=KERNEL_BUCKETS)
-                _metrics.observe(twin, {**labels, "clock": "wall"},
-                                 dur / 1e9, buckets=KERNEL_BUCKETS)
-        rid = _REQUEST_ID.get()
-        sid = ""
-        if rid:
-            sid = new_span_id()
-            ring_append(rid, sid, _SPAN_PARENT.get(), self.trace_type,
-                        self.name, self.start_ns, dur, self.error)
-        if active():
-            publish_span(make_span(
-                self.trace_type, self.name, start_ns=self.start_ns,
-                duration_ns=dur, input_bytes=int(self.nbytes),
-                error=self.error, span_id=sid, _ring=False,
-                detail=self.detail() if self.detail else None))
+        observe_span(self.trace_type, self.name, self.start_ns, dur,
+                     self.cpu_ns, self.error, int(self.nbytes), self.detail)
         return False
+
+
+def cpu_sampled(name: str) -> bool:
+    """Whether this span of ``name`` reads its thread's CPU clock: one in
+    ``CPU_SAMPLE_EVERY`` of each name, the first one always."""
+    seen = _cpu_seen.get(name, 0)
+    _cpu_seen[name] = seen + 1
+    return not seen % CPU_SAMPLE_EVERY
+
+
+def observe_span(trace_type: str, name: str, start_ns: int, dur_ns: int,
+                 cpu_ns: int | None = None, error: str = "",
+                 nbytes: int = 0, detail=None) -> None:
+    """What a :class:`span` records on its exit, for an interval timed
+    elsewhere (a native read wave's per-item clocks,
+    storage/xl_storage.py read_shard_wave): the ring tuple, the leg
+    histogram and its CPU twin (when ``cpu_ns`` was sampled) and, behind
+    :func:`active`, the full span.  ``start_ns`` is on the wall clock."""
+    family = LEG_FAMILIES.get(trace_type)
+    if family:
+        op, _, leg = name.partition(".")
+        labels = {"op": op, "leg": leg}
+        _metrics.observe(family, labels, dur_ns / 1e9,
+                         buckets=KERNEL_BUCKETS)
+        if cpu_ns is not None:
+            twin = _LEG_CPU_FAMILIES[trace_type]
+            _metrics.observe(twin, {**labels, "clock": "cpu"},
+                             cpu_ns / 1e9, buckets=KERNEL_BUCKETS)
+            _metrics.observe(twin, {**labels, "clock": "wall"},
+                             dur_ns / 1e9, buckets=KERNEL_BUCKETS)
+    rid = _REQUEST_ID.get()
+    sid = ""
+    if rid:
+        sid = new_span_id()
+        ring_append(rid, sid, _SPAN_PARENT.get(), trace_type, name,
+                    start_ns, dur_ns, error)
+    if active():
+        publish_span(make_span(
+            trace_type, name, start_ns=start_ns, duration_ns=dur_ns,
+            input_bytes=nbytes, error=error, span_id=sid, _ring=False,
+            detail=detail() if detail else None))
 
 
 def publish(info: Dict[str, Any]) -> None:

@@ -195,8 +195,9 @@ class _Landed(ctypes.Structure):
 @functools.cache
 def _wave_lib():
     """native/syncwave.c, built and loaded on first use; None when it
-    cannot be (utils/nativelib.status() says why).  Its read wave
-    (``mt_read_files``) serves xl_storage.read_version_wave."""
+    cannot be (utils/nativelib.status() says why).  Its read waves
+    (``mt_read_files``, ``mt_read_verify_ranges``) serve
+    xl_storage.read_version_wave and read_shard_wave."""
     from ..utils import nativelib
     lib = nativelib.load(_WAVE_SRC, _WAVE_SO)
     if lib is not None:
@@ -218,6 +219,11 @@ def _wave_lib():
             ctypes.POINTER(ctypes.c_char_p), ctypes.c_int, ctypes.c_void_p,
             ctypes.c_size_t, i64s, ints, i64s, i64s]
         lib.mt_read_files.restype = None
+        lib.mt_read_verify_ranges.argtypes = [
+            ctypes.POINTER(ctypes.c_char_p), i64s, ctypes.c_int,
+            ctypes.c_size_t, ctypes.c_size_t, ctypes.c_size_t,
+            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_void_p, i64s]
+        lib.mt_read_verify_ranges.restype = None
     return lib
 
 
@@ -723,12 +729,17 @@ class SegmentStore:
                 col.defer_fd(os.dup(self._jfd), storage=storage,
                              key=("segj", id(self)))
 
-    def read(self, sid: int, off: int, length: int) -> bytes:
+    def file(self, sid: int) -> str:
+        """The path of segment ``sid``, the store loaded first: what
+        :meth:`read` opens (xl_storage.read_shard_wave reads it too)."""
         with self._mu:
             self._ensure()
+        return os.path.join(self.dir, _seg_name(sid))
+
+    def read(self, sid: int, off: int, length: int) -> bytes:
+        path = self.file(sid)
         try:
-            fd = os.open(os.path.join(self.dir, _seg_name(sid)),
-                         os.O_RDONLY)
+            fd = os.open(path, os.O_RDONLY)
         except FileNotFoundError:
             raise errors.FileNotFound(f"segment {sid}") from None
         try:

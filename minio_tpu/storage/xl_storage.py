@@ -32,6 +32,8 @@ import time
 import uuid
 from typing import Iterable
 
+import numpy as np
+
 from ..admin.metrics import GLOBAL as _metrics
 from ..admin.metrics import KERNEL_BUCKETS
 from ..obs import lastminute as _lastminute
@@ -1402,7 +1404,8 @@ def read_version_wave(disks, volume: str, path: str,
         if isinstance(got[i], tuple) and got[i][1] == _READ_TOOBIG:
             t1s[i] = time.monotonic_ns()     # read again by the plain path
         out.append((fi, err, t0s[i], t1s[i]))
-        dts.append(_observe_wave(xls[i], volume, path, err, t0s[i], t1s[i]))
+        dts.append(_observe_wave(xls[i], "read_version", (volume, path),
+                                 err, t0s[i], t1s[i]))
     _metrics.observe_many("mt_drive_call_seconds", _CALL_LABELS,
                           [dt / 1e9 for dt in dts], buckets=KERNEL_BUCKETS)
     return out
@@ -1453,14 +1456,194 @@ def _decode(got, volume: str, path: str, rel: str, version_id) -> FileInfo:
     return XLMeta.load(buf).to_fileinfo(volume, path, version_id)
 
 
-def _observe_wave(xl: XLStorage, volume: str, path: str, e, t0: int,
-                  t1: int) -> int:
-    """One drive read here, observed as ``_traced_op`` observes a call:
-    its window and its span; the caller folds the returned ns into
-    ``mt_drive_call_seconds``."""
+def _observe_wave(xl: XLStorage, op: str, where: tuple, e, t0: int,
+                  t1: int, nbytes: int = 0) -> int:
+    """One drive call a wave made, observed as ``_traced_op`` observes
+    ``op``: its window and its span (``where``: its volume and path);
+    the caller folds the returned ns into ``mt_drive_call_seconds``."""
     dt = t1 - t0
     err = f"{type(e).__name__}: {e}" if e is not None else ""
-    xl.latency.record("read_version", dt, 0)
-    _publish_call(xl, "read_version", t0 + time.time_ns()
-                  - time.monotonic_ns(), dt, err, lambda: (volume, path))
+    xl.latency.record(op, dt, nbytes)
+    _publish_call(xl, op, t0 + time.time_ns() - time.monotonic_ns(), dt,
+                  err, lambda: where, 0, nbytes)
     return dt
+
+
+# -- a GET's local shard reads, in one native wave ---------------------------
+# A round of a GET's shard read was k pool children: each waited for a
+# pool thread and the interpreter to start, then for the interpreter
+# again after each of its open / seek / read / close and around its
+# verify (PERF.md, get_io_ms).  The local drives' windows are read,
+# verified and gathered instead by ONE call on the calling thread that
+# never holds the interpreter lock (native/syncwave.c
+# mt_read_verify_ranges, checking frames with highwayhash.c's own
+# mt_hh256_verify_framed); the error each drive raises and how it is
+# observed stay here.
+
+# syncwave.c MT_SHARD_*: res[0] of a window read short, of one whose
+# frames did not verify, of one whose frames hold less payload than asked
+_SHORT, _BITROT, _TRUNC = -2, -3, -4
+_OPENED = 1         # MT_SHARD_OPEN: res[1] of an errno its open met
+_SHARD_RES = 6      # MT_SHARD_RES: int64s per item
+_SHARD_OPS = ("read_file_stream", "read_segment")
+
+
+def shard_wave_positions(disks) -> list[int]:
+    """Positions of the drives of ``disks`` whose shard windows
+    :func:`read_shard_wave` reads: those of :func:`wave_positions`, and
+    none where O_DIRECT reads are on (``MT_ODIRECT``: the drive call's
+    aligned reader) or the native frame check cannot be loaded."""
+    from ..hashing import highwayhash
+    if _ODIRECT or highwayhash.verify_framed_address() is None:
+        return []
+    return wave_positions(disks)
+
+
+def read_shard_wave(disks, items, framed_len: int, seg_len: int,
+                    shard_size: int) -> list[tuple]:
+    """One round of a GET's shard read on every drive of ``disks`` (the
+    caller picks them with :func:`shard_wave_positions`), from the
+    calling thread: each drive's window of ``framed_len`` framed bytes is
+    read, every frame's HighwayHash-256S digest checked, and its first
+    ``seg_len`` payload bytes gathered, by ONE native call (at most 8
+    threads, joined before it returns) that never holds the interpreter
+    lock.  ``items``, per drive: ``("read_file_stream", volume, path,
+    offset)`` for a part file, ``("read_segment", sid, offset)`` for a
+    packed extent.  Per drive, in order: ``(payload | None, error |
+    None, start_ns, end_ns)`` on the monotonic clock; the payloads are
+    rows of one array made for this call.
+
+    Each drive raises what the pool route raises, type and message: the
+    drive call's errors (``read_file_stream``: a missing file
+    ``FileNotFound``, a refused one ``FileAccessDenied``, a short one
+    ``FileCorrupt("short read ...")``; ``read_segment``: its own), raised
+    as the drive's call under its breaker (``HealthDisk.guarded``) where
+    that call is guarded; then a frame that does not verify, or a
+    payload short of ``seg_len``, ``FileCorrupt`` with the message
+    ``bitrot.verify_extract`` gives.  A drive is observed as
+    ``_traced_op`` observes its call (its window,
+    ``mt_drive_call_seconds{op,kind=local}`` = the native read's own
+    time, its ``storage.<op>`` span), and its verify as the ``get.verify``
+    span observes it (wall, and thread CPU for one in
+    ``trace.CPU_SAMPLE_EVERY``)."""
+    from ..hashing import highwayhash
+    n = len(disks)
+    xls = [wave_target(d) for d in disks]
+    files: list = []        # per drive: its file, or what refused it
+    t0s, t1s = [0] * n, [0] * n
+    for i, (xl, item) in enumerate(zip(xls, items)):
+        t0s[i] = time.monotonic_ns()
+        try:
+            files.append(xl._file_path(item[1], item[2])
+                         if item[0] == "read_file_stream"
+                         else xl.segments.file(item[1]))
+        except Exception as e:  # noqa: BLE001 — raised as the drive's call
+            files.append(e)
+        t1s[i] = time.monotonic_ns()
+    wave = [i for i, f in enumerate(files) if isinstance(f, str)]
+    m = len(wave)
+    rows = np.empty((m, framed_len), dtype=np.uint8)
+    res = np.zeros((m, _SHARD_RES), dtype=np.int64)
+    res[:, 5] = [_trace.cpu_sampled("get.verify") for _ in wave]
+    if m:
+        _commit._wave_lib().mt_read_verify_ranges(
+            (ctypes.c_char_p * m)(*(os.fsencode(files[i]) for i in wave)),
+            (ctypes.c_longlong * m)(*(items[i][-1] for i in wave)), m,
+            framed_len, shard_size, seg_len,
+            highwayhash.verify_framed_address(), highwayhash.MAGIC_KEY,
+            rows.ctypes.data,
+            res.ctypes.data_as(ctypes.POINTER(ctypes.c_longlong)))
+    wall = time.time_ns() - time.monotonic_ns()
+    slot = {i: j for j, i in enumerate(wave)}
+    dts: dict = {op: [] for op in _SHARD_OPS}
+    out = []
+    for i, d in enumerate(disks):
+        op, item, j = items[i][0], items[i], slot.get(i)
+        if j is None:       # refused before the read
+            code, info, t0, t_read, t_end, cpu = 0, 0, t0s[i], t1s[i], \
+                t1s[i], -1
+            call_err = files[i]
+        else:
+            code, info, t0, t_read, t_end, cpu = (int(v) for v in res[j])
+            call_err = _shard_read_error(code, info, op, files[i], item,
+                                         framed_len)
+        try:
+            if op == "read_file_stream":
+                _as_call(d, _raise_any, call_err)
+            else:
+                _raise_any(call_err)
+        except Exception as e:  # noqa: BLE001 — per-drive isolation
+            row, err = None, e
+        else:
+            row, err = _verified(code, info, seg_len, rows[j],
+                                 t_read + wall, t_end - t_read, cpu)
+        dts[op].append(_observe_wave(
+            xls[i], op, item[1:3] if op == "read_file_stream" else ("", ""),
+            call_err, t0, t_read, 0 if call_err is not None else framed_len))
+        out.append((row, err, t0, t_end))
+    for op, got in dts.items():
+        if got:
+            _metrics.observe_many("mt_drive_call_seconds",
+                                  {"op": op, "kind": "local"},
+                                  [dt / 1e9 for dt in got],
+                                  buckets=KERNEL_BUCKETS)
+    return out
+
+
+def _raise_any(err) -> None:
+    if err is not None:
+        raise err
+
+
+def _os_error(err: int, full: str | None) -> OSError:
+    """The ``OSError`` subclass Python raises for ``err``, naming
+    ``full`` as an ``open`` does (a read names no file)."""
+    return OSError(err, os.strerror(err), full) if full \
+        else OSError(err, os.strerror(err))
+
+
+def _shard_read_error(code: int, info: int, op: str, full: str, item,
+                      length: int) -> Exception | None:
+    """What the drive call ``op`` raises where the native read of its
+    window met ``code`` (syncwave.c res[0], res[1] = ``info``); None
+    where it read the whole window."""
+    if code in (0, _BITROT, _TRUNC):
+        return None
+    if op == "read_file_stream":        # XLStorage.read_file_stream
+        path = item[2]
+        if code == _SHORT:
+            return errors.FileCorrupt(
+                f"short read {info} < {length} at {path}")
+        if code == errno.ENOENT:
+            return errors.FileNotFound(path)
+        if code in (errno.EACCES, errno.EPERM):
+            return errors.FileAccessDenied(path)
+        # open() refuses a directory itself, naming it
+        return _os_error(code, full if info == _OPENED
+                         or code == errno.EISDIR else None)
+    sid, off = item[1], item[2]         # commit.SegmentStore.read
+    if code == _SHORT:
+        return errors.FileCorrupt(
+            f"segment {sid}: short read {info} < {length} at +{off}")
+    if code == errno.ENOENT and info == _OPENED:
+        return errors.FileNotFound(f"segment {sid}")
+    return _os_error(code, full if info == _OPENED else None)
+
+
+def _verified(code: int, info: int, seg_len: int, row, start_ns: int,
+              dur_ns: int, cpu: int) -> tuple:
+    """``(payload, None)`` of a window whose frames verified, or
+    ``(None, FileCorrupt)`` with ``bitrot.verify_extract``'s message; the
+    verify observed as its ``get.verify`` span."""
+    msg = ""
+    if code == _BITROT:
+        msg = f"content hash mismatch (block {info})"
+    elif code == _TRUNC:
+        msg = (f"truncated frame: {info} payload bytes present, "
+               f"{seg_len} declared")
+    _trace.observe_span("read", "get.verify", start_ns, dur_ns,
+                        cpu if cpu >= 0 else None,
+                        f"BitrotError: {msg}" if msg else "")
+    if msg:
+        return None, errors.FileCorrupt(msg)
+    return row[:seg_len], None

@@ -6,8 +6,9 @@ are rebuilt with
 ``-fsanitize=address,undefined`` into a scratch build dir
 (MT_NATIVE_BUILD_DIR) and exercised — through their normal Python
 bindings, under concurrent load (sixteen threads landing op-body files
-through syncwave.c at once among them) — in a subprocess running with
-libasan preloaded.  Any ASan/UBSan report fails the run.
+through syncwave.c at once, and a GET's shard read wave meeting a
+corrupt frame, a short file and a missing one, among them) — in a
+subprocess running with libasan preloaded.  Any ASan/UBSan report fails the run.
 
 A canary proves the harness has teeth: a deliberately buggy library
 built and driven the same way MUST be caught.
@@ -180,6 +181,50 @@ WORKLOAD = textwrap.dedent("""
             got = xl_storage.read_version_wave(disks, "bkt", "obj")
             assert [fi is not None for fi, *_ in got] == want, got
 
+    def shardwave_work():
+        # a GET's shard read wave (mt_read_verify_ranges): whole windows,
+        # a window of one frame and a short tail frame; a corrupt frame,
+        # a short file, a missing one
+        import tempfile
+        from minio_tpu.hashing import bitrot, highwayhash
+        from minio_tpu.storage import commit, xl_storage
+        assert commit._wave_lib() is not None, "syncwave build failed"
+        assert highwayhash.verify_framed_address() is not None
+        ssize, blocks = 5462, 5
+        with tempfile.TemporaryDirectory() as d:
+            disks, want = [], []
+            payload = os.urandom(ssize * blocks - 1000)
+            framed = highwayhash.hh256_frame(payload, ssize)
+            for i in range(12):
+                root = os.path.join(d, f"d{i}")
+                os.makedirs(os.path.join(root, "bkt", "obj"))
+                disks.append(xl_storage.XLStorage(root))
+                blob = bytearray(framed)
+                if i == 9:
+                    blob[32 + ssize + 32 + 7] ^= 0xFF   # frame 2
+                elif i == 10:
+                    blob = blob[:len(blob) // 2]        # short
+                if i != 11:                             # missing
+                    with open(os.path.join(root, "bkt", "obj",
+                                           "part.1"), "wb") as f:
+                        f.write(blob)
+            for b0, b1 in ((0, blocks), (0, 1), (blocks - 1, blocks),
+                           (1, 3)):
+                off = b0 * ssize
+                seg = min(b1 * ssize, len(payload)) - off
+                flen = seg + (b1 - b0) * 32
+                items = [("read_file_stream", "bkt", "obj/part.1",
+                          off + b0 * 32)] * 12
+                got = xl_storage.read_shard_wave(disks, items, flen, seg,
+                                                 ssize)
+                for i, (row, err, *_) in enumerate(got):
+                    ok = i < 9 or (i == 9 and not b0 <= 1 < b1) \
+                        or (i == 10 and off + b0 * 32 + flen
+                            <= len(framed) // 2)
+                    assert (err is None) == ok, (i, b0, b1, err)
+                    if ok:
+                        assert row.tobytes() == payload[off:off + seg]
+
     def run(fn):
         try:
             for _ in range(5):
@@ -189,7 +234,7 @@ WORKLOAD = textwrap.dedent("""
 
     threads = [threading.Thread(target=run, args=(f,))
                for f in (gf8_work, snappy_work, hh_work, jsonscan_work,
-                         syncwave_work, readwave_work)
+                         syncwave_work, readwave_work, shardwave_work)
                for _ in range(3)]
     # one writer thread per drive of a 16-drive set, landing at once
     threads += [threading.Thread(target=run, args=(landing_work,))
