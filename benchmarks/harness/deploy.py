@@ -1,4 +1,5 @@
-"""Start, read back and stop the deployment a configuration file names.
+"""Start, read back and stop the deployment a configuration file names,
+and fail the drives it names as down.
 
 ``start_servers`` / ``stop_servers`` / ``wait_live`` / ``scrape`` follow
 ``chip_smoke.py`` (the floor under every cell); here the argv and the
@@ -112,6 +113,21 @@ class Deployment:
                     p.wait(max(0.1, deadline - time.monotonic()))
                 except subprocess.TimeoutExpired:
                     pass
+
+    def fail_drives(self) -> list[int]:
+        """The configuration's ``down_drives`` lose their drive, from
+        outside the servers: ``dNN`` is renamed to ``dNN.failed`` and an
+        empty regular file takes its path, so that every open, stat or
+        mkdir below it fails (ENOTDIR), as on a drive whose mount is gone.
+        Not an empty directory: the servers would format and heal into
+        it, which is a replaced drive, another deployment."""
+        down = list(self.config.get("down_drives", []))
+        for i in down:
+            path = self.dirs[i]
+            os.rename(path, path + ".failed")
+            with open(path, "xb"):
+                pass
+        return down
 
     def wait_live(self, timeout: float) -> None:
         deadline = time.monotonic() + timeout

@@ -179,6 +179,36 @@ def load_data(kind: str, name: str) -> dict:
     return d
 
 
+def check_config(cfg: dict) -> dict:
+    """A configuration file's ``down_drives``, where it has the key:
+    indices into its ``drives``, all distinct, at least one and at most
+    ``parity_shards``, leaving the write quorum alive, and the guarantee
+    ``shards_expected_on_healthy_drives`` equal to the drives that stay.
+    A file without the key is returned as it is.  Raises ManifestError;
+    returns ``cfg``."""
+    if "down_drives" not in cfg:
+        return cfg
+    down, name = cfg["down_drives"], cfg.get("name")
+    n, m = cfg["drives"], cfg["fixes"]["parity_shards"]
+    g = cfg["guarantees"]
+    _need(isinstance(down, list) and all(
+        isinstance(i, int) and not isinstance(i, bool) and 0 <= i < n
+        for i in down), f"config {name}: down_drives {down!r} are not "
+        f"indices into its {n} drives")
+    _need(len(set(down)) == len(down),
+          f"config {name}: down_drives {down} name a drive twice")
+    _need(1 <= len(down) <= m, f"config {name}: {len(down)} down_drives, "
+          f"from 1 to parity_shards = {m}")
+    _need(n - len(down) >= g["write_quorum"],
+          f"config {name}: {n - len(down)} live drives are under the write "
+          f"quorum {g['write_quorum']}")
+    _need(g["shards_expected_on_healthy_drives"] == n - len(down),
+          f"config {name}: shards_expected_on_healthy_drives is "
+          f"{g['shards_expected_on_healthy_drives']}, the drives that stay "
+          f"are {n - len(down)}")
+    return cfg
+
+
 def metrics_for(m: dict, section: str, cell: str) -> list[dict]:
     """The manifest's metrics of one section that this cell reports."""
     return [e for e in m[section]
@@ -198,7 +228,7 @@ class Cell:
         for k in ("config", "traffic", "chips"):
             _need(self.file.get(k) == entry[k],
                   f"workloads/{name}.json and BENCHMARK.json differ on {k}")
-        self.config = load_data("configs", entry["config"])
+        self.config = check_config(load_data("configs", entry["config"]))
         self.traffic = load_data("traffic", entry["traffic"])
         self.end_to_end = [dict(e, **load_data("end_to_end", e["name"]))
                            for e in metrics_for(manifest, "end_to_end", name)]

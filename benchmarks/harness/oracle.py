@@ -1,9 +1,11 @@
 """The on-disk oracle: what the drives hold, checked by code that never
 touches the device path (a copy of ``chip_smoke.py``'s ``read_shards`` /
 ``verify_on_disk``).  Frame digests are checked with the program's HOST
-HighwayHash (native C, ``minio_tpu/hashing``), parity is recomputed from
-the data shards by the benchmark's own plain reference
-(``reference.py``), data shards are compared with the body.
+HighwayHash (native C, ``minio_tpu/hashing``); every block is rebuilt from
+k of the shards present by the benchmark's own plain reference
+(``reference.py``), and what it rebuilds is compared with the body and
+with every shard present.  On a set with failed drives the shards they
+held are missing, and only those.
 """
 
 from __future__ import annotations
@@ -52,18 +54,29 @@ def read_shards(dirs: list[str], bucket: str, key: str) -> dict:
 
 
 def verify_on_disk(dirs: list[str], bucket: str, key: str, body: bytes,
-                   k: int, m: int, expect_shards: int) -> dict:
-    """Every frame digest against the host HighwayHash; parity recomputed
-    from the data shards by the plain reference; data shards against the
-    body; a shard on every drive the configuration promises."""
+                   k: int, m: int, expect_shards: int,
+                   down: tuple = ()) -> dict:
+    """A shard on every drive the configuration promises, and none where
+    the object's distribution put one on a failed drive (``down``: indices
+    into ``dirs``); every present frame digest against the host
+    HighwayHash; every block rebuilt from its first k present rows by the
+    plain reference, the rebuilt data rows against the body and every
+    present row against its rebuilt row (with all k+m present: the parity
+    recomputed from the data shards)."""
     from minio_tpu.hashing import highwayhash
     shards = read_shards(dirs, bucket, key)
     check(len(shards) == expect_shards,
           f"{key}: {len(shards)}/{expect_shards} drives hold a shard")
-    fi = shards[0]["fi"]
+    present = sorted(shards)
+    fi = shards[present[0]]["fi"]
     ec = fi.erasure
     check((ec.data_blocks, ec.parity_blocks) == (k, m),
           f"{key}: geometry {ec.data_blocks}+{ec.parity_blocks}")
+    lost = sorted(ec.distribution[d] - 1 for d in down)
+    missing = sorted(set(range(k + m)) - set(present))
+    check(missing == lost, f"{key}: shards {missing} are missing; the "
+          f"failed drives {list(down)} held shards {lost}")
+    use = present[:k]
     bs = ec.block_size
     ss = reference.ceil_div(bs, k)
     frames = blocks = off = 0
@@ -72,7 +85,7 @@ def verify_on_disk(dirs: list[str], bucket: str, key: str, body: bytes,
         tail_ss = reference.ceil_div(tail, k)
         want_len = nfull * (32 + ss) + ((32 + tail_ss) if tail else 0)
         rows = []
-        for i in range(k + m):
+        for i in present:
             raw = np.frombuffer(shards[i]["parts"][part.number], np.uint8)
             check(raw.size == want_len,
                   f"{key} part {part.number} shard {i}: {raw.size} bytes "
@@ -88,16 +101,18 @@ def verify_on_disk(dirs: list[str], bucket: str, key: str, body: bytes,
             n = ss if b < nfull else tail_ss
             base = b * (32 + ss) + 32
             stripe = framed[:, base:base + n]
-            want = reference.encode_parity(
-                np.ascontiguousarray(stripe[:k]), m)
-            check(np.array_equal(want, stripe[k:]),
-                  f"{key} part {part.number} block {b}: parity on disk "
-                  f"differs from the plain reference")
+            full = reference.decode(np.ascontiguousarray(stripe[:k]), use,
+                                    k, m)
+            for j, i in enumerate(present):
+                check(np.array_equal(full[i], stripe[j]),
+                      f"{key} part {part.number} block {b}: shard {i} on "
+                      f"disk differs from the plain reference's rebuild "
+                      f"from shards {use}")
             blen = bs if b < nfull else tail
-            check(np.array_equal(stripe[:k].reshape(-1)[:blen],
+            check(np.array_equal(full[:k].reshape(-1)[:blen],
                                  pbody[b * bs:b * bs + blen]),
-                  f"{key} part {part.number} block {b}: data shards differ "
-                  f"from the body")
+                  f"{key} part {part.number} block {b}: the data shards "
+                  f"rebuilt from shards {use} differ from the body")
             blocks += 1
         off += part.size
     check(off == len(body), f"{key}: parts cover {off} of {len(body)}")

@@ -3,7 +3,8 @@
 Reed-Solomon over GF(2^8) as MinIO's klauspost/reedsolomon dependency
 defines it (cmd/erasure-coding.go): field polynomial 0x11d, generator 2,
 a Vandermonde matrix made systematic by its top square's inverse; parity
-is the bottom m rows applied to the k data shards.  Straight numpy table
+is the bottom m rows applied to the k data shards, and any k shards give
+all k+m back through the inverse of their rows.  Straight numpy table
 lookups: no kernels, no native code, nothing shared with the program
 (``minio_tpu/ops/gf8_ref.py`` is the program's own copy of the same
 mathematics and is not imported here).
@@ -82,6 +83,19 @@ def encode_parity(data: np.ndarray, m: int) -> np.ndarray:
     """(k, n) data shards -> (m, n) parity shards."""
     k = data.shape[0]
     return _matmul(rs_matrix(k, k + m)[k:], data)
+
+
+def decode(rows: np.ndarray, present, k: int, m: int) -> np.ndarray:
+    """(k, n) shards at the k distinct indices ``present`` of k+m -> all
+    (k+m, n) shards: the data through the inverse of the matrix's present
+    rows, the parity encoded again from that data."""
+    present = list(present)
+    if len(present) != k or len(set(present)) != k \
+            or not all(0 <= i < k + m for i in present):
+        raise ValueError(f"decode needs {k} distinct indices of {k + m}, "
+                         f"got {present}")
+    matrix = rs_matrix(k, k + m)
+    return _matmul(_matmul(matrix, _invert(matrix[present])), rows)
 
 
 def ceil_div(a: int, b: int) -> int:

@@ -7,9 +7,16 @@ Starts the deployment the cell's configuration names (child processes
 under ``harness/serve.py``; this process never imports JAX), drives it
 through the S3 front with the benchmark's own client processes, and prints
 as its LAST stdout line one JSON object: ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` and, traced, ``breakdown``.  Earlier
-lines (prefixed ``#``) say what the run did: filesystem, set-up breakdown,
-sample counts, the generator's own overhead, stage sums.
+``failed``, ``metrics``, ``device``, traced ``breakdown``, and last
+``checks``: every number the run compares, with its limit (also the last
+lines on stderr).  Earlier lines (prefixed ``#``) say what the run did:
+filesystem, set-up breakdown, sample counts, the generator's own overhead,
+stage sums.
+
+A configuration with ``down_drives`` loses those drives once the preload
+has finished (``Deployment.fail_drives``): the warm-up and the window run
+on the failed set, GETs have to be rebuilt by the device codec, and the
+on-disk oracle checks what survives.
 
 ``--trace 0``: the cell's end-to-end metrics.  ``--trace 1``: its per-layer
 metrics; a ``jax.profiler`` slice of at most 5 s is taken in the middle of
@@ -141,6 +148,7 @@ class Clients:
 
 PUTS = {"family": "mt_s3_requests_api_total", "labels": {"api": "PutObject"}}
 ENCODES = {"family": "mt_tpu_ops_total", "labels": {"op": "encode"}}
+MATMULS = {"family": "mt_tpu_ops_total", "labels": {"op": "matmul"}}
 
 
 def compile_tally(infos: list[dict]) -> int:
@@ -207,8 +215,11 @@ def collect_traces(dep: Deployment, timeout: float = 120.0) -> list[dict]:
 def verify_after(dep, cell, live: dict, bodies: traffic.BodyPool,
                  seed: int) -> tuple[dict, list[str]]:
     """HEAD every surviving key, read a seeded sample back whole, and check
-    one object of each size on the drives."""
+    one object of each size on the drives.  ``wrong`` counts what each of
+    the four checks found wrong."""
     problems: list[str] = []
+    wrong = dict.fromkeys(("head_after", "read_back", "drives_holding",
+                           "on_disk"), 0)
     keys = sorted(live)
     eps = dep.endpoints
 
@@ -234,6 +245,7 @@ def verify_after(dep, cell, live: dict, bodies: traffic.BodyPool,
     with ThreadPoolExecutor(VERIFY_THREADS) as ex:
         for bad in ex.map(head, chunks):
             problems += bad
+            wrong["head_after"] += len(bad)
 
     g = cell.config["guarantees"]
     k, m = cell.config["fixes"]["data_shards"], \
@@ -247,12 +259,14 @@ def verify_after(dep, cell, live: dict, bodies: traffic.BodyPool,
         if not (st == 200 and data == body
                 and h.get("etag", "").strip('"') == md5):
             problems.append(f"read-back {key}: HTTP {st}, {len(data)} bytes")
+            wrong["read_back"] += 1
         held = sum(os.path.exists(os.path.join(d, BUCKET, key, "xl.meta"))
                    for d in dep.dirs)
         if held != g["shards_expected_on_healthy_drives"]:
             problems.append(f"{key}: on {held} drives, "
                             f"{g['shards_expected_on_healthy_drives']} "
                             f"expected")
+            wrong["drives_holding"] += 1
     conn.close()
 
     on_disk = {}
@@ -264,11 +278,13 @@ def verify_after(dep, cell, live: dict, bodies: traffic.BodyPool,
         try:
             on_disk[key] = oracle.verify_on_disk(
                 dep.dirs, BUCKET, key, body, k, m,
-                g["shards_expected_on_healthy_drives"])
+                g["shards_expected_on_healthy_drives"],
+                tuple(cell.config.get("down_drives", ())))
         except Failed as e:
             problems.append(f"on-disk oracle: {e}")
+            wrong["on_disk"] += 1
     return ({"head_checked": len(keys), "read_back": len(sample),
-             "on_disk": on_disk}, problems)
+             "on_disk": on_disk, "wrong": wrong}, problems)
 
 
 def device_of(infos: list[dict]) -> dict:
@@ -288,6 +304,7 @@ def run(args, cell: manifest.Cell, work: str, logs: dict) -> dict:
     mix = traffic.effective(cell.traffic, args.rehearse)
     seconds = float(args.seconds)
     dep = Deployment(cell.config, work, bool(args.trace), args.rehearse)
+    down = cell.config.get("down_drives", [])
     clients = None
     setup: dict = {}
     try:
@@ -329,6 +346,12 @@ def run(args, cell: manifest.Cell, work: str, logs: dict) -> dict:
               f"{[e for g in got for e in g['error_samples']][:5]}")
         setup["preload_s"] = time.monotonic() - t
         setup["preload_objects"] = len(plan)
+        if down:
+            t = time.monotonic()
+            dep.fail_drives()
+            setup["fault_s"] = time.monotonic() - t
+            say(f"fault: drives {down} failed {t - T_PROC:.3f} s after "
+                f"start")
 
         # a client that finished its preload early has been idle since
         clients.connect(setup)
@@ -398,6 +421,26 @@ def run(args, cell: manifest.Cell, work: str, logs: dict) -> dict:
         problems.append(f"{puts_in_window} PUTs completed in the window but "
                         f"mt_tpu_ops_total{{op=encode}} rose by {enc}: the "
                         f"device codec did not encode them")
+    gets = sum(1 for r in window if r[stats.OP] == "GET" and r[stats.OK])
+    if down:
+        dec = readers.delta({"scrape0": scrape0, "scrape1": scrape1},
+                            MATMULS) or 0
+        say(f"decode: {dec:.0f} matmul dispatches for {gets} GETs in the "
+            f"window, drives {down} failed")
+        if gets and dec <= 0:
+            problems.append(f"{gets} GETs completed in the window on a set "
+                            f"with drives {down} failed but "
+                            f"mt_tpu_ops_total{{op=matmul}} rose by {dec}: "
+                            f"the device codec rebuilt nothing")
+    # every number the run compares, beside its limit
+    checks = {"wrong_in_window": (len(mismatches), "at_most", 0),
+              **{f"wrong_{n}": (v, "at_most", 0)
+                 for n, v in verified["wrong"].items()},
+              "failed_outside_window": (outside, "at_most", 0)}
+    if puts_in_window:
+        checks["encode_dispatches"] = (enc, "at_least", 1)
+    if down and gets:
+        checks["matmul_dispatches"] = (dec, "at_least", 1)
     over = stats.generator_overhead_share(records, t0, t1)
     say(f"window {span:.3f}s: attempted={attempted} failed={failed} "
         + " ".join(f"{op}={sum(1 for r in window if r[stats.OP] == op)}"
@@ -497,6 +540,8 @@ def run(args, cell: manifest.Cell, work: str, logs: dict) -> dict:
               "metrics": metrics, "device": dev}
     if breakdown:
         result["breakdown"] = breakdown
+    result["checks"] = {n: {"value": v, rel: lim}
+                        for n, (v, rel, lim) in checks.items()}
     if args.rehearse and problems:
         raise Failed(f"rehearsal found problems: {problems[:3]}")
     return result
@@ -536,6 +581,9 @@ def main() -> int:
     finally:
         if work:
             shutil.rmtree(work, ignore_errors=True)
+    for name, c in result["checks"].items():
+        print(f"check {name}: " + ", ".join(f"{k} {v}" for k, v in c.items()),
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

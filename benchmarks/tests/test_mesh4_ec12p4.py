@@ -16,6 +16,7 @@ import pytest
 from benchmarks.harness import manifest, readers
 from benchmarks.harness.reducers import device as device_reducer
 from benchmarks.harness.reducers import mesh as mesh_reducer
+from benchmarks.tests.tail_cells import tail_block_cells
 
 CELL = "mesh4.put-10m"
 NEW = ("rs_fused_roofline", "mesh_collective_ms_per_put",
@@ -31,9 +32,11 @@ def test_manifest_holds_the_configuration_and_its_cell():
     cell = manifest.Cell(m, CELL)
     entry = next(c for c in m["configs"] if c["name"] == "mesh4-ec12p4")
     assert cell.chips == 4 and cell.traffic["name"] == "warp-put-10m"
-    assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == \
-        ["d4x4.mixed-10m", CELL]
-    assert len(m["workloads"]) == 6
+    four = [w["name"] for w in m["workloads"] if w["chips"] == 4]
+    assert "d4x4.mixed-10m" in four and CELL in four
+    assert [w for w in m["workloads"] if w["name"] == CELL] == [{
+        "name": CELL, "config": "mesh4-ec12p4", "traffic": "warp-put-10m",
+        "chips": 4, "why": cell.file["why"]}]
     assert sorted(entry["reduced"]) == sorted(cell.config["reduced"]) == \
         ["duration", "objects"]
     for ref in ("format-erasure.go:896-906", "object-api-common.go:32",
@@ -71,14 +74,18 @@ def test_the_new_metrics_are_the_cells_alone_and_hash_launch_is_not_its():
             mine["rs_fused_roofline"]["layer"]) == ("%", "kernels")
     assert mine["mesh_chip_busy_skew_pct"]["layer"] == "device"
     # the fused route launches no second hash program: the accepted
-    # metric keeps the cells it reads in
+    # metric keeps the cells whose bodies end in a tail block
     assert "hash_launch_ms" not in mine
-    others = [w["name"] for w in m["workloads"] if w["name"] != CELL]
+    tails = tail_block_cells(m)
+    assert CELL not in tails
     assert next(e for e in m["per_layer"]
-                if e["name"] == "hash_launch_ms")["workloads"] == others
-    for w in others:
-        got = {e["name"] for e in manifest.Cell(m, w).per_layer}
-        assert "hash_launch_ms" in got and not got & set(NEW)
+                if e["name"] == "hash_launch_ms")["workloads"] == tails
+    for w in m["workloads"]:
+        if w["name"] == CELL:
+            continue
+        got = {e["name"] for e in manifest.Cell(m, w["name"]).per_layer}
+        assert ("hash_launch_ms" in got) == (w["name"] in tails)
+        assert not got & set(NEW)
 
 
 # -- reducers/mesh.py on hand-made summaries ------------------------------------

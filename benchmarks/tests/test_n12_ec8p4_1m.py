@@ -18,6 +18,7 @@ import pytest
 from benchmarks.harness import manifest, readers, reference
 from benchmarks.harness.deploy import parse_scrape
 from benchmarks.harness.reducers import device as device_reducer
+from benchmarks.tests.tail_cells import tail_block_cells
 
 K, M = 8, 4
 BS = 1 << 20
@@ -58,15 +59,20 @@ def test_any_eight_of_twelve_rows_invert():
 # -- the manifest with the new entries ----------------------------------------
 
 def test_manifest_validates_with_the_four_entries_appended():
+    """The four entries are there once each and valid, wherever later
+    entries put them."""
     m = manifest.load_manifest()
-    assert m["configs"][-1]["name"] == CONFIG
-    assert m["workloads"][-1]["name"] == CELL
-    assert [e["name"] for e in m["per_layer"][-2:]] == list(NEW)
+    assert [c["name"] for c in m["configs"]].count(CONFIG) == 1
+    assert [w for w in m["workloads"] if w["config"] == CONFIG] == [{
+        "name": CELL, "config": CONFIG, "traffic": "warp-put-10m",
+        "chips": 1, "why": manifest.load_data("workloads", CELL)["why"]}]
     for name in NEW:
-        assert m["per_layer"][[e["name"] for e in m["per_layer"]].index(
-            name)]["workloads"] == [CELL]
-    four = [w["name"] for w in m["workloads"] if w["chips"] == 4]
-    assert len(m["workloads"]) == 7 and len(four) == 2
+        mine = [e for e in m["per_layer"] if e["name"] == name]
+        assert len(mine) == 1 and mine[0]["workloads"] == [CELL]
+    # whole blocks only: no tail block, so no second hash program
+    hl = next(e for e in m["per_layer"] if e["name"] == "hash_launch_ms")
+    assert CELL not in hl["workloads"]
+    assert hl["workloads"] == tail_block_cells(m)
 
 
 def test_manifest_holds_the_configuration_and_its_cell():

@@ -30,7 +30,21 @@ def test_rehearse(cell, trace):
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
     last = json.loads(p.stdout.strip().splitlines()[-1])
     assert set(last) == {"correct", "attempted", "failed", "metrics",
-                         "device"} | ({"breakdown"} if trace else set())
+                         "device", "checks"} | ({"breakdown"} if trace
+                                                else set())
+    # every number compared, beside its limit: last on the line and on
+    # stderr
+    assert list(last)[-1] == "checks"
+    for name, c in last["checks"].items():
+        (rel, limit), = [(k, v) for k, v in c.items() if k != "value"]
+        assert rel in ("at_most", "at_least"), name
+        assert (c["value"] <= limit) if rel == "at_most" \
+            else (c["value"] >= limit), name
+    assert {"wrong_in_window", "wrong_on_disk",
+            "encode_dispatches"} <= set(last["checks"])
+    tail = p.stderr.strip().splitlines()[-len(last["checks"]):]
+    assert [x.split(":")[0] for x in tail] == \
+        [f"check {n}" for n in last["checks"]]
     assert last["correct"] is False and last["failed"] == 0
     assert last["attempted"] > 0
     m = manifest.load_manifest()
